@@ -300,6 +300,9 @@ def cmd_apiserver(args) -> int:
         wire=getattr(args, "wire", "binary"),
         persistence=("" if persistence == "off" else persistence),
         telemetry=telemetry,
+        # which store core serves: the C++ one, or the Python fallback
+        # after a disabled/failed build (kubetpu.native.build_status)
+        store_core=("native" if store.native else "python"),
     )
     if server.replication is not None:
         banner_fields["role"] = server.replication.role
@@ -427,8 +430,17 @@ def cmd_up(args) -> int:
         emit_banner("cluster", **fields)
         for child in cluster.supervisor.children:
             url = child.url()
+            # the children's stdout is captured, so what their banners
+            # advertise about the machine is repeated here: the device a
+            # scheduler holds, the store core an apiserver serves from
+            held = {
+                k: (child.banner or {})[k]
+                for k in ("platform", "device_kind", "devices", "store_core")
+                if k in (child.banner or {})
+            }
             print(f"  {child.name:<16} pid {child.pid}"
-                  + (f"  {url}" if url else ""), flush=True)
+                  + (f"  {url}" if url else "")
+                  + (f"  {json.dumps(held)}" if held else ""), flush=True)
         print(f"kubetpu up: {cluster.n_processes()} process(es) ready — "
               f"apiserver {cluster.api_url} "
               f"({args.replicas} replica(s), {args.partition}, "
@@ -801,8 +813,23 @@ def cmd_scheduler(args) -> int:
     except (ConfigError, OSError) as e:
         print(f"invalid config: {e}", file=sys.stderr)
         return 1
+    from . import device_stamp
     from .parallel.mesh import resolve_mesh
 
+    # take the accelerator FIRST, and say so when it cannot be taken: a
+    # chip belongs to one process, and what libtpu reports when a second
+    # process asks (a multi-process lockfile error) does not name the cause
+    try:
+        held = device_stamp()
+    except RuntimeError as e:
+        print(
+            "scheduler: cannot take the accelerator. A chip belongs to ONE "
+            "process — is another scheduler (kubetpu up --replicas N, "
+            "--processes N) already holding it? Run one scheduler per chip, "
+            "or pin this one to the CPU with JAX_PLATFORMS=cpu.\n"
+            f"  jax: {e}", file=sys.stderr,
+        )
+        return 1
     try:
         mesh = resolve_mesh(args.mesh)
     except ValueError as e:
@@ -950,6 +977,9 @@ def cmd_scheduler(args) -> int:
     banner_fields = dict(
         server=args.server, engine=args.engine,
         replica=args.replica_id, partition=partition,
+        # what this scheduler holds (platform / device_kind / devices):
+        # whoever launched it reads the chip — or its absence — here
+        **held,
     )
     if diag is not None:
         banner_fields["url"] = diag.url

@@ -267,13 +267,11 @@ def _make_routed_scatter(mesh, axis: str):
     host→device routing happened at ``device_put`` — and the scatter body
     runs shard-local (indices are shard-local; no collectives). Donation
     aliases each state buffer in place, like the single-device scatter."""
-    from jax.experimental.shard_map import shard_map
-
     spec = jax.sharding.PartitionSpec(axis)
 
     @partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5))
     @partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec,) * 13, out_specs=(spec,) * 6,
     )
     def scatter(alloc, requested, nonzero, pod_count, allowed, valid,

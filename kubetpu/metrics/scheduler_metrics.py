@@ -130,6 +130,13 @@ class SchedulerMetricsRegistry:
             buckets=exponential_buckets(0.0001, 2, 20),
             declared={"stage": E2E_STAGES},
         )
+        self.explain_kernel_failures = r.counter(
+            "scheduler_explain_kernel_failures_total",
+            "Flight-recorder explain-kernel failures (compile, run or "
+            "fetch): the cycle goes on without its score/filter breakdown, "
+            "and after three the breakdown is switched off. Non-zero means "
+            "a shape or backend the kernel cannot handle.",
+        )
         self.pending_pods = r.gauge(
             "scheduler_pending_pods",
             "Number of pending pods, by the queue type.",
@@ -306,6 +313,10 @@ class SchedulerMetricsRegistry:
             }
         return {
             "schedule_attempts": attempts,
+            # lifetime, warm-up included: any failure is a finding
+            "explain_kernel_failures": int(
+                self.explain_kernel_failures.value
+            ),
             "attempt_duration_s": {
                 "p50": q(attempt_h, 0.50),
                 "p99": q(attempt_h, 0.99),

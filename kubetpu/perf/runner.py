@@ -21,6 +21,7 @@ util.go:468 samples scheduled-pod deltas every second and averages).
 
 from __future__ import annotations
 
+import hashlib
 import os
 import time
 from dataclasses import dataclass, field
@@ -98,6 +99,11 @@ class WorkloadResult:
     n_devices: int = 1
     mesh_shape: tuple = ()
     collective_wall_s: float | None = None
+    # where the sharded resident block REALLY lives (None when unsharded):
+    # block_sharded (False = the whole block fell back to one device),
+    # resident_devices (fewest devices any node-major leaf spans) and
+    # shards_with_transfer (shards that received routed delta bytes)
+    mesh_placement: dict | None = None
     # post-run metric snapshot (SchedulerMetricsRegistry.snapshot): p50/p99
     # from the histograms + schedule_attempts by result — every BENCH json
     # carries its own diagnosis
@@ -134,6 +140,9 @@ class WorkloadResult:
     conflicts: int = 0
     conflict_rate: float | None = None
     binding_parity: int | None = None
+    # sha256 over the sorted (pod, node) pairs this run bound (fullstack):
+    # two runs bound the same map, pod for pod, iff their digests are equal
+    bindings_digest: str = ""
     lease_transitions: int = 0
     recovery_s: float | None = None
     # telemetry-plane view when a run exported to a collector
@@ -215,14 +224,22 @@ class WorkloadResult:
     # artifact paths written next to the bench JSON when tracing is on:
     # chrome trace, /metrics text, device-side cycle records
     artifacts: dict = field(default_factory=dict)
+    # platform / device_kind / devices of the process that SCHEDULED — a
+    # record from a machine without a chip says so. None = this process
+    # (the in-process runners); the multi-process runner, whose measuring
+    # parent holds no device, copies its scheduler children's banner stamp
+    device: dict | None = None
 
     def to_json(self) -> dict:
+        from .. import device_stamp
+
         out = {
             "case": self.case_name,
             "workload": self.workload_name,
             "metric": "SchedulingThroughput/Average",
             "value": round(self.throughput, 1),
             "unit": "pods/s",
+            **(self.device or device_stamp()),
             "scheduled": self.scheduled,
             "measure_pods": self.measure_pods,
             "duration_s": round(self.duration_s, 3),
@@ -265,6 +282,7 @@ class WorkloadResult:
             out["mesh_shape"] = list(self.mesh_shape)
             if self.collective_wall_s is not None:
                 out["collective_wall_s"] = round(self.collective_wall_s, 6)
+            out["mesh_placement"] = self.mesh_placement
         if self.staged_latency_ms is not None:
             out["staged_latency_ms"] = self.staged_latency_ms
         if self.soak is not None:
@@ -283,12 +301,16 @@ class WorkloadResult:
             out["conflicts"] = self.conflicts
             if self.conflict_rate is not None:
                 out["conflict_rate"] = round(self.conflict_rate, 4)
-            if self.binding_parity is not None:
-                out["binding_parity"] = self.binding_parity
             if self.lease_transitions:
                 out["lease_transitions"] = self.lease_transitions
             if self.recovery_s is not None:
                 out["recovery_s"] = round(self.recovery_s, 3)
+        if self.binding_parity is not None:
+            out["binding_parity"] = self.binding_parity
+        if self.bindings_digest:
+            out["bindings_digest"] = self.bindings_digest
+        if self.compile_misses:
+            out["compile_misses"] = self.compile_misses
         if self.admission_p99_ms is not None:
             out["admission_p99_ms"] = round_latency_ms(self.admission_p99_ms)
             if self.admission_p50_ms is not None:
@@ -510,10 +532,28 @@ def _mesh_stats(sched) -> dict:
     n = 1
     for d in shape:
         n *= d
+    placement = None
+    block = sched._resident.device
+    if sched.mesh is not None and block is not None:
+        import jax
+
+        sent = [0] * n
+        for r in sched.metrics.tpu.records:
+            for i, b in enumerate(r.shard_transfer_bytes or ()):
+                sent[i] += b
+        placement = dict(
+            block_sharded=sched._resident._block_sharded,
+            resident_devices=min(
+                len(leaf.sharding.device_set)
+                for leaf in jax.tree_util.tree_leaves(block)
+            ),
+            shards_with_transfer=sum(1 for b in sent if b),
+        )
     return dict(
         n_devices=n,
         mesh_shape=shape,
         collective_wall_s=sched._collective_wall_s,
+        mesh_placement=placement,
     )
 
 
@@ -2130,6 +2170,18 @@ def run_workload_full_stack(
         informers.pump()
         sched.dispatcher.sync()
         sched._drain_bind_completions()
+        # the store's own view, read back over REST while the apiserver
+        # still serves: measured pods this run bound exactly once whose
+        # stored node is the node the client was acked for, pod for pod
+        stored = {
+            pod.name: pod.node_name for _key, pod in remote.list(PODS)[0]
+        }
+        binds = collections.Counter(name for name, _ in client.bound_pairs)
+        parity = sum(
+            1 for name, node in client.bound_pairs
+            if name.startswith("measure-") and binds[name] == 1
+            and stored.get(name) == node
+        )
         sentinel_report = None
         if sentinel_obj is not None:
             sentinel_report = _sentinel_settle(
@@ -2195,6 +2247,10 @@ def run_workload_full_stack(
         attempts=sched.metrics.schedule_attempts - attempts0,
         cycles=sched.metrics.cycles - cycles0,
         p99_attempt_latency_ms=lat,
+        binding_parity=parity,
+        bindings_digest=hashlib.sha256(
+            repr(sorted(client.bound_pairs)).encode()
+        ).hexdigest()[:16],
         telemetry=telemetry_stats,
         sentinel=sentinel_report,
         metrics_snapshot=sched.metrics.prom.snapshot(baseline=prom_base),
@@ -2492,6 +2548,14 @@ def run_workload_federated(
         lease_transitions=fed.lease_transitions(),
         recovery_s=recovery_s,
     )
+
+
+def _children_device(cluster) -> dict:
+    """The device stamp the scheduler children published on their
+    readiness banners: the measuring parent of a multi-process run holds
+    no device, so its records carry what the schedulers held."""
+    banner = cluster.schedulers[0].banner or {}
+    return {k: banner.get(k) for k in ("platform", "device_kind", "devices")}
 
 
 def _scrape_metrics(url: str):
@@ -2880,6 +2944,7 @@ def run_workload_multiprocess(
         ),
         replication_chain=replication_chain,
         leader_replication_bytes=leader_rep_bytes,
+        device=_children_device(cluster),
     )
 
 
@@ -3214,6 +3279,7 @@ def run_trace_multiprocess(
         n_processes=n_processes,
         child_stats=child_stats,
         restarts=restarts,
+        device=_children_device(cluster),
         admission_p50_ms=p50,
         admission_p99_ms=p99,
         slo_budget_ms=profile.slo_budget_ms,

@@ -61,7 +61,10 @@ from typing import Any
 
 import numpy as np
 
+from .. import klog
 from .. import names as N
+
+_log = klog.get_logger("kubetpu.sched.flightrecorder")
 
 #: how the fused device filter decomposes for attribution: the component
 #: order of ``runtime.filter_components``. The static mask fuses the
@@ -181,7 +184,11 @@ class FlightRecorder:
         max_e2e_samples: int = 65536,
         top_k: int = 3,
         replica: str = "",
+        failure_counter=None,
     ) -> None:
+        """``failure_counter``: the owning scheduler's
+        ``scheduler_explain_kernel_failures_total`` child — every
+        explain-kernel failure is counted there, scrape-visible."""
         self.top_k = top_k
         # federation stamp: every decision record carries the scheduler
         # replica that made it ("" in single-scheduler mode) so a
@@ -207,6 +214,7 @@ class FlightRecorder:
         )
         self.breakdown_failures = 0     # explain-kernel errors (soft-off)
         self._breakdown_ok = True
+        self._failure_counter = failure_counter
         self._seq = itertools.count()
         # the previous cycle's dispatched-but-unfetched explain kernel:
         # (device summary pytree, device masks or None, records, node
@@ -294,12 +302,8 @@ class FlightRecorder:
                     # example rejected nodes (the all-feasible steady
                     # state never pays this)
                     masks_dev = _explain_masks_kernel(device_batch, params)
-            except Exception:
-                # never break the cycle for diagnostics; stop retrying a
-                # shape/backend the kernel cannot handle
-                self.breakdown_failures += 1
-                if self.breakdown_failures >= 3:
-                    self._breakdown_ok = False
+            except Exception as e:
+                self._note_breakdown_failure(e)
         recs: list = []
         for k, info in enumerate(batch_infos):
             j = int(idx[k])
@@ -357,10 +361,39 @@ class FlightRecorder:
                 rec.update(self._pod_breakdown(
                     k, j, summary, comp_masks, node_names, n_real
                 ))
-        except Exception:
-            self.breakdown_failures += 1
-            if self.breakdown_failures >= 3:
-                self._breakdown_ok = False
+        except Exception as e:
+            self._note_breakdown_failure(e)
+
+    def _note_breakdown_failure(self, exc: Exception) -> None:
+        """Diagnostics never break the cycle, and a kernel that keeps
+        failing stops being retried after three — but a shape or backend
+        the explain kernel cannot handle must be SEEN: the first failure
+        is logged with its exception and every one is counted
+        (``scheduler_explain_kernel_failures_total`` on /metrics)."""
+        self.breakdown_failures += 1
+        if self._failure_counter is not None:
+            self._failure_counter.inc()
+        if self.breakdown_failures == 1:
+            _log.error(
+                "explain kernel failed; decision records lose their "
+                "score/filter breakdown",
+                err=f"{type(exc).__name__}: {exc}",
+            )
+        if self.breakdown_failures >= 3:
+            self._breakdown_ok = False
+
+    def warm(self, device_batch, params, assignments) -> None:
+        """Compile and run the explain kernel for this batch shape ahead of
+        the hot loop (``Scheduler.warmup``), under the same failure
+        accounting as a live cycle."""
+        import jax
+
+        try:
+            jax.block_until_ready(
+                _explain_kernel(device_batch, params, assignments)[0]
+            )
+        except Exception as e:
+            self._note_breakdown_failure(e)
 
     @staticmethod
     def _fetch_summary(summary_dev):

@@ -357,7 +357,8 @@ class Scheduler:
             from .flightrecorder import FlightRecorder
 
             self.flight_recorder: "FlightRecorder | None" = FlightRecorder(
-                replica=replica_id
+                replica=replica_id,
+                failure_counter=self.metrics.prom.explain_kernel_failures,
             )
         else:
             self.flight_recorder = None
@@ -391,11 +392,9 @@ class Scheduler:
 
             # one-shot cross-shard reduction probe: the collective tax this
             # mesh pays per argmax, exposed as a gauge next to the per-cycle
-            # kernel walls (MULTICHIP evidence carries its own context)
-            try:
-                self._collective_wall_s = measure_collective_wall(self.mesh)
-            except Exception:
-                self._collective_wall_s = None
+            # kernel walls (MULTICHIP evidence carries its own context). A
+            # mesh that cannot run one argmax cannot run the engines: raise
+            self._collective_wall_s = measure_collective_wall(self.mesh)
         # --- pipeline state (see class docstring of _InflightCycle) ------
         self.pipeline = bool(pipeline)
         # the device-resident node block serves the SERIAL loop too (PR 2
@@ -935,14 +934,7 @@ class Scheduler:
             if self.flight_recorder is not None and self.mesh is None:
                 # warm the recorder's explain kernel for the same shape —
                 # the first measured cycle must not pay its compile
-                try:
-                    from .flightrecorder import _explain_kernel
-
-                    jax.block_until_ready(
-                        _explain_kernel(batch.device, params, a)[0]
-                    )
-                except Exception:
-                    pass
+                self.flight_recorder.warm(batch.device, params, a)
 
     def prewarm(self, max_pods: int | None = None) -> None:
         """Warm the bucket ladder with synthetic constraint-free pods (the
@@ -952,6 +944,14 @@ class Scheduler:
         node set, so the first real cycles never stall on XLA."""
         from ..api.wrappers import make_pod
 
+        self._snapshot = self.cache.update_snapshot(self._snapshot)
+        if not self._snapshot.nodes:
+            # every program is shaped by the node axis: against a cluster
+            # with no nodes yet there is nothing to warm that a real cycle
+            # would reuse (on the v5e those sixteen throwaway programs cost
+            # minutes cold and `kubetpu up --prewarm` missed its readiness
+            # timeout); the first real cycles compile, as without the flag
+            return
         n = min(max_pods or self.max_batch, self.max_batch)
         pods = [
             make_pod(f"prewarm-{i}", namespace="kubetpu-prewarm",

@@ -286,18 +286,32 @@ def greedy(
     check_interpod: bool = False,
     hard_weight: int = 1,
     tie_rng=None,
+    nominated: dict[str, list[t.Pod]] | None = None,
 ) -> list[str | None]:
     """The per-pod greedy loop: Filter → Score → Normalize → weighted sum →
-    first-max selectHost → assume (NodeInfo.add_pod). Mutates ``infos``."""
+    first-max selectHost → assume (NodeInfo.add_pod). Mutates ``infos``.
+
+    ``nominated`` ({node name: pods nominated there}, mutated): the fit
+    filter sees each node WITH the pods nominated to it whose priority is
+    >= the filtered pod's (RunFilterPluginsWithNominatedPods, fit
+    dimension); scores see the node as it is. A nominee this loop assigns
+    stops being charged (nominations are deleted at assume,
+    schedule_one.go:307)."""
     resources = resources or [(t.CPU, 1), (t.MEMORY, 1)]
     out: list[str | None] = []
     for pod in pods:
+        # the spread / inter-pod maps depend on the pod and the cluster,
+        # never on the candidate node: built once per pod, not once per
+        # (pod, node) — what keeps the oracle usable at 5000 nodes
+        sp_state = _spread_filter_state(pod, infos) if check_spread else None
+        ip_state = _interpod_filter_state(pod, infos) if check_interpod else None
         feas = [
             (not check_static or static_feasible(pod, info))
-            and fits(pod, info)
+            and fits(pod, _with_nominated(pod, info, nominated))
             and (not check_ports or ports_ok(pod, info))
-            and (not check_spread or spread_filter(pod, infos, info))
-            and (not check_interpod or interpod_filter(pod, infos, info))
+            and (not check_spread or spread_filter(pod, infos, info, sp_state))
+            and (not check_interpod
+                 or interpod_filter(pod, infos, info, ip_state))
             for info in infos
         ]
         if not any(feas):
@@ -344,7 +358,24 @@ def greedy(
             best = ties[int(tie_rng.integers(0, len(ties)))]
         infos[best].add_pod(pod.with_node(infos[best].node.name))
         out.append(infos[best].node.name)
+        for noms in (nominated or {}).values():
+            noms[:] = [n for n in noms if n.uid != pod.uid]
     return out
+
+
+def _with_nominated(pod: t.Pod, info: NodeInfo, nominated) -> NodeInfo:
+    """The node as the fit filter sees it: plus the pods nominated to it
+    with priority >= ``pod``'s (never ``pod`` itself)."""
+    extra = [
+        n for n in (nominated or {}).get(info.node.name, ())
+        if n.priority >= pod.priority and n.uid != pod.uid
+    ]
+    if not extra:
+        return info
+    view = info.clone()
+    for n in extra:
+        view.add_pod(n.with_node(info.node.name))
+    return view
 
 
 # --- PodTopologySpread (plugins/podtopologyspread) -------------------------
@@ -397,19 +428,17 @@ def _spread_counts(pod: t.Pod, infos, c, key_set):
     return m
 
 
-def spread_filter(pod: t.Pod, infos, info_j: NodeInfo) -> bool:
-    """filtering.go:314 Filter for one candidate node."""
+def _spread_filter_state(pod: t.Pod, infos) -> list:
+    """Per hard constraint: (constraint, {domain: count}, min_match,
+    self_match) — everything of filtering.go:314 that does not depend on
+    the candidate node."""
     hard = [
         c for c in pod.topology_spread_constraints
         if c.when_unsatisfiable == t.UnsatisfiableConstraintAction.DO_NOT_SCHEDULE
     ]
-    if not hard:
-        return True
     key_set = frozenset(c.topology_key for c in hard)
-    labels_j = info_j.node.labels_dict()
+    state = []
     for c in hard:
-        if c.topology_key not in labels_j:
-            return False
         m = _spread_counts(pod, infos, c, key_set)
         min_domains = c.min_domains if c.min_domains is not None else 1
         if len(m) < min_domains:
@@ -417,6 +446,20 @@ def spread_filter(pod: t.Pod, infos, info_j: NodeInfo) -> bool:
         else:
             min_match = min(m.values()) if m else 0
         self_match = 1 if _sel_matches(c.selector, pod.labels_dict()) else 0
+        state.append((c, m, min_match, self_match))
+    return state
+
+
+def spread_filter(pod: t.Pod, infos, info_j: NodeInfo, state=None) -> bool:
+    """filtering.go:314 Filter for one candidate node. ``state``: a
+    ``_spread_filter_state(pod, infos)`` computed against the same
+    ``infos`` (None = compute it here)."""
+    if state is None:
+        state = _spread_filter_state(pod, infos)
+    labels_j = info_j.node.labels_dict()
+    for c, m, min_match, self_match in state:
+        if c.topology_key not in labels_j:
+            return False
         match_num = m.get(labels_j[c.topology_key], 0)
         if match_num + self_match - min_match > c.max_skew:
             return False
@@ -521,12 +564,15 @@ def _pref_anti(pod):
     return a.preferred if a else ()
 
 
-def interpod_filter(pod: t.Pod, infos, info_j: NodeInfo) -> bool:
-    """filtering.go:364-419 with maps built from scratch (calPreFilterState)."""
+def _interpod_filter_state(pod: t.Pod, infos) -> tuple:
+    """(existing_anti, anti_counts, aff_counts): the calPreFilterState maps
+    of filtering.go:364-419, built from scratch — none depends on the
+    candidate node."""
     aff_terms = _req_aff(pod)
     anti_terms = _req_anti(pod)
-    # existingAntiAffinityCounts
     existing_anti: dict[tuple, int] = {}
+    anti_counts: dict[tuple, int] = {}
+    aff_counts: dict[tuple, int] = {}
     for info in infos:
         labels_n = info.node.labels_dict()
         for ex in info.pods.values():
@@ -537,40 +583,45 @@ def interpod_filter(pod: t.Pod, infos, info_j: NodeInfo) -> bool:
                         existing_anti[(term.topology_key, v)] = (
                             existing_anti.get((term.topology_key, v), 0) + 1
                         )
+            for term in anti_terms:
+                if _term_matches(term, pod.namespace, ex):
+                    v = labels_n.get(term.topology_key)
+                    if v is not None:
+                        anti_counts[(term.topology_key, v)] = (
+                            anti_counts.get((term.topology_key, v), 0) + 1
+                        )
+            if aff_terms and all(
+                _term_matches(tm, pod.namespace, ex) for tm in aff_terms
+            ):
+                for term in aff_terms:
+                    v = labels_n.get(term.topology_key)
+                    if v is not None:
+                        aff_counts[(term.topology_key, v)] = (
+                            aff_counts.get((term.topology_key, v), 0) + 1
+                        )
+    return existing_anti, anti_counts, aff_counts
+
+
+def interpod_filter(pod: t.Pod, infos, info_j: NodeInfo, state=None) -> bool:
+    """filtering.go:364-419 for one candidate node. ``state``: an
+    ``_interpod_filter_state(pod, infos)`` computed against the same
+    ``infos`` (None = compute it here)."""
+    existing_anti, anti_counts, aff_counts = (
+        state if state is not None else _interpod_filter_state(pod, infos)
+    )
+    aff_terms = _req_aff(pod)
     labels_j = info_j.node.labels_dict()
+    # existingAntiAffinityCounts
     for k, v in labels_j.items():
         if existing_anti.get((k, v), 0) > 0:
             return False
     # incoming anti-affinity
-    if anti_terms:
-        anti_counts: dict[tuple, int] = {}
-        for info in infos:
-            labels_n = info.node.labels_dict()
-            for ex in info.pods.values():
-                for term in anti_terms:
-                    if _term_matches(term, pod.namespace, ex):
-                        v = labels_n.get(term.topology_key)
-                        if v is not None:
-                            anti_counts[(term.topology_key, v)] = (
-                                anti_counts.get((term.topology_key, v), 0) + 1
-                            )
-        for term in anti_terms:
-            v = labels_j.get(term.topology_key)
-            if v is not None and anti_counts.get((term.topology_key, v), 0) > 0:
-                return False
+    for term in _req_anti(pod):
+        v = labels_j.get(term.topology_key)
+        if v is not None and anti_counts.get((term.topology_key, v), 0) > 0:
+            return False
     # incoming affinity
     if aff_terms:
-        aff_counts: dict[tuple, int] = {}
-        for info in infos:
-            labels_n = info.node.labels_dict()
-            for ex in info.pods.values():
-                if all(_term_matches(tm, pod.namespace, ex) for tm in aff_terms):
-                    for term in aff_terms:
-                        v = labels_n.get(term.topology_key)
-                        if v is not None:
-                            aff_counts[(term.topology_key, v)] = (
-                                aff_counts.get((term.topology_key, v), 0) + 1
-                            )
         pods_exist = True
         for term in aff_terms:
             v = labels_j.get(term.topology_key)

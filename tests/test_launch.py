@@ -254,6 +254,13 @@ def test_mp_cluster_replica_kill_respawn_cascade_and_fsck(tmp_path):
         persistence=wal_dir, env=CPU_ENV, cwd=REPO,
     )
     with cluster:
+        # whoever launches a scheduler sees what it holds, and which store
+        # core its apiserver serves from, on the readiness banners
+        for sched in cluster.schedulers:
+            assert sched.banner["platform"] == "cpu"
+            assert sched.banner["device_kind"] and sched.banner["devices"] >= 1
+        assert cluster.apiserver_children[0].banner["store_core"] in (
+            "native", "python")
         admin = RemoteStore(cluster.api_url)
         for i in range(4):
             admin.create("nodes", f"n{i}",
@@ -339,6 +346,8 @@ def test_run_workload_multiprocess_joins_on_parity():
     # CI/bench hygiene: per-child peak RSS + cpu_seconds in the record
     doc = r.to_json()
     assert doc["n_processes"] == 3
+    # the device stamp is the scheduler CHILDREN's (the parent holds none)
+    assert doc["platform"] == "cpu" and doc["devices"] >= 1
     stats = doc["child_stats"]
     assert set(stats) == {"apiserver", "scheduler-r0", "scheduler-r1"}
     for child in stats.values():
