@@ -415,7 +415,7 @@ class RemoteStore:
                     # same trace id + this span id as its parent
                     self._tracer.record(
                         f"rpc.{method}", start=t_span,
-                        end=_time.perf_counter(),
+                        end=_time.perf_counter(), per_item=True,
                         path=path.partition("?")[0], status=status,
                         trace_id=ctx.trace_id, span_id=ctx.span_id,
                     )

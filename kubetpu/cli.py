@@ -749,11 +749,19 @@ def _retry_start(fn, what: str) -> None:
             time.sleep(2.0)
 
 
-def _make_loop(run_once, period_s: float = 0.05, stop=None):
+def _make_loop(run_once, period_s: float = 0.05, stop=None, clock=None):
     """Component work loop; ``stop`` (an Event from
     ``_install_stop_event``) makes SIGTERM a graceful exit through the
-    caller's ``finally`` instead of a mid-cycle kill."""
+    caller's ``finally`` instead of a mid-cycle kill. ``clock`` (the
+    scheduler's ``tracing.PhaseClock``) counts the completed iterations
+    and takes the sleeps as its ``sleep`` phase."""
+    import contextlib
     import time
+
+    def sleep(seconds: float) -> None:
+        with (clock.phase("sleep") if clock is not None
+              else contextlib.nullcontext()):
+            time.sleep(seconds)
 
     def loop() -> int:
         try:
@@ -765,13 +773,53 @@ def _make_loop(run_once, period_s: float = 0.05, stop=None):
                     # restart must not kill the component
                     print(f"apiserver unavailable, retrying: {e}",
                           file=sys.stderr, flush=True)
-                    time.sleep(2.0)
+                    sleep(2.0)
                     continue
-                time.sleep(period_s)
+                if clock is not None:
+                    clock.iteration_done()
+                sleep(period_s)
         except KeyboardInterrupt:
             pass
         return 0
     return loop
+
+
+def _scheduler_iteration(sched, informers, is_leader=lambda: True,
+                         membership=None):
+    """One iteration of the served scheduler loop, as a callable for
+    ``_make_loop``: pump the informers, run a cycle, drain the bind
+    completions. An iteration that delivered an event, popped a pod or took
+    a completion leaves a ``loop-iteration`` span with ``pump``, the
+    cycle's spans and ``drain`` under it; an idle one leaves none (its time
+    is in the phase clock's counters)."""
+    tracer, clock = sched.tracer, sched.loop_clock
+
+    def pump() -> int:
+        with tracer.span("pump") as sp:
+            rpc_s0 = clock.seconds["pump_rpc"]
+            deliveries = informers.pump()
+            if sp is not None:
+                sp.discard = not deliveries
+                sp.attrs.update(
+                    deliveries=deliveries,
+                    rpc_s=round(clock.seconds["pump_rpc"] - rpc_s0, 6),
+                )
+        return deliveries
+
+    def once() -> None:
+        # the envelope is long by design: no LogIfLong line per iteration
+        with tracer.span("loop-iteration", log_long=False) as sp:
+            work0 = sched.loop_work
+            deliveries = 0
+            if is_leader():
+                if membership is not None:
+                    membership.tick(sched)
+                deliveries = pump()
+                sched.schedule_batch()
+                sched._drain_bind_completions()
+            if sp is not None:
+                sp.discard = not (deliveries or sched.loop_work != work0)
+    return once
 
 
 def _maybe_elect(args, store, component: str):
@@ -994,16 +1042,9 @@ def cmd_scheduler(args) -> int:
           )
           + ")", flush=True)
 
-    def once():
-        if not is_leader():
-            return
-        if membership is not None:
-            membership.tick(sched)
-        informers.pump()
-        sched.schedule_batch()
-        sched._drain_bind_completions()
+    once = _scheduler_iteration(sched, informers, is_leader, membership)
     try:
-        return _make_loop(once, stop=stop)()
+        return _make_loop(once, stop=stop, clock=sched.loop_clock)()
     finally:
         if exporter is not None:
             exporter.close()

@@ -17,6 +17,7 @@ aggregated client-side by (regarding, reason, note).
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import time
 from typing import Callable
@@ -24,6 +25,10 @@ from typing import Callable
 from ..api import types as t
 
 EVENTS = "events"
+#: entries the aggregation cache keeps (client-go's events_cache
+#: maxLruCacheEntries): the scheduler records one ``Scheduled`` per pod,
+#: so an unbounded cache grows for as long as the cluster binds pods
+MAX_SEEN = 4096
 
 
 class EventRecorder:
@@ -38,8 +43,12 @@ class EventRecorder:
         self.store = store
         self.controller = controller
         self.clock = clock if clock is not None else time.time
-        # (regarding, reason, note) -> event key (the aggregation cache)
-        self._seen: dict[tuple[str, str, str], str] = {}
+        # (regarding, reason, note) -> event key: the aggregation cache,
+        # least recently used first, at most MAX_SEEN entries. A lost entry
+        # only means that a repeat starts a new series
+        self._seen: "collections.OrderedDict[tuple[str, str, str], str]" = (
+            collections.OrderedDict()
+        )
         self.dropped = 0   # store-write failures (best-effort contract)
 
     def event(
@@ -52,6 +61,7 @@ class EventRecorder:
         key = self._seen.get(sig)
         try:
             if key is not None:
+                self._seen.move_to_end(sig)
                 current, rv = self.store.get(EVENTS, key)
                 if current is not None:
                     import dataclasses
@@ -76,6 +86,8 @@ class EventRecorder:
             )
             self.store.update(EVENTS, ev.key, ev)   # upsert
             self._seen[sig] = ev.key
+            if len(self._seen) > MAX_SEEN:
+                self._seen.popitem(last=False)
         except Exception:
             # an event write must never break the action it annotates
             self.dropped += 1

@@ -10,7 +10,10 @@ flow back through the informers (level-triggered reconciliation, the same
 all-state-through-the-API-server shape as the reference; SURVEY §1).
 
 Pump-driven: ``pump()`` steps every reflector once; callers interleave it
-with ``schedule_batch`` (the informer goroutines folded into the loop).
+with ``schedule_batch`` (the informer goroutines folded into the loop). The
+pump times itself on the scheduler's phase clock (``tracing.PhaseClock``):
+``pump_rpc`` while blocked on the watch poll, ``pump_apply`` while
+delivering. Numbers go up to the scheduler; its tracer does not come down.
 """
 
 from __future__ import annotations
@@ -208,8 +211,12 @@ class SchedulerInformers:
         self, store: MemStore, sched: Any, bulk: bool = True,
         pod_filter: "Any | None" = None,
     ) -> None:
+        from ..tracing import PhaseClock
+
         self.store = store
         self.sched = sched
+        # the scheduler's own clock; a stand-in scheduler gets a private one
+        self._clock = getattr(sched, "loop_clock", None) or PhaseClock()
         self._bulk = bulk and hasattr(store, "watch_bulk")
         self._reflectors: list[Reflector] = []
         s = sched
@@ -274,14 +281,18 @@ class SchedulerInformers:
         poll; any reflector the batched path cannot serve (not yet synced,
         scoped, or pull-only watcher) falls the whole pump back to
         per-kind stepping."""
-        if self._bulk:
-            pumped = self._pump_bulk()
-            if pumped is not None:
-                return pumped
-        total = 0
-        for r in self._reflectors:
-            total += r.step()
-        return total
+        with self._clock.phase("pump_apply"):
+            if self._bulk:
+                pumped = self._pump_bulk()
+                if pumped is not None:
+                    return pumped
+            total = 0
+            for r in self._reflectors:
+                total += r.step(polling=self._polling)
+            return total
+
+    def _polling(self):
+        return self._clock.phase("pump_rpc")
 
     def _pump_bulk(self) -> int | None:
         """One batched watch poll for every reflector's cursor. None =
@@ -295,7 +306,8 @@ class SchedulerInformers:
                 return None
             cursors[r.informer.kind] = w.resource_version
         try:
-            buckets = self.store.watch_bulk(cursors)
+            with self._polling():
+                buckets = self.store.watch_bulk(cursors)
         except ConnectionError:
             # transient transport failure: same retry-next-pump shape as
             # Reflector.step's

@@ -18,6 +18,7 @@ owners fold it into their loops (the framework's no-goroutine shape).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Protocol
 
 from ..store.memstore import (
@@ -205,14 +206,16 @@ class Reflector:
             return
         self._watcher = self._store.watch(self.informer.kind, rv, **kwargs)
 
-    def step(self) -> int:
+    def step(self, polling=contextlib.nullcontext) -> int:
         """Drain available watch events; relist on compaction. Returns the
-        number of deliveries dispatched."""
+        number of deliveries dispatched. ``polling()`` is entered around
+        the blocking poll alone (the scheduler's informers time it)."""
         if self._watcher is None:
             self.sync()
             return len(self.informer.store)
         try:
-            events = self._watcher.poll()
+            with polling():
+                events = self._watcher.poll()
         except CompactedError:
             # reflector.go: watch too old → full relist
             self.note_relist()

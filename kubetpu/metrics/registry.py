@@ -95,6 +95,13 @@ class Counter(_Metric):
         with self._lock:
             self.value += amount
 
+    def set_total(self, value: float) -> None:
+        """For a counter whose OWNER keeps the monotonic total (the loop's
+        phase clock, on a thread that must take no lock) and hands it over
+        at scrape time."""
+        with self._lock:
+            self.value = value
+
     def samples(self):
         if self.label_names:
             for key, child in self._children_snapshot():

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import math
 
+from ..tracing import LOOP_PHASES
 from .registry import Histogram, Registry, exponential_buckets
 
 #: the per-pod staged latency attribution vector (sched.flightrecorder):
@@ -228,9 +229,46 @@ class SchedulerMetricsRegistry:
             labels=("event",),
         )
 
+        # --- the served loop's phase clock (tracing.PhaseClock) -----------
+        # set at scrape time from PhaseClock.snapshot(): the loop thread
+        # owns the totals and takes no lock. Every phase is a series from
+        # the first scrape, at zero, so a delta never meets a missing one.
+        self.loop_phase_seconds = r.counter(
+            "scheduler_loop_phase_seconds_total",
+            "Wall seconds of the scheduler's loop thread by phase; the "
+            "phases are self times and sum to the elapsed time (the "
+            "running phase's elapsed part is included at scrape time). "
+            "Phases: " + ", ".join(LOOP_PHASES) + ".",
+            labels=("phase",),
+            declared={"phase": LOOP_PHASES},
+        )
+        self.loop_phase_entries = r.counter(
+            "scheduler_loop_phase_entries_total",
+            "Times the loop thread entered each phase: Event writes for "
+            "events, watch polls for pump_rpc.",
+            labels=("phase",),
+            declared={"phase": LOOP_PHASES},
+        )
+        self.loop_iterations = r.counter(
+            "scheduler_loop_iterations_total",
+            "Completed iterations of the served loop (pump, "
+            "schedule_batch, drain), idle ones included.",
+        )
+        for phase in LOOP_PHASES:
+            self.loop_phase_seconds.labels(phase)
+            self.loop_phase_entries.labels(phase)
+
     def set_dispatcher_stats(self, stats: dict) -> None:
         for event, value in stats.items():
             self.api_dispatcher_calls.labels(event).set(value)
+
+    def set_loop_clock(self, snapshot: tuple) -> None:
+        seconds, entries, iterations = snapshot
+        for phase, value in seconds.items():
+            self.loop_phase_seconds.labels(phase).set_total(value)
+        for phase, value in entries.items():
+            self.loop_phase_entries.labels(phase).set_total(value)
+        self.loop_iterations.set_total(iterations)
 
     def expose(self) -> str:
         return self.registry.expose()

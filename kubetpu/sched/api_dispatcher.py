@@ -352,7 +352,7 @@ class APIDispatcher:
         pod = getattr(call, "pod", None)
         tr.record(
             f"api.{call.call_type}", start=t0, end=_time.perf_counter(),
-            key=call.object_key,
+            per_item=True, key=call.object_key,
             status="error" if err is not None else "ok",
             pod_trace=getattr(pod, "trace_id", "") or "",
         )

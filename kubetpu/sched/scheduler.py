@@ -326,13 +326,22 @@ class Scheduler:
             initial_backoff_seconds=self.cfg.pod_initial_backoff_seconds,
             max_backoff_seconds=self.cfg.pod_max_backoff_seconds,
         )
-        from ..tracing import Tracer
+        from ..tracing import PhaseClock, Tracer
 
         # cycle tracing (utiltrace analog): top-level span per profile
         # cycle; >100ms cycles log their step breakdown
         # (schedule_one.go:566-567's LogIfLong). Created BEFORE the
         # dispatcher so its call-type spans land in the same buffer
         self.tracer = Tracer()
+        # where the loop thread's time goes, by phase (always on; read at
+        # scrape time into scheduler_loop_phase_*_total). Whoever drives
+        # this scheduler's loop switches it: schedule_batch and the drain
+        # here, the informers' pump, cli.py's sleep
+        self.loop_clock = PhaseClock()
+        # pods popped plus bind completions taken, ever: the served loop
+        # reads the difference to tell an iteration that did something
+        # (and earns a span) from an idle one
+        self.loop_work = 0
         self.dispatcher = APIDispatcher(
             client, workers=dispatcher_workers, bulk=bulk,
             tracer=self.tracer,
@@ -978,15 +987,16 @@ class Scheduler:
         The cycle boundary is the dispatcher's micro-batch window: every
         API write the cycle enqueued (binds, status patches, victim
         deletes) is flushed as per-call-type bulk RPCs on the way out."""
-        try:
-            return self._schedule_batch_inner(max_batch)
-        finally:
-            self.dispatcher.flush()
-            if self.sentinel is not None:
-                # the sentinel rides the cycle boundary: at most one rule
-                # evaluation per interval, on the owner's thread (the
-                # SentinelOverhead bench pair prices exactly this)
-                self.sentinel.maybe_evaluate()
+        with self.loop_clock.phase("cycle"):
+            try:
+                return self._schedule_batch_inner(max_batch)
+            finally:
+                self.dispatcher.flush()
+                if self.sentinel is not None:
+                    # the sentinel rides the cycle boundary: at most one
+                    # rule evaluation per interval, on the owner's thread
+                    # (the SentinelOverhead bench pair prices exactly this)
+                    self.sentinel.maybe_evaluate()
 
     def _schedule_batch_inner(
         self, max_batch: int | None = None
@@ -1011,6 +1021,7 @@ class Scheduler:
             from .podgroup import schedule_pod_groups
 
             res = schedule_pod_groups(self, budget=limit)
+            self.loop_work += res["scheduled"] + res["unschedulable"]
             self.metrics.note_unschedulable(res["unschedulable"])
             return res
         if self.pipeline:
@@ -1030,6 +1041,7 @@ class Scheduler:
         cycle_id = self.metrics.cycles + 1
         t_pop = time.perf_counter()
         batch_infos = self.queue.pop_batch(limit)
+        self.loop_work += len(batch_infos)
         if batch_infos:
             self.tracer.record(
                 "queue-pop", start=t_pop, end=time.perf_counter(),
@@ -1448,17 +1460,6 @@ class Scheduler:
             prom.framework_extension_point_duration.labels(
                 "Filter+Score", "Success", profile.name
             ).observe(kernel_wall_s)
-            cycle_attrs = dict(
-                cycle=cycle_id, profile=profile.name,
-                pods=len(batch_infos), pipelined=inflight.pipelined,
-                off_stack=False,
-            )
-            if self.mesh_shape:
-                cycle_attrs["mesh"] = "x".join(map(str, self.mesh_shape))
-            self.tracer.record(
-                "scheduling-cycle", start=inflight.t_start,
-                end=time.perf_counter(), **cycle_attrs,
-            )
             self._cycle_ctx = (
                 batch, inflight.params, inflight.final_state,
                 {info.key: k for k, info in enumerate(batch_infos)},
@@ -1468,24 +1469,26 @@ class Scheduler:
                     # one decision record per pod, with the cycle-start
                     # score/filter breakdown (skipped under a mesh: the
                     # sharded batch is not re-evaluated for diagnostics)
-                    self.flight_recorder.note_cycle(
-                        batch=batch,
-                        device_batch=inflight.device_batch,
-                        params=inflight.params,
-                        batch_infos=batch_infos,
-                        idx=idx,
-                        cycle_id=cycle_id,
-                        profile=profile.name,
-                        encode_s=inflight.encode_s,
-                        kernel_s=kernel_wall_s,
-                        breakdown=self.mesh is None,
-                        engine=self.engine,
-                        objective_value=objective_value,
-                        solver_iters=solver_iters,
-                        skipped_reason=(
-                            None if self.mesh is None else "mesh"
-                        ),
-                    )
+                    with self.loop_clock.phase("explain"), \
+                            self.tracer.span("explain", cycle=cycle_id):
+                        self.flight_recorder.note_cycle(
+                            batch=batch,
+                            device_batch=inflight.device_batch,
+                            params=inflight.params,
+                            batch_infos=batch_infos,
+                            idx=idx,
+                            cycle_id=cycle_id,
+                            profile=profile.name,
+                            encode_s=inflight.encode_s,
+                            kernel_s=kernel_wall_s,
+                            breakdown=self.mesh is None,
+                            engine=self.engine,
+                            objective_value=objective_value,
+                            solver_iters=solver_iters,
+                            skipped_reason=(
+                                None if self.mesh is None else "mesh"
+                            ),
+                        )
                 except Exception:
                     pass    # diagnostics must never fail the cycle
         except Exception:
@@ -1494,15 +1497,33 @@ class Scheduler:
 
         scheduled = 0
         failed: list[QueuedPodInfo] = []
-        for k, info in enumerate(batch_infos):
-            j = int(idx[k])
-            self.metrics.note_attempts()
-            if 0 <= j < len(batch.node_names):
-                if self._assume_and_bind(info, batch.node_names[j]):
-                    scheduled += 1
-                # a Reserve/Permit rejection already requeued the pod
-            else:
-                failed.append(info)
+        with self.loop_clock.phase("bind_dispatch"), self.tracer.span(
+            "bind-dispatch", cycle=cycle_id, pods=len(batch_infos)
+        ):
+            for k, info in enumerate(batch_infos):
+                j = int(idx[k])
+                self.metrics.note_attempts()
+                if 0 <= j < len(batch.node_names):
+                    if self._assume_and_bind(info, batch.node_names[j]):
+                        scheduled += 1
+                    # a Reserve/Permit rejection already requeued the pod
+                else:
+                    failed.append(info)
+        # the cycle's span ends where its histogram's observation does:
+        # it covers explain and bind-dispatch, not the failure handling
+        cycle_attrs = dict(
+            cycle=cycle_id, profile=profile.name,
+            pods=len(batch_infos), pipelined=inflight.pipelined,
+        )
+        if self.mesh_shape:
+            cycle_attrs["mesh"] = "x".join(map(str, self.mesh_shape))
+        self.tracer.record(
+            "scheduling-cycle", start=inflight.t_start,
+            end=time.perf_counter(), parent_id=self.tracer.current_id,
+            # a pipelined cycle straddles two loop iterations: it cannot
+            # nest on the loop's lane and rides one of its own
+            off_stack=inflight.pipelined, **cycle_attrs,
+        )
         self.metrics.note_scheduled(scheduled)
         self.metrics.note_unschedulable(len(failed))
         # active cycle time = launch half + finish half: in pipeline mode
@@ -1713,9 +1734,40 @@ class Scheduler:
             else:
                 self._dispatch_bind(wp.info, assumed)
 
-    def _drain_bind_completions(self) -> None:
+    def _drain_bind_completions(self) -> int:
         """Bind results re-enter the loop thread here (the reference handles
-        this in the per-pod binding goroutine; we serialize into the cycle)."""
+        this in the per-pod binding goroutine; we serialize into the cycle).
+        Returns how many completions it took; a drain that found none
+        records no span."""
+        clock = self.loop_clock
+        with clock.phase("drain"), self.tracer.span("drain") as sp:
+            events0 = clock.entries["events"]
+            events_s0 = clock.seconds["events"]
+            completions = self._apply_bind_completions()
+            self.loop_work += completions
+            if sp is not None:
+                sp.discard = not completions
+                sp.attrs.update(
+                    completions=completions,
+                    events=clock.entries["events"] - events0,
+                    events_s=round(clock.seconds["events"] - events_s0, 6),
+                )
+        return completions
+
+    def _record_event(
+        self, pod: t.Pod, reason: str, note: str, type: str = "Normal"
+    ) -> None:
+        """Every Event the scheduler writes goes through here: the write is
+        a phase of its own (``events``), whatever phase it interrupts."""
+        if self.recorder is None:
+            return
+        with self.loop_clock.phase("events"):
+            self.recorder.event(
+                f"Pod/{pod.namespace}/{pod.name}", reason, note, type=type
+            )
+
+    def _apply_bind_completions(self) -> int:
+        completions = 0
         while True:
             try:
                 info, assumed, err, t_dispatch, t_exec, t_done = (
@@ -1723,12 +1775,14 @@ class Scheduler:
                 )
             except IndexError:
                 break
+            completions += 1
             if isinstance(err, CallSkipped):
                 continue  # superseded bind: the newer call's completion rules
             # the bind ran off-thread: record its dispatch→completion span
-            # here on the loop thread, joined to the cycle by cycle id
+            # here on the loop thread, joined to the cycle by cycle id (one
+            # per POD: the per-item ring)
             self.tracer.record(
-                "bind", start=t_dispatch, end=t_done,
+                "bind", start=t_dispatch, end=t_done, per_item=True,
                 cycle=getattr(info, "cycle_id", 0), pod=info.key,
                 status="error" if err is not None else "bound",
                 # the cross-process join key: the collector stitches this
@@ -1755,13 +1809,11 @@ class Scheduler:
             if err is None:
                 self.cache.finish_binding(assumed.uid)
                 self.queue.done(info.key)
-                if self.recorder is not None:
-                    self.recorder.event(
-                        f"Pod/{info.pod.namespace}/{info.pod.name}",
-                        "Scheduled",
-                        f"Successfully assigned {info.key} to "
-                        f"{assumed.node_name}",
-                    )
+                self._record_event(
+                    info.pod, "Scheduled",
+                    f"Successfully assigned {info.key} to "
+                    f"{assumed.node_name}",
+                )
             else:
                 # bind failed: roll back the assume and retry as error status
                 # (handleSchedulingFailure, schedule_one.go:1190 analog)
@@ -1795,6 +1847,7 @@ class Scheduler:
                     where = self.queue.add_unschedulable(info, error=True)
                     if fr is not None:
                         fr.note_requeue(info.key, where, error=True)
+        return completions
 
     def _handle_unschedulable(
         self, info: QueuedPodInfo, profile: C.Profile | None = None
@@ -1837,13 +1890,11 @@ class Scheduler:
             self.dispatcher.add(
                 StatusPatchCall(info.pod, reason="Unschedulable")
             )
-            if self.recorder is not None:
-                self.recorder.event(
-                    f"Pod/{info.pod.namespace}/{info.pod.name}",
-                    "FailedScheduling",
-                    "0 nodes are available for the pod's constraints",
-                    type="Warning",
-                )
+            self._record_event(
+                info.pod, "FailedScheduling",
+                "0 nodes are available for the pod's constraints",
+                type="Warning",
+            )
 
     # ------------------------------------------------------------- running
 
@@ -1867,6 +1918,7 @@ class Scheduler:
         executed/errors + bulk batch counts) are folded in at scrape time
         so the DiagnosticsServer surfaces API-write failures."""
         self.metrics.prom.set_dispatcher_stats(self.dispatcher.stats())
+        self.metrics.prom.set_loop_clock(self.loop_clock.snapshot())
         text = self.metrics.prom.expose()
         if self.recorder is not None and hasattr(
             self.recorder, "metrics_text"

@@ -15,7 +15,20 @@ Reference surfaces:
 
 Single-owner like the scheduler loop: span entry/exit runs on the loop
 thread, so the parent stack is a plain list (no contextvars in the hot
-path). Recording one span costs two ``perf_counter`` calls and an append.
+path). Opening a span costs two ``perf_counter`` calls, one ``Span`` and an
+append to one of TWO bounded rings: the loop's ring holds what is recorded
+once per cycle or per loop iteration, the per-item ring what is recorded
+once per pod or per request (``Tracer.record(..., per_item=True)``: the
+``bind`` span of every pod, the client's ``rpc.*``), so a burst of a
+thousand binds cannot evict the cycle that caused them. Readers see both,
+ordered by start.
+
+Beside the spans runs the ``PhaseClock``: the loop thread's wall time
+partitioned into named phases that sum to the elapsed time by construction
+(two dictionary additions and one clock read per switch, always on). The
+spans say WHEN something ran; the phase counters say how much of every
+second each part of the loop took, idle iterations included, which the
+spans leave out on purpose.
 """
 
 from __future__ import annotations
@@ -43,6 +56,10 @@ class Span:
     # a zero-duration marker (Tracer.instant) — exported as a Chrome-trace
     # instant ("i") event instead of a complete span
     instant: bool = False
+    # set inside a ``with tracer.span(...)`` block to drop the span when it
+    # closes: an iteration, pump or drain that found nothing to do records
+    # nothing, or an idle 20 Hz loop would evict every real cycle
+    discard: bool = False
 
     @property
     def duration_s(self) -> float:
@@ -64,17 +81,29 @@ class Tracer:
         self.threshold_s = threshold_s
         self._clock = clock
         self._log = log
+        # the loop's ring, and the ring of spans recorded once per pod or
+        # per request (record(..., per_item=True)), of the same size
         self._spans: collections.deque[Span] = collections.deque(
+            maxlen=max_spans
+        )
+        self._item_spans: collections.deque[Span] = collections.deque(
             maxlen=max_spans
         )
         self._stack: list[Span] = []
         self._ids = itertools.count(1)
 
+    @property
+    def current_id(self) -> int | None:
+        """The id of the innermost open span on the loop thread's stack:
+        the parent for a loop-owned span recorded after the fact."""
+        return self._stack[-1].span_id if self._stack else None
+
     @contextmanager
-    def span(self, name: str, **attrs):
-        """Open a span; yields it so steps can attach attributes. A
-        TOP-LEVEL span exceeding ``threshold_s`` logs its child breakdown
-        (utiltrace's LogIfLong)."""
+    def span(self, name: str, *, log_long: bool = True, **attrs):
+        """Open a span; yields it so steps can attach attributes (or set
+        ``discard``). A TOP-LEVEL span exceeding ``threshold_s`` logs its
+        child breakdown (utiltrace's LogIfLong) unless ``log_long`` is
+        off — the served loop's iteration envelope is long by design."""
         if not self.enabled:
             yield None
             return
@@ -92,9 +121,13 @@ class Tracer:
         finally:
             sp.end = self._clock()
             self._stack.pop()
-            self._spans.append(sp)
-            if parent is None and sp.duration_s >= self.threshold_s:
-                self._log_long(sp)
+            if not sp.discard:
+                self._spans.append(sp)
+                if (
+                    log_long and parent is None
+                    and sp.duration_s >= self.threshold_s
+                ):
+                    self._log_long(sp)
 
     def record(
         self,
@@ -103,6 +136,7 @@ class Tracer:
         end: float,
         parent_id: int | None = None,
         off_stack: bool = True,
+        per_item: bool = False,
         **attrs,
     ) -> Span | None:
         """Record a span whose timing happened OFF the loop thread's span
@@ -111,9 +145,13 @@ class Tracer:
         buffer like any other but never touches the parent stack.
         ``off_stack=False`` places it on the loop lane (tid 1) in the
         Chrome-trace export — for loop-owned phases whose start/end bracket
-        other calls (the pipelined scheduling cycle spans dispatch→sync
-        across two loop iterations), provided the caller guarantees proper
-        nesting with the lane's other spans."""
+        other calls (the serial scheduling cycle, recorded where it ends
+        around its snapshot, encode, explain and bind-dispatch spans),
+        provided the caller guarantees proper nesting with the lane's other
+        spans; ``parent_id=tracer.current_id`` then hangs it under the
+        open span (the loop iteration). ``per_item=True`` is for what
+        is recorded once per POD or per REQUEST: it lands in the per-item
+        ring, so its volume never evicts the loop's spans."""
         if not self.enabled:
             return None
         sp = Span(
@@ -125,7 +163,7 @@ class Tracer:
             attrs=dict(attrs),
             off_stack=off_stack,
         )
-        self._spans.append(sp)
+        (self._item_spans if per_item else self._spans).append(sp)
         return sp
 
     def instant(self, name: str, **attrs) -> Span | None:
@@ -149,16 +187,23 @@ class Tracer:
         return sp
 
     # ---- inspection ------------------------------------------------------
-    def _snapshot_spans(self) -> list[Span]:
-        """Copy the buffer tolerating concurrent appends: a diagnostics
+    @staticmethod
+    def _copy(ring: "collections.deque[Span]") -> list[Span]:
+        """Copy one ring tolerating concurrent appends: a diagnostics
         HTTP thread snapshots while the loop thread records (deque appends
         are atomic, but iterating during an append raises RuntimeError —
         retry instead of locking the hot path)."""
         while True:
             try:
-                return list(self._spans)
+                return list(ring)
             except RuntimeError:
                 continue
+
+    def _snapshot_spans(self) -> list[Span]:
+        """Both rings, ordered by start (ties by id: the order recorded)."""
+        out = self._copy(self._spans) + self._copy(self._item_spans)
+        out.sort(key=lambda s: (s.start, s.span_id))
+        return out
 
     def recent(self, n: int = 100) -> list[Span]:
         return self._snapshot_spans()[-n:]
@@ -169,17 +214,19 @@ class Tracer:
         recorded between the snapshot and the clear (the loop thread
         records while an exporter drains) — those must survive for the
         next drain and for concurrent readers (``/trace``, the flight
-        recorder), so only the snapshotted prefix is popped."""
+        recorder), so only the snapshotted prefix is popped, ring by
+        ring."""
         out = self._snapshot_spans()
         drained = {id(s) for s in out}
-        while True:
-            try:
-                head = self._spans[0]
-            except IndexError:
-                break
-            if id(head) not in drained:
-                break            # a newer span reached the head: stop
-            self._spans.popleft()
+        for ring in (self._spans, self._item_spans):
+            while True:
+                try:
+                    head = ring[0]
+                except IndexError:
+                    break
+                if id(head) not in drained:
+                    break        # a newer span reached the head: stop
+                ring.popleft()
         return out
 
     # ---- export ----------------------------------------------------------
@@ -249,10 +296,13 @@ class Tracer:
         return path
 
     def children_of(self, span: Span) -> list[Span]:
-        return [
-            s for s in self._snapshot_spans()
-            if s.parent_id == span.span_id
-        ]
+        # filter first, order after: the LogIfLong hook calls this on the
+        # loop thread, which should not sort two full rings for it
+        rings = self._copy(self._spans) + self._copy(self._item_spans)
+        return sorted(
+            (s for s in rings if s.parent_id == span.span_id),
+            key=lambda s: (s.start, s.span_id),
+        )
 
     # ---- threshold logging ----------------------------------------------
     def _log_long(self, sp: Span) -> None:
@@ -271,6 +321,83 @@ class Tracer:
             import logging
 
             logging.getLogger("kubetpu.trace").warning(msg)
+
+
+#: the phases of the served loop (``cli.py`` ``cmd_scheduler``): the ONLY
+#: legal values of the {phase} label on scheduler_loop_phase_*_total
+LOOP_PHASES = (
+    "pump_rpc",         # blocked on the watch poll, decoding its reply
+    "pump_apply",       # delivering events into queue and cache; relists
+    "cycle",            # schedule_batch, less explain and bind_dispatch
+    "explain",          # the flight recorder's note_cycle
+    "bind_dispatch",    # assume, Reserve/Permit, handing binds over
+    "drain",            # bind results back into cache and queue
+    "events",           # the Event write (EventRecorder.event)
+    "sleep",            # the loop's period sleep and its back-off
+    "other",            # leader and membership checks, loop overhead
+)
+
+
+class PhaseClock:
+    """The loop thread's wall time, partitioned into ``LOOP_PHASES``.
+
+    Every instant belongs to exactly one phase — the one ``switch`` named
+    last — so the phases are SELF times and sum to the elapsed time by
+    construction. Switched only from the loop thread; ``snapshot`` may be
+    called from any thread (the /metrics scrape) and takes no lock."""
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter):
+        self._clock = clock
+        self.seconds: dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
+        self.entries: dict[str, int] = dict.fromkeys(LOOP_PHASES, 0)
+        #: completed iterations of the served loop (``_make_loop``)
+        self.iterations = 0
+        # (phase, the moment it began): ONE reference, so a reader on
+        # another thread never pairs one phase with another's start
+        self._running: tuple[str, float] = ("other", clock())
+
+    @property
+    def current(self) -> str:
+        return self._running[0]
+
+    def switch(self, phase: str, count: bool = True) -> str:
+        """End the running phase and begin ``phase``; returns the phase
+        that ended. One clock read. ``count=False`` resumes an interrupted
+        phase without counting another entry for it."""
+        now = self._clock()
+        prev, since = self._running
+        # the new phase first, the old one's seconds second: a scrape in
+        # between reads a little too LITTLE, never a counter that later
+        # steps back
+        self._running = (phase, now)
+        self.seconds[prev] += now - since
+        if count:
+            self.entries[phase] += 1
+        return prev
+
+    def iteration_done(self) -> None:
+        """One more completed iteration of the served loop."""
+        self.iterations += 1
+
+    @contextmanager
+    def phase(self, name: str):
+        """Run the block as ``name``, then resume the phase it interrupted
+        (also when the block raises)."""
+        prev = self.switch(name)
+        try:
+            yield
+        finally:
+            self.switch(prev, count=False)
+
+    def snapshot(self) -> tuple[dict[str, float], dict[str, int], int]:
+        """(seconds, entries, iterations) with the running phase's elapsed
+        part added, so that a scrape in the middle of a long phase does
+        not lose it. Lock-free: a read torn by a concurrent ``switch`` is
+        off by one phase slice at most and is made good at the next."""
+        phase, since = self._running
+        seconds = dict(self.seconds)
+        seconds[phase] += max(self._clock() - since, 0.0)
+        return seconds, dict(self.entries), self.iterations
 
 
 @contextmanager
