@@ -5,7 +5,7 @@ kube-scheduler, /root/reference/pkg/scheduler) for TPU hardware: the in-tree
 Filter plugins become boolean-mask kernels and the Score plugins become
 vectorized JAX/XLA kernels over a device-resident ``(pods, nodes)`` tensor;
 the per-pod greedy ``scheduleOne`` loop becomes a single device-resident
-``lax.scan`` (greedy-parity mode) or a capacity-coupled batched assignment
+loop (greedy-parity mode) or a capacity-coupled batched assignment
 (Sinkhorn mode), sharded over a TPU mesh with ``shard_map``/``pjit``.
 
 Subpackages

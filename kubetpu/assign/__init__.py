@@ -2,7 +2,7 @@
 (``selectHost``, pkg/scheduler/schedule_one.go:605) and its one-pod-at-a-time
 outer loop (``ScheduleOne``, schedule_one.go:67).
 
-- ``greedy``: device-resident ``lax.scan`` with exact sequential-consistency
+- ``greedy``: device-resident per-pod loop with exact sequential-consistency
   semantics (each assignment updates node usage before the next pod is
   scored) — the ≥99%-parity reference mode.
 - ``sinkhorn``: capacity-coupled batched assignment (LP-relaxed bin-pack via

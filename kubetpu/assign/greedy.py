@@ -1,4 +1,4 @@
-"""Greedy assignment as one device-resident ``lax.scan``.
+"""Greedy assignment as one device-resident loop over the pods.
 
 The reference schedules pods strictly one at a time: ``scheduleOne`` pops a
 pod, filters + scores all nodes against the *current* cache (which includes
@@ -7,11 +7,13 @@ schedule_one.go:605), and assumes the pod onto it (cache.AssumePod,
 backend/cache/cache.go:397) before the next pod starts. That serialization is
 what makes greedy results well-defined on saturated clusters.
 
-Here the same semantics run as a single XLA program: ``lax.scan`` over the
-pod axis, carrying ``(requested, nonzero_requested, pod_count)`` node-state
+Here the same semantics run as a single XLA program: a loop over the pod
+axis, carrying ``(requested, nonzero_requested, pod_count)`` node-state
 tensors; each step re-runs the full Filter+Score composition for one pod
 against the running state and updates it with a one-hot scatter. No
-host↔device round-trips inside the batch.
+host↔device round-trips inside the batch. The loop ends at the last REAL
+pod, so a batch padded to a larger compile bucket (``Scheduler._pod_bucket``)
+pays for its own pods' steps and not for the bucket's.
 
 Tie-breaking: the reference picks uniformly at random among max-score nodes
 (schedule_one.go:1037 reservoir sample). We take the FIRST max-score node in
@@ -186,8 +188,19 @@ def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
         None if b.nominated_pod_idx is None
         else jnp.ones(b.nominated_pod_idx.shape[0], dtype=bool),
     )
-    final_state, assignments = jax.lax.scan(
-        step, init, jnp.arange(p, dtype=jnp.int32)
+    # padded pods are infeasible on every node (``pod_valid`` masks them):
+    # their steps would choose -1 and leave the state as it is
+    stop = jnp.max(jnp.where(
+        b.pod_valid, jnp.arange(1, p + 1, dtype=jnp.int32), 0
+    ))
+
+    def body(i, carry):
+        state, assignments = carry
+        state, chosen = step(state, i)
+        return state, assignments.at[i].set(chosen)
+
+    final_state, assignments = jax.lax.fori_loop(
+        0, stop, body, (init, jnp.full(p, -1, dtype=jnp.int32))
     )
     return assignments, final_state
 

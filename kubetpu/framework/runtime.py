@@ -246,8 +246,9 @@ def _scatter_node_rows(
     state buffers are DONATED: each output aliases its input (same
     shape/dtype), so the update is in-place on device and the old buffers
     are invalidated — the ResidentNodeState owner is the only holder by
-    contract. ``idx`` is padded to a compile bucket with out-of-range
-    indices; mode="drop" discards those writes. ``valid`` rides along so an
+    contract. ``idx`` is padded to the chunk length (ONE per cluster size,
+    ``ResidentNodeState._scatter_single``) with out-of-range indices;
+    mode="drop" discards those writes. ``valid`` rides along so an
     incremental reshard (node add/delete within the same padded capacity)
     can flip validity rows without a full re-upload."""
     return (
@@ -478,34 +479,41 @@ class ResidentNodeState:
     def _scatter_single(
         self, nt: "enc.NodeTensors", rows: list, valid_of: np.ndarray, NC: int
     ) -> DeviceNodeState:
-        pad = enc.round_up(len(rows))
-        idx = np.full(pad, NC, dtype=np.int32)   # pad rows → dropped writes
-        idx[: len(rows)] = rows
-
-        def deltas(a: np.ndarray) -> np.ndarray:
-            u = np.zeros((pad,) + a.shape[1:], dtype=a.dtype)
-            u[: len(rows)] = a[rows]
-            return u
-
-        u_alloc = deltas(nt.alloc)
-        u_req = deltas(nt.requested)
-        u_nz = deltas(nt.nonzero_requested)
-        u_pc = deltas(nt.pod_count)
-        u_al = deltas(nt.allowed_pods)
-        u_vd = np.zeros(pad, dtype=bool)
-        u_vd[: len(rows)] = valid_of
+        # ONE scatter program a cluster size: the dirty rows travel in
+        # chunks of a fixed length (a bucket per row count would compile a
+        # new program in the serving loop whenever a drain dirties a count
+        # not met before; at 1024 rows a chunk is ~140 KB, beside a block
+        # of megabytes). refresh() sends 2 * len(rows) >= num_nodes whole,
+        # so a cluster under 2048 nodes needs one chunk
+        pad = max(min(1024, NC // 2), 1)
         dev = self.device
-        alloc, req, nz, pc, al, vd = _scatter_node_rows(
+        state = (
             dev.alloc, dev.requested, dev.nonzero_requested,
             dev.pod_count, dev.allowed_pods, dev.node_valid,
-            jnp.asarray(idx), jnp.asarray(u_alloc), jnp.asarray(u_req),
-            jnp.asarray(u_nz), jnp.asarray(u_pc), jnp.asarray(u_al),
-            jnp.asarray(u_vd),
         )
-        self.last_upload_bytes = int(
-            idx.nbytes + u_alloc.nbytes + u_req.nbytes + u_nz.nbytes
-            + u_pc.nbytes + u_al.nbytes + u_vd.nbytes
+        sources = (
+            nt.alloc, nt.requested, nt.nonzero_requested, nt.pod_count,
+            nt.allowed_pods,
         )
+        self.last_upload_bytes = 0
+        for lo in range(0, len(rows), pad):
+            chunk = rows[lo: lo + pad]
+            idx = np.full(pad, NC, dtype=np.int32)   # pad rows → dropped writes
+            idx[: len(chunk)] = chunk
+            updates = []
+            for a in sources:
+                u = np.zeros((pad,) + a.shape[1:], dtype=a.dtype)
+                u[: len(chunk)] = a[chunk]
+                updates.append(u)
+            u_vd = np.zeros(pad, dtype=bool)
+            u_vd[: len(chunk)] = valid_of[lo: lo + pad]
+            updates.append(u_vd)
+            state = _scatter_node_rows(
+                *state, jnp.asarray(idx), *(jnp.asarray(u) for u in updates)
+            )
+            self.last_upload_bytes += int(
+                idx.nbytes + sum(u.nbytes for u in updates)
+            )
         # keep the per-shard arrays n_shards long even on the (shouldn't-
         # happen: encode pads NC to a shard multiple) unsharded fallback,
         # so shard-labeled metrics never disagree with mesh_shape
@@ -513,6 +521,7 @@ class ResidentNodeState:
             [self.last_upload_bytes] + [0] * (self._n_shards - 1)
         )
         self.last_rows_per_shard = [len(rows)] + [0] * (self._n_shards - 1)
+        alloc, req, nz, pc, al, vd = state
         return DeviceNodeState(
             alloc=alloc, requested=req, nonzero_requested=nz,
             pod_count=pc, allowed_pods=al, node_valid=vd,
@@ -783,6 +792,7 @@ def encode_batch(
     track_changes: bool = True,
     mesh=None,
     topology: str = "off",
+    pad_pods: int = 0,
 ) -> EncodedBatch:
     """Snapshot + pending pods → padded device batch.
 
@@ -818,7 +828,7 @@ def encode_batch(
         snapshot, pods, profile, pad=pad, resource_names=resource_names,
         nominated=nominated, prev_nt=prev_nt, cache=cache,
         track_changes=track_changes, pad_multiple=pad_multiple,
-        topology=topology,
+        topology=topology, pad_pods=pad_pods,
     )
     return finalize_batch(
         sb, snapshot, nominated=nominated, resident=resident, mesh=mesh
@@ -837,6 +847,7 @@ def encode_batch_static(
     track_changes: bool = True,
     pad_multiple: int = 1,
     topology: str = "off",
+    pad_pods: int = 0,
 ) -> StaticBatch:
     """Stage 1: the assume-independent host encode (see StaticBatch).
     ``track_changes=False`` (serial loop) skips the pipeline-only
@@ -844,12 +855,17 @@ def encode_batch_static(
     round the padded NODE capacity up to this multiple — a mesh of
     n_shards devices needs NC % n_shards == 0 or the sharded resident
     block degrades to per-cycle replication (round_up's buckets are
-    multiples of 8, so this only bites past 8 shards on tiny clusters)."""
+    multiples of 8, so this only bites past 8 shards on tiny clusters).
+    ``pad_pods``: pad the POD axis to this bucket (at least ``len(pods)``)
+    instead of ``round_up``'s — the scheduler names one whose programs it
+    has already compiled (``Scheduler._pod_bucket``)."""
     N, P = snapshot.num_nodes(), len(pods)
     NP = enc.round_up(N) if pad else N
     if pad:
         NP = enc.shard_aligned(NP, pad_multiple)
-    PP = enc.round_up(P) if pad else P
+    PP = P
+    if pad:
+        PP = max(pad_pods, P) if pad_pods else enc.round_up(P)
     folded: frozenset = frozenset()
     if resource_names is None:
         resource_names, folded = enc.batch_resource_axis(snapshot, pods)

@@ -188,7 +188,7 @@ class Scheduler:
         sentinel: "bool | Any" = False,
         topology: str = "off",
     ) -> None:
-        """``engine``: "greedy" (per-pod lax.scan, exact reference
+        """``engine``: "greedy" (per-pod device loop, exact reference
         semantics) or "batched" (capacity-coupled rounds,
         assign.batched — one big device program per round; wins when
         batches are signature-homogeneous, the scheduler_perf shape).
@@ -263,6 +263,13 @@ class Scheduler:
         from ..framework.featuregate import FeatureGate
 
         self.recorder = recorder
+        # Events recorded and not yet written (_record_event), as
+        # client.events.Occurrence tuples: emptied before the drain or the
+        # cycle that recorded them returns
+        self._pending_events: list[tuple] = []
+        # profile name -> the pod-axis buckets its programs have run at
+        # (_pod_bucket)
+        self._pod_buckets: dict[str, set[int]] = {}
         self.replica_id = replica_id
         self.federation_mode = federation_mode
 
@@ -909,6 +916,8 @@ class Scheduler:
         pipeline mode, the device-resident node block — all derived views of
         the cache that the first measured cycle would otherwise rebuild from
         scratch. Seeding them is the point: steady state starts at cycle 1.
+        The rungs warmed are remembered, so a later batch pads to its own
+        (``_pod_bucket``).
         """
         if not pods:
             return
@@ -926,6 +935,9 @@ class Scheduler:
             warm = list(pods)
             while len(warm) < size:   # replicate up the ladder rung
                 warm.extend(pods[: size - len(warm)])
+            self._pod_buckets.setdefault(self.profile.name, set()).add(
+                round_up(size)
+            )
             batch = rt.encode_batch(
                 self._snapshot, warm[:size], self.profile,
                 nominated=self.nominator.entries(),
@@ -969,6 +981,24 @@ class Scheduler:
         ]
         self.warmup(pods + pods * ((n - 1) // max(len(pods), 1)), ladder=True)
 
+    def _pod_bucket(self, profile: C.Profile, pods: int) -> int:
+        """The padded pod count for a batch of ``pods``: the smallest bucket
+        this profile has already run that holds it — its programs (assign,
+        explain) are compiled, and the longer program costs tenths of a
+        second at most where a compile in the serving loop costs seconds
+        from a warm cache and minutes cold — else ``round_up``'s, compiled
+        now and remembered. So the loop compiles only for a batch larger
+        than any before it; after ``warmup``/``--prewarm`` every rung is
+        known and each batch gets its own."""
+        from ..state.encoder import round_up
+
+        known = self._pod_buckets.setdefault(profile.name, set())
+        bucket = min((b for b in known if b >= pods), default=0)
+        if not bucket:
+            bucket = round_up(pods)
+            known.add(bucket)
+        return bucket
+
     def schedule_batch(self, max_batch: int | None = None) -> dict[str, int]:
         """One scheduling cycle over up to ``max_batch`` pods. Returns result
         counts. The serial cycle: drain bind completions → pop batch →
@@ -992,6 +1022,8 @@ class Scheduler:
                 return self._schedule_batch_inner(max_batch)
             finally:
                 self.dispatcher.flush()
+                # the cycle's FailedScheduling Events, in one request
+                self._write_events()
                 if self.sentinel is not None:
                     # the sentinel rides the cycle boundary: at most one
                     # rule evaluation per interval, on the owner's thread
@@ -1162,6 +1194,7 @@ class Scheduler:
                 cache=self.encode_cache,
                 pad_multiple=self._pad_multiple,
                 topology=self.topology,
+                pad_pods=self._pod_bucket(profile, len(pods)),
             )
         except Exception:
             # stage 1 is an optimization: any failure falls back to the
@@ -1280,6 +1313,7 @@ class Scheduler:
                         track_changes=self.pipeline,
                         mesh=self.mesh,
                         topology=self.topology,
+                        pad_pods=self._pod_bucket(profile, len(pods)),
                     )
                 if self.encode_cache is not None and enc_sp is not None:
                     # gather-vs-fresh-vs-invalidate: how this cycle's rows
@@ -1738,12 +1772,14 @@ class Scheduler:
         """Bind results re-enter the loop thread here (the reference handles
         this in the per-pod binding goroutine; we serialize into the cycle).
         Returns how many completions it took; a drain that found none
-        records no span."""
+        records no span. The Events recorded on the way (one ``Scheduled``
+        per bound pod) are written in one bulk request before it returns."""
         clock = self.loop_clock
         with clock.phase("drain"), self.tracer.span("drain") as sp:
             events0 = clock.entries["events"]
             events_s0 = clock.seconds["events"]
             completions = self._apply_bind_completions()
+            requests = self._write_events()
             self.loop_work += completions
             if sp is not None:
                 sp.discard = not completions
@@ -1751,20 +1787,42 @@ class Scheduler:
                     completions=completions,
                     events=clock.entries["events"] - events0,
                     events_s=round(clock.seconds["events"] - events_s0, 6),
+                    event_requests=requests,
                 )
         return completions
 
     def _record_event(
         self, pod: t.Pod, reason: str, note: str, type: str = "Normal"
     ) -> None:
-        """Every Event the scheduler writes goes through here: the write is
-        a phase of its own (``events``), whatever phase it interrupts."""
+        """Every Event the scheduler records goes through here, stamped
+        now and NOT yet written: ``_write_events`` sends what has gathered,
+        at the end of ``_drain_bind_completions`` and of ``schedule_batch``.
+        When either returns, every Event recorded so far is in the store or
+        counted as dropped (an Event recorded outside both — ``warmup``
+        completing an in-flight cycle — goes with the next; ``close`` ends
+        in a drain)."""
         if self.recorder is None:
             return
-        with self.loop_clock.phase("events"):
-            self.recorder.event(
-                f"Pod/{pod.namespace}/{pod.name}", reason, note, type=type
-            )
+        self._pending_events.append((
+            f"Pod/{pod.namespace}/{pod.name}", reason, note, type,
+            self.recorder.clock(),
+        ))
+
+    def _write_events(self) -> int:
+        """Write the recorded Events — ``EventRecorder.events``: one bulk
+        request, two when a repeat is read first — and empty the list. The
+        write is a phase of its own (``events``), whatever phase it
+        interrupts, and its entries count the Events. Returns the store
+        round trips it took."""
+        pending = self._pending_events
+        if not pending:
+            return 0
+        self._pending_events = []
+        recorder = self.recorder
+        requests0 = recorder.requests
+        with self.loop_clock.phase("events", entries=len(pending)):
+            recorder.events(pending)
+        return recorder.requests - requests0
 
     def _apply_bind_completions(self) -> int:
         completions = 0
@@ -1923,9 +1981,10 @@ class Scheduler:
         if self.recorder is not None and hasattr(
             self.recorder, "metrics_text"
         ):
-            # the owning component exposes its recorder's drop counter
-            # (kubetpu_events_dropped_total) — the best-effort event
-            # contract made scrape-visible
+            # the owning component exposes its recorder's counters
+            # (kubetpu_events_dropped_total, ..._written_total, the write
+            # requests) — the best-effort event contract made
+            # scrape-visible
             text += self.recorder.metrics_text()
         if self.sentinel is not None:
             text += self.sentinel.metrics_text()

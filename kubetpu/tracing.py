@@ -332,7 +332,8 @@ LOOP_PHASES = (
     "explain",          # the flight recorder's note_cycle
     "bind_dispatch",    # assume, Reserve/Permit, handing binds over
     "drain",            # bind results back into cache and queue
-    "events",           # the Event write (EventRecorder.event)
+    "events",           # the bulk Event writes of a drain or a cycle
+                        # (EventRecorder.events); entries count Events
     "sleep",            # the loop's period sleep and its back-off
     "other",            # leader and membership checks, loop overhead
 )
@@ -360,10 +361,11 @@ class PhaseClock:
     def current(self) -> str:
         return self._running[0]
 
-    def switch(self, phase: str, count: bool = True) -> str:
+    def switch(self, phase: str, entries: int = 1) -> str:
         """End the running phase and begin ``phase``; returns the phase
-        that ended. One clock read. ``count=False`` resumes an interrupted
-        phase without counting another entry for it."""
+        that ended. One clock read. ``entries`` is what the visit counts
+        for: 0 resumes an interrupted phase, the ``events`` phase counts
+        the Events it writes."""
         now = self._clock()
         prev, since = self._running
         # the new phase first, the old one's seconds second: a scrape in
@@ -371,8 +373,7 @@ class PhaseClock:
         # steps back
         self._running = (phase, now)
         self.seconds[prev] += now - since
-        if count:
-            self.entries[phase] += 1
+        self.entries[phase] += entries
         return prev
 
     def iteration_done(self) -> None:
@@ -380,14 +381,14 @@ class PhaseClock:
         self.iterations += 1
 
     @contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, entries: int = 1):
         """Run the block as ``name``, then resume the phase it interrupted
         (also when the block raises)."""
-        prev = self.switch(name)
+        prev = self.switch(name, entries)
         try:
             yield
         finally:
-            self.switch(prev, count=False)
+            self.switch(prev, entries=0)
 
     def snapshot(self) -> tuple[dict[str, float], dict[str, int], int]:
         """(seconds, entries, iterations) with the running phase's elapsed
