@@ -31,6 +31,23 @@ feasible nodes converges in O(P / distinct-target-nodes) rounds — one round
 for SchedulingBasic shapes; the adversarial case (every pod feasible on one
 node only) degrades to the scan's O(P) — with the same result.
 
+A batch that carries a HARD topology spread constraint
+(``b.spread.has_hard``) is not solved in rounds at all but handed to the
+greedy scan: acceptance projects onto capacity only, and K pods that each
+pass the skew check against round-start counts can together exceed
+``maxSkew`` (30 nodes in zones of 20/5/5, 64 pods at maxSkew 1: 38 of the
+64 round placements were infeasible at their turn). A per-domain quota
+would be correct but admits about ``maxSkew`` pods a domain a round.
+Required anti-affinity inside one round is the same open question
+(ROADMAP B2). Two things follow until a later PR moves the choice to where
+the engine is chosen (``Scheduler.__init__``, ``assign/placement.py``,
+``ops/preemption.py``, ``parallel/mesh.py``): the rounds never see
+``has_hard``, so the hard-spread filter that ``feasible_and_scores`` applies
+inside a round is dead code on this path (the soft score and the counts
+update below are not); and such a batch runs the scan under this function's
+program name, ``jit_batched_assign_device``, so a profile or a bytes model
+keyed by that name reads rounds where the scan ran.
+
 This is the LP-relaxation/Sinkhorn family member that keeps integer
 semantics: the tie-spread argmax is the zero-temperature limit of a
 Sinkhorn row/column balancing over score-equivalent columns, and the
@@ -138,6 +155,15 @@ def batched_assign_device(
     """Run the round loop. Same contract as ``greedy_assign_device``:
     returns ``(assignments (P,) int32 node index or -1, final_state)`` with
     the identical 7-slot final-state tuple."""
+    if b.spread is not None and b.spread.has_hard:
+        # a round admits by capacity alone (``_accept``), so pods of one
+        # round can each pass a DoNotSchedule skew check that their sum
+        # breaks; the scan re-runs the filter after every placement. Decided
+        # at trace time from the batch's static field: a batch without a
+        # hard constraint compiles the program it always did.
+        from .greedy import greedy_assign_device
+
+        return greedy_assign_device(b, params)
     p = b.requests.shape[0]
     n = b.alloc.shape[0]
     cap = max_rounds or p
@@ -195,12 +221,18 @@ def batched_assign_device(
             )[:n] > 0
         )
         if spread_counts is not None:
-            onehot = (choice[:, None] == node_iota[None, :]) & accepted[:, None]
-            upd = jnp.einsum(
-                "ps,pn->sn", b.spread.pod_match_sig.astype(jnp.int32),
-                onehot.astype(jnp.int32),
-            ) * b.spread.eligible.astype(jnp.int32)
-            spread_counts = spread_counts + upd.astype(spread_counts.dtype)
+            with jax.named_scope("spread_counts_update"):
+                onehot = (
+                    (choice[:, None] == node_iota[None, :])
+                    & accepted[:, None]
+                )
+                upd = jnp.einsum(
+                    "ps,pn->sn", b.spread.pod_match_sig.astype(jnp.int32),
+                    onehot.astype(jnp.int32),
+                ) * b.spread.eligible.astype(jnp.int32)
+                spread_counts = spread_counts + upd.astype(
+                    spread_counts.dtype
+                )
         if pa_sums is not None:
             pa = b.podaffinity
             r_rows, d = pa_sums.shape

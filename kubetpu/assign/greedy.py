@@ -150,12 +150,15 @@ def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
             # updateWithPod (podtopologyspread/filtering.go:181): +1 in every
             # signature whose selector+namespace the assigned pod matches, on
             # the chosen node, when that node is eligible for the signature.
-            upd = (
-                b.spread.pod_match_sig[i][:, None]
-                & b.spread.eligible
-                & onehot[None, :]
-            )
-            spread_counts = spread_counts + upd.astype(spread_counts.dtype)
+            with jax.named_scope("spread_counts_update"):
+                upd = (
+                    b.spread.pod_match_sig[i][:, None]
+                    & b.spread.eligible
+                    & onehot[None, :]
+                )
+                spread_counts = spread_counts + upd.astype(
+                    spread_counts.dtype
+                )
         if pa_sums is not None:
             # interpodaffinity updateWithPod (filtering.go:75): scatter the
             # assigned pod's increments into each row at the chosen node's

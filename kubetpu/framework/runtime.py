@@ -15,6 +15,7 @@ through jit/scan/shard_map unchanged.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Sequence
@@ -222,6 +223,22 @@ class EncodedBatch:
     # bytes of the device-resident node block backing this batch (0 when the
     # node block was a one-shot upload, i.e. no residency)
     resident_bytes: int = 0
+    # the spread encode of this batch, when some pod carries or inherits a
+    # topology spread constraint (else None): what the scheduler observes
+    # and records as the ``encode-spread`` span
+    spread_encode: "SpreadEncodeStamp | None" = None
+
+
+@dataclass(frozen=True)
+class SpreadEncodeStamp:
+    """``encode_spread`` as ``finalize_batch`` ran it: start and end on
+    ``time.perf_counter`` (the tracer's clock), and what it built."""
+
+    start: float
+    end: float
+    signatures: int
+    domains: int
+    constrained_pods: int
 
 
 class StaleStaticEncode(Exception):
@@ -1129,10 +1146,12 @@ def finalize_batch(
                 has_score_work=pa.has_score_work,
             )
     spread_dev = None
+    spread_stamp = None
     if sb.want_spread:
         defaults = (
             profile.default_spread_constraints if profile is not None else ()
         )
+        t_spread = time.perf_counter()
         sp = enc_spread.encode_spread(
             nt, pods, pad_pods=PP,
             default_constraints=defaults,
@@ -1146,6 +1165,13 @@ def finalize_batch(
             groups=_groups_memo[0] if _groups_memo else None,
         )
         if sp is not None:
+            spread_stamp = SpreadEncodeStamp(
+                start=t_spread, end=time.perf_counter(),
+                signatures=sp.num_sigs, domains=sp.max_domains,
+                constrained_pods=int(
+                    (sp.sig_idx[:P] >= 0).any(axis=1).sum()
+                ),
+            )
             spread_dev = SpreadDevice(
                 eligible=sp.eligible,
                 node_domain=sp.node_domain,
@@ -1312,6 +1338,7 @@ def finalize_batch(
         port_vocab=pb.port_vocab,
         upload_bytes=pod_block_bytes + node_upload,
         resident_bytes=resident_bytes,
+        spread_encode=spread_stamp,
     )
 
 
@@ -1463,11 +1490,13 @@ def filter_components(
     if sp is not None:
         sp_counts = sp.node_count if spread_counts is None else spread_counts
         if p.filter_spread and sp.has_hard:
-            spread_ok = jax.vmap(
-                lambda si, ac, ms, md, sm: SP.spread_filter_pod(
-                    sp, sp_counts, si, ac, ms, md, sm
-                )
-            )(sp.sig_idx, sp.action, sp.max_skew, sp.min_domains, sp.self_match)
+            with jax.named_scope("spread_filter"):
+                spread_ok = jax.vmap(
+                    lambda si, ac, ms, md, sm: SP.spread_filter_pod(
+                        sp, sp_counts, si, ac, ms, md, sm
+                    )
+                )(sp.sig_idx, sp.action, sp.max_skew, sp.min_domains,
+                  sp.self_match)
     pa = b.podaffinity
     pa_state = None
     pa_ok = None
@@ -1563,11 +1592,12 @@ def feasible_and_scores(
         )
         total = total + p.w_image * S.image_locality_score(img, b.image_count)
     if sp is not None and p.w_spread and sp.has_soft:
-        spread_sc = jax.vmap(
-            lambda si, ac, ms, ig, m: SP.spread_score_pod(
-                sp, sp_counts, si, ac, ms, ig, m
-            )
-        )(sp.sig_idx, sp.action, sp.max_skew, sp.ignored, mask)
+        with jax.named_scope("spread_score"):
+            spread_sc = jax.vmap(
+                lambda si, ac, ms, ig, m: SP.spread_score_pod(
+                    sp, sp_counts, si, ac, ms, ig, m
+                )
+            )(sp.sig_idx, sp.action, sp.max_skew, sp.ignored, mask)
         total = total + p.w_spread * spread_sc
     if pa is not None and p.w_interpod and pa.has_score_work:
         pa_sc = jax.vmap(

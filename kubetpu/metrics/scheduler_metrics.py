@@ -13,7 +13,10 @@ layouts (pkg/scheduler/metrics/metrics.go):
   (:353, ExponentialBuckets(0.00001, 1.5, 20)) — per host-side lifecycle
   plugin call; the fused device Filter+Score program cannot be timed
   per-plugin (it is ONE XLA program), so its wall time lands on
-  extension_point="Filter+Score" at the framework level instead
+  extension_point="Filter+Score" at the framework level instead; the host
+  spread encode is timed as plugin="PodTopologySpread",
+  extension_point="PreFilter" (``Scheduler._launch_cycle``), beside
+  spread_constrained_pods_total
 - schedule_attempts_total{result, profile}, preemption_attempts_total,
   preemption_victims (:267 ExponentialBuckets(1, 2, 7)), pending_pods{queue}
 """
@@ -112,6 +115,12 @@ class SchedulerMetricsRegistry:
             "scheduler_schedule_attempts_total",
             "Number of attempts to schedule pods, by the result.",
             labels=("result", "profile"),
+        )
+        self.spread_constrained_pods = r.counter(
+            "scheduler_spread_constrained_pods_total",
+            "Pods of the scheduling cycles that carried or inherited a "
+            "topology spread constraint, so that the spread encode and the "
+            "spread kernels ran for them; a cycle with none adds nothing.",
         )
         self.preemption_attempts = r.counter(
             "scheduler_preemption_attempts_total",

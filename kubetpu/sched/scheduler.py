@@ -1327,6 +1327,20 @@ class Scheduler:
                             "encode-cache-invalidate", cycle=cycle_id,
                             count=delta["invalidations"],
                         )
+                stamp = batch.spread_encode
+                if stamp is not None:
+                    # the spread path's host time, named: a cycle with no
+                    # constrained pod observes nothing
+                    prom.plugin_execution_duration.labels(
+                        C.POD_TOPOLOGY_SPREAD, "PreFilter", "Success"
+                    ).observe(stamp.end - stamp.start)
+                    self.tracer.record(
+                        "encode-spread", stamp.start, stamp.end,
+                        parent_id=self.tracer.current_id, off_stack=False,
+                        cycle=cycle_id, signatures=stamp.signatures,
+                        domains=stamp.domains,
+                        constrained_pods=stamp.constrained_pods,
+                    )
             # the host encode builds per-pod state ahead of filtering —
             # the PreFilter role in the reference's extension-point map
             encode_s = time.perf_counter() - t_enc
@@ -1579,6 +1593,11 @@ class Scheduler:
             prom.scheduling_attempt_duration.labels(
                 "unschedulable", profile.name
             ).observe_n(cycle_s, len(failed))
+        if batch.spread_encode is not None:
+            # beside the attempts it is read as a share of
+            prom.spread_constrained_pods.inc(
+                batch.spread_encode.constrained_pods
+            )
 
         try:
             for info in failed:
