@@ -12,12 +12,14 @@ never initialises a JAX backend (asserted at exit): the chip belongs to the
 scheduler.
 
 It takes commands as JSON lines on stdin and answers with JSON lines on
-stdout: post, await_init, burst, start, stop, report, quit. End of input is quit.
+stdout: post, await_init, burst, start, pause, stop, report, quit. End of
+input is quit.
 """
 
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
 import os
 import sys
@@ -130,6 +132,12 @@ class Generator:
         self.template = templates.resolve(
             templates.POD_TEMPLATES, measured["template"])
         self.namespace = measured["namespace"]
+        #: pods the cluster holds: past it a created pod can never bind
+        self.capacity = templates.capacity(config)
+        #: when the traffic was told to end, on ``clock`` (None while it runs)
+        self.ended_at: float | None = None
+        #: how long after its window's close that was (``pause`` knows)
+        self.past_t1_s: float | None = None
         self.serial = 0
         self.watch_thread = threading.Thread(
             target=self._watch, name="generator-watch", daemon=True)
@@ -289,16 +297,51 @@ class Generator:
             if wait > 0:
                 time.sleep(wait)
 
+    def _end_traffic(self) -> None:
+        """No create starts after this; the one in flight lands."""
+        if self.ended_at is None:
+            self.stop_traffic.set()
+            self.ended_at = clock()
+
+    def pause(self, t1: float) -> dict:
+        """The traffic ends, and nothing else: no waiting, not even for the
+        bulk create in flight. ``t1`` is the window's close on ``clock``;
+        ``created_at_t1`` counts the pods whose create was sent by then, so
+        ``created`` of the drain less it is what was made outside the
+        window."""
+        self._end_traffic()
+        self.past_t1_s = round(self.ended_at - t1, 3)
+        with self.ledger.lock:     # stamps of one clock, in their order
+            at_t1 = bisect.bisect_right(self.ledger.sent, t1)
+        return {"event": "paused", "created_at_t1": at_t1,
+                "capacity": self.capacity, "past_t1_s": self.past_t1_s}
+
     def stop(self, timeout_s: float) -> dict:
-        t0 = clock()
-        self.stop_traffic.set()
+        """The traffic ends, if ``pause`` has not ended it, and the backlog
+        binds. ``s`` runs from the end of the traffic to the last bind the
+        watch showed (to now, where pods are left unbound), however late
+        the harness asks. A backlog that cannot bind because the cluster
+        holds no more pods says so under ``why``."""
+        self._end_traffic()
         if self.traffic_thread is not None:
             self.traffic_thread.join(timeout=60)
         ok = self.ledger.wait_all_bound(timeout_s)
-        return {"event": "drained", "ok": ok,
-                "created": len(self.ledger.keys),
-                "unbound": self.ledger.standing(),
-                "s": round(clock() - t0, 3)}
+        with self.ledger.lock:
+            last = max((b for b in self.ledger.bound_at if b is not None),
+                       default=self.ended_at)
+        end = last if ok else clock()
+        doc = {"event": "drained", "ok": ok,
+               "created": len(self.ledger.keys),
+               "unbound": self.ledger.standing(),
+               "s": round(max(end - self.ended_at, 0.0), 3)}
+        if not ok and doc["created"] >= self.capacity:
+            past = "" if self.past_t1_s is None else (
+                f"; the load ran {self.past_t1_s} s past the window")
+            doc["why"] = (
+                f"the cluster is full: created {doc['created']} of "
+                f"{self.capacity}{past} ({doc['unbound']} pods unbound "
+                f"after {doc['s']} s)")
+        return doc
 
     def report(self, t0: float, t1: float, out: str) -> dict:
         """Everything the client saw, reduced over the window [t0, t1], with
@@ -396,6 +439,8 @@ def main(argv: list[str]) -> int:
                     say(gen.burst(cmd["n"], cmd.get("timeout_s", 600.0)))
                 elif what == "start":
                     say(gen.start())
+                elif what == "pause":
+                    say(gen.pause(cmd["t1"]))
                 elif what == "stop":
                     say(gen.stop(cmd.get("timeout_s", 60.0)))
                 elif what == "report":

@@ -26,6 +26,7 @@ import tempfile
 import threading
 import time
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 from benchmark.harness import promtext
 from benchmark.harness.manifest import BENCH, ROOT, Cell
@@ -34,7 +35,7 @@ clock = time.perf_counter
 #: a window needs this many cycles in a row that compiled nothing before it
 QUIET_CYCLES = 3
 #: the traced part of a traced run: the LAST seconds of the window, so that
-#: writing the trace out falls after the window's closing scrape. Longer
+#: stopping the profiler falls after the window's closing scrape. Longer
 #: than the 4.5 s in which today's loop repeats itself (PERF.md, PR 22), and
 #: short enough for the trace to stay readable: 100,000 device events and
 #: 7 MB a second
@@ -116,6 +117,8 @@ class GeneratorLink:
         return self.expect(answer, timeout_s)
 
     def expect(self, answer: str, timeout_s: float) -> dict:
+        """The next ``answer``. One that says ``"ok": false`` fails the run
+        here, in the generator's own words where it gives them (``why``)."""
         deadline = clock() + timeout_s
         while True:
             doc = json.loads(self.child.next_line(max(deadline - clock(), 0.1)))
@@ -123,7 +126,7 @@ class GeneratorLink:
                 raise RunFailed(f"generator: {doc}")
             if doc.get("event") == answer:
                 if doc.get("ok") is False:
-                    raise RunFailed(f"generator: {doc}")
+                    raise RunFailed(f"generator: {doc.get('why', doc)}")
                 return doc
 
     def quit(self) -> dict:
@@ -239,8 +242,7 @@ def _control(run: Run, gen: GeneratorLink, meter) -> None:
             compiled=meter.snapshot())
 
         # ---- window
-        spans = None
-        trace_dir = None
+        spans = session = None
         programs0 = meter.snapshot()["programs"]
         sched0 = promtext.scrape(run.diag_url)
         api0 = promtext.scrape(run.api_url)
@@ -255,12 +257,8 @@ def _control(run: Run, gen: GeneratorLink, meter) -> None:
             from benchmark.harness.spans import SpanLog
 
             spans = SpanLog(run.diag_url)
-            trace_dir = os.path.join(run.scratch, "trace")
             time.sleep(max(t1 - min(TRACE_SECONDS, run.seconds) - clock(), 0))
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
-            options.host_tracer_level = 1
-            jax.profiler.start_trace(trace_dir, profiler_options=options)
+            session = start_profiler()
             # the anchor puts this process's clock on the trace's clock
             with jax.profiler.TraceAnnotation(ANCHOR):
                 anchors.append(clock())
@@ -270,33 +268,8 @@ def _control(run: Run, gen: GeneratorLink, meter) -> None:
             anchors.append(clock())
         else:
             time.sleep(max(t1 - clock(), 0))
-        t1 = clock()
-        run.cpu_s = {name: cpu_seconds(pid) - cpu0[name]
-                     for name, pid in run.pids.items()}
-        sched1 = promtext.scrape(run.diag_url)
-        api1 = promtext.scrape(run.api_url)
-        if run.trace:
-            import jax
-
-            spans.poll()
-            jax.profiler.stop_trace()
-        run.window_s = t1 - t0
-        run.scheduler = promtext.Delta(sched0, sched1)
-        run.apiserver = promtext.Delta(api0, api1)
-        run.cycles = run.scheduler.total(CYCLES)
-        run.compiles_in_window = meter.snapshot()["programs"] - programs0
-        run.phases["window_s"] = run.window_s
-        say("window", s=round(run.window_s, 3), cycles=run.cycles,
-            compiles_in_window=run.compiles_in_window,
-            cpu_s={k: round(v, 2) for k, v in run.cpu_s.items()})
-
-        # ---- drain: the generator stops and the backlog binds, bounded
-        t = clock()
-        drained = gen.ask(
-            {"cmd": "stop", "timeout_s": traffic["drain_timeout_s"]},
-            "drained", traffic["drain_timeout_s"] + 90)
-        run.phases["drain_s"] = clock() - t
-        say("drain", **drained)
+        t1, xspace = _close_window(run, gen, meter, spans, session,
+                                   Opened(t0, sched0, api0, cpu0, programs0))
         out = os.path.join(run.scratch, "generator.json")
         gen.ask({"cmd": "report", "t0": t0, "t1": t1, "out": out},
                 "report_done", 120)
@@ -304,7 +277,7 @@ def _control(run: Run, gen: GeneratorLink, meter) -> None:
             run.report = json.load(f)
         run.pods_bound = run.report["bound_in_window"]
         if run.trace:
-            _reduce_trace(run, trace_dir, anchors, spans)
+            _reduce_trace(run, xspace, anchors, spans)
     except Exception as e:  # noqa: BLE001 — reported by the main thread
         run.errors.append(f"{type(e).__name__}: {e}")
     finally:
@@ -312,16 +285,102 @@ def _control(run: Run, gen: GeneratorLink, meter) -> None:
         os.kill(os.getpid(), signal.SIGTERM)
 
 
-def _reduce_trace(run: Run, trace_dir: str, anchors: list[float],
+class Opened(NamedTuple):
+    """What was read as the window opened."""
+
+    t0: float
+    scheduler: promtext.Scrape
+    apiserver: promtext.Scrape
+    cpu: dict
+    programs: int
+
+
+#: a window that leaves less room than this in the cluster says so
+NEAR_FULL = 0.8
+
+
+def start_profiler():
+    """A profiler session of this process: what ``jax.profiler.start_trace``
+    starts, kept in hand, because ``jax.profiler.stop_trace`` also writes
+    the trace out as files, among them a gzipped JSON of every operation,
+    and on the chip that writing is most of the minutes a stop takes
+    (PERF.md section 6, PR 30). The session hands the same XSpace over as
+    bytes. The scheduler has initialised the backend long before."""
+    import jax
+    from jax._src.lib import _profiler
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    return _profiler.ProfilerSession(options)
+
+
+def stop_profiler(session) -> bytes:
+    """The trace, a serialized XSpace. Seconds to minutes on the chip: it
+    grows with the operations traced, 125 of them a pod bound."""
+    return session.stop()
+
+
+def _close_window(run: Run, gen: GeneratorLink, meter, spans, session,
+                  opened: Opened) -> tuple[float, bytes]:
+    """The window's close, and the drain. Everything a run measures is
+    closed at ``t1`` and the load ends at ``t1``, before anything slow
+    happens: stopping the profiler takes seconds to minutes, and a closed
+    loop left running meanwhile fills the cluster, the sooner the faster the
+    program is. ``spans`` and ``session`` are a traced run's SpanLog and
+    profiler session, else None. Returns ``t1`` and the trace."""
+    t1 = clock()
+    run.cpu_s = {name: cpu_seconds(pid) - opened.cpu[name]
+                 for name, pid in run.pids.items()}
+    sched1 = promtext.scrape(run.diag_url)
+    api1 = promtext.scrape(run.api_url)
+    programs1 = meter.snapshot()["programs"]
+    paused = gen.ask({"cmd": "pause", "t1": t1}, "paused", 90)
+    xspace = b""
+    if session is not None:
+        spans.poll()
+        t = clock()
+        xspace = stop_profiler(session)
+        run.phases["stop_trace_s"] = clock() - t
+    run.window_s = t1 - opened.t0
+    run.scheduler = promtext.Delta(opened.scheduler, sched1)
+    run.apiserver = promtext.Delta(opened.apiserver, api1)
+    run.cycles = run.scheduler.total(CYCLES)
+    run.compiles_in_window = programs1 - opened.programs
+    run.phases["window_s"] = run.window_s
+    created, capacity = paused["created_at_t1"], paused["capacity"]
+    near_full = {}
+    if created > NEAR_FULL * capacity:
+        near_full["near_full"] = (
+            f"the window alone made {created} of the {capacity} pods the "
+            "cluster holds: recycle pods before this rate rises (PERF.md "
+            "section 7 (2))")
+    say("window", s=round(run.window_s, 3), cycles=run.cycles,
+        compiles_in_window=run.compiles_in_window,
+        cpu_s={k: round(v, 2) for k, v in run.cpu_s.items()},
+        created_at_t1=created, capacity=capacity,
+        load_past_t1_s=paused["past_t1_s"], **near_full)
+    # ---- drain: the backlog the traffic left binds, bounded; ``created``
+    # less the window's ``created_at_t1`` was made outside the window
+    timeout_s = run.cell.traffic["drain_timeout_s"]
+    drained = gen.ask({"cmd": "stop", "timeout_s": timeout_s}, "drained",
+                      timeout_s + 90)
+    run.phases["drain_s"] = drained["s"]
+    say("drain", **drained)
+    return t1, xspace
+
+
+def _reduce_trace(run: Run, xspace: bytes, anchors: list[float],
                   spans) -> None:
+    import jax
+
     from benchmark.harness import intervals as iv
     from benchmark.harness import xplane
     from benchmark.harness.spans import attribute_gaps
 
     t = clock()
-    path = xplane.find_xplane(trace_dir)
-    size = os.path.getsize(path)
-    data = xplane.load(path)
+    size = len(xspace)
+    data = jax.profiler.ProfileData.from_serialized_xspace(xspace)
     if run.device["platform"] != "tpu":
         # reachable from benchmark/tests only: a CPU trace has no device
         # plane, so the rehearsal stops at having read the file
@@ -460,9 +519,15 @@ def _judge(run: Run, meter) -> dict:
     cell, rep = run.cell, run.report
     nodes, stored = check.readback(run.api_url)
     failed, problems = check.store_agreement(rep, stored)
-    problems += [f"generator: {e}" for e in rep["errors"]]
-    problems += check.validity_problems(nodes, stored)
+    invalid = check.validity_problems(nodes, stored)
     parity = check.oracle_parity(cell.config, nodes, stored, run.seed)
+    #: every number `correct` compares, beside its limit: all exact
+    checks = {"pods_failed": [failed, 0],
+              "ack_store_problems": [len(problems), 0],
+              "generator_errors": [len(rep["errors"]), 0],
+              "invalid_bindings": [len(invalid), 0],
+              "oracle_disagreements": [len(parity["problems"]), 0]}
+    problems += [f"generator: {e}" for e in rep["errors"]] + invalid
     problems += parity["problems"]
     run.phases["check_s"] = clock() - t
     say("check", attempted=rep["attempted"], failed=failed,
@@ -503,6 +568,10 @@ def _judge(run: Run, meter) -> dict:
         line["breakdown"] = {
             "device_ops": ops,
             "idle_gaps": xplane.top(run.idle_by_span, 10)}
+    line["checks"] = checks
+    for name, (value, limit) in checks.items():
+        print(f"check {name}: {value} (limit {limit})", file=sys.stderr,
+              flush=True)
     return line
 
 

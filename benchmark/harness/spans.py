@@ -10,11 +10,16 @@ import json
 from benchmark.harness import intervals as iv
 from benchmark.harness.promtext import fetch
 
-#: which span covers an idle gap, first match wins; the loop's own spans
-#: come before the asynchronous ``bind`` spans, which overlap them
-PRIORITY = ("assign", "encode", "snapshot", "extenders", "scheduling-cycle",
-            "bind")
-LABEL = {"scheduling-cycle": "in a cycle but in no span"}
+#: which span covers an idle gap, first match wins: a span nested in another
+#: stands before it (``encode-spread`` in ``encode``; ``explain`` and
+#: ``bind-dispatch`` in ``scheduling-cycle``), the loop's own spans before
+#: the asynchronous ``bind`` spans, which overlap them, and the
+#: ``loop-iteration`` that holds them all comes last
+PRIORITY = ("assign", "encode-spread", "encode", "snapshot", "extenders",
+            "explain", "bind-dispatch", "scheduling-cycle", "drain", "pump",
+            "bind", "loop-iteration")
+LABEL = {"scheduling-cycle": "in a cycle but in no span",
+         "loop-iteration": "in an iteration but in no span"}
 OUTSIDE = "outside any cycle (pump, sleep, generator)"
 
 

@@ -52,6 +52,22 @@ POD_TEMPLATES = {
 }
 
 
+def capacity(config: dict) -> int:
+    """Pods of the measured template the configuration's cluster holds:
+    nodes x the least of the node template's pod limit and each of its
+    resources over the pod's request for it."""
+    node = resolve(NODE_TEMPLATES, config["node_template"])(
+        0, tuple(config.get("zones", ())))
+    measured = config["measured_pods"]
+    pod = resolve(POD_TEMPLATES, measured["template"])(
+        "capacity", measured["namespace"])
+    alloc = dict(node.allocatable)
+    per_node = min([alloc["pods"]] + [alloc.get(name, 0) // request
+                                      for name, request in pod.requests
+                                      if request > 0])
+    return config["nodes"] * per_node
+
+
 def resolve(table: dict, name: str):
     """A template by its table name, or ``module:function`` for one a later
     PR keeps in a file of its own under benchmark/."""

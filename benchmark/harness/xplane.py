@@ -42,6 +42,9 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load(path: str):
+    """A trace from the file ``jax.profiler.stop_trace`` wrote. A run reads
+    its own trace as bytes (``phases._reduce_trace``); this and
+    ``find_xplane`` serve the fixture's recorder and ``tools/``."""
     from jax.profiler import ProfileData
 
     return ProfileData.from_file(path)

@@ -2,11 +2,13 @@
 only way to reach the harness without a TPU, and only from here (a function
 argument of ``run_cell``, never a flag or an environment variable).
 
-    python benchmark/tests/rehearse.py <workload> <trace 0|1> [nodes]
+    python benchmark/tests/rehearse.py <workload> <trace 0|1> [nodes] \
+        [--slow-profiler SECONDS] [--fault all-on-one-node]
 """
 
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import sys
@@ -53,24 +55,78 @@ def tiny(cell, nodes: int):
     return cell
 
 
+def slow_profiler(seconds: float) -> None:
+    """The chip's profiler takes seconds to minutes to hand its trace over
+    (it grows with the pods bound in the traced seconds); the CPU's takes
+    none. Wrapped HERE, in the rehearsal's own process: the harness has no
+    such switch."""
+    from benchmark.harness import phases
+
+    stop_profiler = phases.stop_profiler
+
+    def slow_stop_profiler(session) -> bytes:
+        time.sleep(seconds)
+        return stop_profiler(session)
+
+    phases.stop_profiler = slow_stop_profiler
+
+
+def all_on_one_node(cell) -> None:
+    """The fault a check of ``correct`` has to catch: an answer altered
+    where it is produced. The cell's engine still runs, and the scheduler
+    is handed node 0 for every pod it placed: the store agrees with every
+    ack, and the bindings over-commit the node. Planted HERE, in the
+    rehearsal's own process."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    flags = cell.config["scheduler_flags"]
+    engine = flags[flags.index("--engine") + 1]
+    # where the Scheduler's constructor finds its engine
+    module = importlib.import_module(
+        {"greedy": "kubetpu.sched.scheduler",
+         "batched": "kubetpu.assign.batched"}[engine])
+    name = f"{engine}_assign_device"
+    real = getattr(module, name)
+
+    def faulty(batch, params):
+        assignments, state = real(batch, params)
+        return jnp.where(assignments >= 0, 0, assignments), state
+
+    setattr(module, name, faulty)
+
+
 def main(argv: list[str]) -> int:
     from benchmark.harness import manifest
     from benchmark.harness.manifest import Cell, load_manifest
     from benchmark.harness.phases import run_cell
 
-    name, trace = argv[0], bool(int(argv[1]))
+    ap = argparse.ArgumentParser()
+    ap.add_argument("workload")
+    ap.add_argument("trace", type=int, choices=(0, 1))
+    # room for every pod the window sends: 40 pods a node
+    ap.add_argument("nodes", type=int, nargs="?", default=256)
+    ap.add_argument("--slow-profiler", type=float, metavar="SECONDS")
+    ap.add_argument("--fault", choices=("all-on-one-node",))
+    args = ap.parse_args(argv)
+    name = args.workload
     entry = PREPARED.get(name)
     if entry is not None and "layer_files" in entry:
         entry = {**entry, "per_layer": [
             {"name": n, **manifest.layer_reader(n).META}
             for n in entry["layer_files"]]}
-    cell = Cell(load_manifest(), name, entry)
-    # room for every pod the window sends: 40 pods a node
-    default = 256
-    cell = tiny(cell, int(argv[2]) if len(argv) > 2 else default)
+    cell = tiny(Cell(load_manifest(), name, entry), args.nodes)
     # a 99th percentile wants a thousand pods due inside the window
     seconds = 4.0 if cell.traffic["mode"] == "saturate" else 8.0
-    line = run_cell(name, seed=7, seconds=seconds, trace=trace,
+    if args.slow_profiler is not None:
+        slow_profiler(args.slow_profiler)
+        # a cluster this small is full within seconds: do not wait two
+        # minutes to hear it
+        cell.traffic = {**cell.traffic, "drain_timeout_s": 10}
+    if args.fault is not None:
+        all_on_one_node(cell)
+    line = run_cell(name, seed=7, seconds=seconds, trace=bool(args.trace),
                     t_start=T_START, platform="cpu", cell=cell)
     print(json.dumps(line), flush=True)
     return 0

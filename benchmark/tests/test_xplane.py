@@ -128,6 +128,40 @@ def test_idle_gaps_go_to_the_span_that_covers_them(synthetic):
     assert got[OUTSIDE] == pytest.approx(200 * US)         # 800..1000
 
 
+def test_the_loop_s_own_spans_split_what_lies_outside_a_cycle():
+    """One iteration, 0..900: pump, then a cycle that holds an encode with
+    its encode-spread, an explain and a bind-dispatch, then a drain; the
+    device is idle throughout. A nested span takes its part before the span
+    that holds it, and the iteration what no other span covers."""
+    spans = {"loop-iteration": [(0.0, 900.0)],
+             "pump": [(0.0, 100.0)],
+             "scheduling-cycle": [(100.0, 700.0)],
+             "encode": [(150.0, 300.0)],
+             "encode-spread": [(200.0, 250.0)],
+             "explain": [(500.0, 560.0)],
+             "bind-dispatch": [(560.0, 650.0)],
+             "drain": [(720.0, 800.0)],
+             "bind": [(600.0, 950.0)]}
+    got = attribute_gaps([(0.0, 1000.0)], spans)
+    assert got == {
+        "encode-spread": pytest.approx(50.0),
+        "encode": pytest.approx(100.0),
+        "explain": pytest.approx(60.0),
+        "bind-dispatch": pytest.approx(90.0),
+        "in a cycle but in no span": pytest.approx(600.0 - 300.0),
+        "drain": pytest.approx(80.0),
+        "pump": pytest.approx(100.0),
+        "bind": pytest.approx(20.0 + 150.0),     # 700..720, 800..950
+        OUTSIDE: pytest.approx(50.0),            # 950..1000
+    }
+    # an iteration whose binds have all come back: its own share shows
+    spans["bind"] = [(600.0, 700.0)]
+    got = attribute_gaps([(0.0, 1000.0)], spans)
+    assert got["in an iteration but in no span"] == pytest.approx(120.0)
+    assert got[OUTSIDE] == pytest.approx(100.0)
+    assert "bind" not in got
+
+
 def test_a_trace_without_a_device_plane_is_refused():
     from jax.profiler import ProfileData
 
@@ -156,3 +190,27 @@ def test_recorded_tpu_trace():
     # the device's clock runs a millisecond or so off the host's: the first
     # program is stamped BEFORE the anchor the host wrote ahead of it
     assert -0.005 < r["window"][0] - anchor[0] < 0.005
+
+
+def test_bytes_and_file_reduce_alike():
+    """The harness takes a run's trace as bytes from its profiler session
+    (``phases.stop_profiler``); the fixture's recorder and ``tools/`` read
+    the file ``jax.profiler.stop_trace`` writes. One XSpace either way: the
+    same planes, the same anchor, the same reduction to the last digit."""
+    from jax.profiler import ProfileData
+
+    path = os.path.join(DATA, "tpu_v5e_small.xplane.pb")
+    with open(path, "rb") as f:
+        from_bytes = ProfileData.from_serialized_xspace(f.read())
+    from_file = xplane.load(path)
+    assert [p.name for p in from_bytes.planes] == \
+        [p.name for p in from_file.planes]
+    assert xplane.annotation(from_bytes, "benchmark-anchor") == \
+        xplane.annotation(from_file, "benchmark-anchor")
+    whole = xplane.reduce_trace(from_file, None, "fixture_program")
+    assert xplane.reduce_trace(from_bytes, None, "fixture_program") == whole
+    # and cut to a window, as a run cuts it
+    s, e = whole["window"]
+    cut = (s + 0.25 * (e - s), e - 0.25 * (e - s))
+    assert xplane.reduce_trace(from_bytes, cut, "fixture_program") == \
+        xplane.reduce_trace(from_file, cut, "fixture_program")
