@@ -30,7 +30,7 @@ every separate-process component — is gated.
 from __future__ import annotations
 
 from contextlib import ExitStack, contextmanager
-from typing import Any, Callable, Iterable
+from typing import Any, Callable, Iterable, Sequence
 
 from ..api import types as t
 
@@ -181,51 +181,83 @@ _VALIDATORS: dict[type, Callable[[Any, list[str]], None]] = {
 }
 
 
+#: (fn, kinds or None for every kind, ``engages`` predicate or None)
+_Registered = tuple[Callable, "set[str] | None", "Callable | None"]
+
+
 class Registry:
     """The admission chain + strategy dispatcher for one server."""
 
     def __init__(self) -> None:
         # hook: fn(kind, key, obj, old) — mutating returns obj|None,
-        # validating raises AdmissionDenied; ``kinds=None`` = every kind
-        self._mutating: list[tuple[Callable, set[str] | None]] = []
-        self._validating: list[tuple[Callable, set[str] | None]] = []
+        # validating raises AdmissionDenied; ``kinds=None`` = every kind.
+        # The third slot is the hook's ``engages`` predicate (None = it
+        # engages for every write of its kinds), see has_dynamic_admission
+        self._mutating: list[_Registered] = []
+        self._validating: list[_Registered] = []
         # locker: fn(kind, key, obj, verb) -> context manager | None; the
         # apiserver holds every matching lock across admit AND the storage
         # write, so a usage-counting validator (quota) sees check+create as
         # one atomic step (the reference's locked quota reservation)
-        self._lockers: list[tuple[Callable, set[str] | None]] = []
+        self._lockers: list[_Registered] = []
 
     def add_mutating_hook(
-        self, fn: Callable, kinds: Iterable[str] | None = None
+        self, fn: Callable, kinds: Iterable[str] | None = None,
+        engages: Callable[[str, Sequence], bool] | None = None,
     ) -> None:
-        self._mutating.append((fn, set(kinds) if kinds else None))
+        self._mutating.append((fn, set(kinds) if kinds else None, engages))
 
     def add_validating_hook(
-        self, fn: Callable, kinds: Iterable[str] | None = None
+        self, fn: Callable, kinds: Iterable[str] | None = None,
+        engages: Callable[[str, Sequence], bool] | None = None,
     ) -> None:
-        self._validating.append((fn, set(kinds) if kinds else None))
+        """``engages(kind, objs)`` is the hook's own promise about a batch
+        of objects it would be handed: False means it would pass every one
+        of them untouched, so the bulk verb may skip it for that batch.
+        A hook registered without one engages for every write. The verb
+        asks with the store lock held: a predicate may read the store, and
+        takes no lock that a write-lock provider holds across a write."""
+        self._validating.append((fn, set(kinds) if kinds else None, engages))
 
     def add_write_lock(
-        self, fn: Callable, kinds: Iterable[str] | None = None
+        self, fn: Callable, kinds: Iterable[str] | None = None,
+        engages: Callable[[str, Sequence], bool] | None = None,
     ) -> None:
         """Register a write-lock provider: ``fn(kind, key, obj, verb)``
         returns a context manager (a ``threading.Lock`` works) scoping the
-        write, or None to pass."""
-        self._lockers.append((fn, set(kinds) if kinds else None))
+        write, or None to pass. ``engages`` as for a hook: False means no
+        write of the batch needs the lock."""
+        self._lockers.append((fn, set(kinds) if kinds else None, engages))
 
-    def has_dynamic_admission(self, kind: str) -> bool:
+    def has_dynamic_admission(self, kind: str, objs: Sequence = ()) -> bool:
         """True when any mutating/validating hook or write-lock provider
-        matches ``kind``. The bulk verb's one-lock storage fast path is
-        only sound for kinds WITHOUT dynamic admission (a usage-counting
-        validator like quota must see each admit+write as one atomic step,
-        and an update hook's ``old`` must reflect earlier ops in the same
-        batch) — such kinds run the batch through the sequential
-        single-verb chain instead."""
-        for _fn, kinds in (
+        matching ``kind`` ENGAGES for the batch ``objs`` (the objects of a
+        bulk request's create/update ops). One registered without an
+        ``engages`` predicate always does, for the empty batch too, which
+        is how a webhook keeps the sequential chain; one registered with a
+        predicate is asked, and what it answers it reads from the store as
+        it stands (quota admission: does a namespace of the batch hold a
+        quota), so the answer moves with the store and needs no restart.
+
+        The bulk verb's one-lock storage pass is only sound for a batch NO
+        hook engages for (a usage-counting validator like quota must see
+        each admit+write as one atomic step, and an update hook's ``old``
+        must reflect earlier ops in the same batch); an engaged batch runs
+        whole through the sequential single-verb chain instead. The verb
+        asks under the store lock that applies the batch (``MemStore.bulk``
+        ``guard``), so the answer cannot go stale before the first write."""
+        # a predicate that hook and lock share (quota's) is asked once
+        passed: list[Callable] = []
+        for _fn, kinds, engages in (
             *self._mutating, *self._validating, *self._lockers,
         ):
             if kinds is None or kind in kinds:
-                return True
+                if engages is None:
+                    return True
+                if objs and engages not in passed:
+                    if engages(kind, objs):
+                        return True
+                    passed.append(engages)
         return False
 
     @contextmanager
@@ -233,7 +265,7 @@ class Registry:
         """Every matching write lock held, in registration order, for the
         duration of the admit + store write."""
         with ExitStack() as stack:
-            for fn, kinds in self._lockers:
+            for fn, kinds, _engages in self._lockers:
                 if kinds is None or kind in kinds:
                     cm = fn(kind, key, obj, verb)
                     if cm is not None:
@@ -246,11 +278,21 @@ class Registry:
     ) -> Any:
         """Mutate → validate strategy → validating hooks. Returns the
         (possibly mutated) object to store, or raises."""
-        for fn, kinds in self._mutating:
+        for fn, kinds, _engages in self._mutating:
             if kinds is None or kind in kinds:
                 replacement = fn(kind, key, obj, old)
                 if replacement is not None:
                     obj = replacement
+        self.validate(kind, key, obj)
+        for fn, kinds, _engages in self._validating:
+            if kinds is None or kind in kinds:
+                fn(kind, key, obj, old)
+        return obj
+
+    def validate(self, kind: str, key: str, obj: Any) -> None:
+        """The kind's validation strategy alone (``admit`` less its hooks):
+        what the bulk verb runs per op on its one-lock pass, where no hook
+        engages."""
         errs: list[str] = []
         _name_key_agree(obj, key, errs)
         validator = _VALIDATORS.get(type(obj))
@@ -258,7 +300,3 @@ class Registry:
             validator(obj, errs)
         if errs:
             raise ValidationError(kind, key, errs)
-        for fn, kinds in self._validating:
-            if kinds is None or kind in kinds:
-                fn(kind, key, obj, old)
-        return obj

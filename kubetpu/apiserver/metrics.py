@@ -10,6 +10,9 @@ Reference names and shapes (metrics.go):
   (watch streams) are EXCLUDED (the reference's longrunning predicate)
   and counted on
 - ``apiserver_longrunning_requests{verb, resource}`` instead
+
+Beside them, kubetpu's own: ``apiserver_bulk_ops_total{resource, path}``,
+the ops of ``:bulk`` requests by the path the request took.
 """
 
 from __future__ import annotations
@@ -110,6 +113,17 @@ class APIServerMetrics:
             labels=("mode",),
             declared={"mode": ("paged", "full")},
         )
+        # which of the bulk verb's two paths a request's ops took: OPS, not
+        # requests, so the one-lock share of a kind's writes is one division
+        self.bulk_ops = r.counter(
+            "apiserver_bulk_ops_total",
+            "Ops of :bulk requests, by the path the request took: one_lock "
+            "(one store lock, one WAL commit, one watch wake-up for the "
+            "batch) or sequential (each op through the single-verb "
+            "admission chain).",
+            labels=("resource", "path"),
+            declared={"path": ("one_lock", "sequential")},
+        )
         # replication-feed egress by path — the chained-shipping
         # acceptance (leader egress ~= one follower's worth) reads the
         # leader's log-path delta
@@ -130,6 +144,15 @@ class APIServerMetrics:
         """Record ``n`` payload bytes moving through the wire seam."""
         if n:
             self.wire_bytes.labels(codec, direction).inc(n)
+
+    def count_bulk_ops(self, resource: str, path: str, n: int) -> None:
+        """Record the ``n`` ops of one :bulk request under the path it
+        took; ``resource`` folds like a failed request's (only a kind some
+        2xx has proved gets a label of its own)."""
+        if n:
+            self.bulk_ops.labels(
+                self._resource_label(resource, succeeded=False), path
+            ).inc(n)
 
     def count_replication(self, path: str, n: int) -> None:
         """Record ``n`` replication-feed payload bytes served."""

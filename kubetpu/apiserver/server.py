@@ -294,6 +294,7 @@ class _Handler(BaseHTTPRequestHandler):
         # per-request stash (one handler instance serves one connection's
         # requests sequentially, so a plain attribute is race-free)
         self._span_pod_traces: list[str] = []
+        self._span_attrs: dict = {}     # a handler's own (BULK: its path)
         t0 = time.perf_counter()
         try:
             with self.metrics.track(
@@ -305,6 +306,7 @@ class _Handler(BaseHTTPRequestHandler):
             attrs: dict = {
                 "verb": verb, "resource": resource,
                 "code": getattr(self, "_status", 0),
+                **self._span_attrs,
             }
             if ctx is not None:
                 # the cross-process join: same trace id as the client's
@@ -1121,29 +1123,55 @@ class _Handler(BaseHTTPRequestHandler):
         """POST /apis/<kind>:bulk — results are positional; each op's
         status/resourceVersion/error matches what its single-op verb would
         have returned, so a mid-batch conflict or admission veto fails only
-        its own op. Two execution paths, chosen by the kind's admission
-        shape:
+        its own op. Two execution paths, chosen per BATCH by whether the
+        admission chain engages for its objects
+        (``Registry.has_dynamic_admission``):
 
-        - no dynamic admission (no hooks, no write locks — the scheduler's
-          bind/status traffic): decode + strategy-validate per op, then
-          apply every surviving storage write under ONE store lock
-          acquisition (``MemStore.bulk``);
-        - dynamic admission present (quota locks, webhooks): each op runs
-          the EXACT single-verb chain sequentially — lock spans admit AND
-          write, and an update's ``old`` reflects earlier ops in the same
-          batch — trading the one-lock storage pass for unchanged
-          admission atomicity (the round trip is still one)."""
+        - ``one_lock``: no hook and no write lock engages (none registered
+          for the kind, or each says so of this batch: quota admission
+          where no namespace of the batch holds a quota — the generator's
+          creates, the scheduler's binds and Events): decode +
+          strategy-validate per op, then apply every surviving storage
+          write under ONE store lock acquisition, one WAL group commit and
+          one watch wake-up (``MemStore.bulk``);
+        - ``sequential``: something engages (a quota in a namespace of the
+          batch, a webhook): the WHOLE batch runs the EXACT single-verb
+          chain op by op — lock spans admit AND write, and an update's
+          ``old`` reflects earlier ops in the same batch — trading the
+          one-lock storage pass for unchanged admission atomicity (the
+          round trip is still one).
+
+        The decision is the storage pass's ``guard``, asked under the same
+        store lock acquisition that applies the batch: a ResourceQuota the
+        store committed before the batch's first write sends all of it
+        down the sequential chain. ``apiserver_bulk_ops_total{resource,
+        path}`` and the BULK span's ``path`` say which was taken."""
         body = self._read_body()
         ops = body.get("ops")
         if not isinstance(ops, list):
             self._error(400, "body must carry an ops list")
             return
-        if self.registry.has_dynamic_admission(kind):
+        # a hook registered without a predicate engages whatever the batch
+        # holds: nothing to prepare, nothing to ask the store
+        path, out = "one_lock", None
+        if not self.registry.has_dynamic_admission(kind):
+            out = self._bulk_one_lock(kind, ops)
+        if out is None:
+            path = "sequential"
+            self._span_pod_traces.clear()   # the chain notes each pod anew
             out = [self._bulk_op_sequential(kind, op) for op in ops]
-            if any(r.get("status", 500) < 400 for r in out):
-                self.metrics.admit_resource(kind)
-            self._reply({"results": out})
-            return
+        if any(r.get("status", 500) < 400 for r in out):
+            # a 2xx op proves the kind exists (same gate as the single
+            # verbs' proving responses)
+            self.metrics.admit_resource(kind)
+        self.metrics.count_bulk_ops(kind, path, len(ops))
+        self._span_attrs["path"] = path
+        self._reply({"results": out})
+
+    def _bulk_one_lock(self, kind: str, ops: list) -> "list[dict] | None":
+        """The one-lock pass of ``_do_bulk``: the positional results, or
+        None (nothing written) when the admission chain engages for the
+        batch's objects after all."""
         results: list[dict | None] = []
         prepared: list[dict | None] = []
         for op in ops:
@@ -1163,10 +1191,10 @@ class _Handler(BaseHTTPRequestHandler):
                     if real == "create":
                         obj = _stamp_pod_ingest(kind, obj)
                     self._note_pod_trace(kind, obj)
-                    # this path only runs WITHOUT dynamic admission, so
-                    # admit() is pure strategy validation — no locker to
-                    # hold, no hook to feed `old`, no per-op store read
-                    obj = self.registry.admit(kind, key, obj, verb=real)
+                    # strategy validation only: the guard below holds that
+                    # no hook engages — no locker to hold, no hook to feed
+                    # `old`, no per-op store read
+                    self.registry.validate(kind, key, obj)
                     prepared.append({
                         "op": real, "key": key, "object": obj,
                         "expect_rv": op.get("resourceVersion"),
@@ -1178,23 +1206,23 @@ class _Handler(BaseHTTPRequestHandler):
                 results.append(_op_error_result(e))
                 prepared.append(None)
         store_ops = [p for p in prepared if p is not None]
-        store_res = iter(self.store.bulk(kind, store_ops))
-        any_ok = False
+        objs = [p["object"] for p in store_ops if "object" in p]
+        stored = self.store.bulk(
+            kind, store_ops,
+            guard=lambda: not self.registry.has_dynamic_admission(kind, objs),
+        )
+        if stored is None:
+            return None
+        store_res = iter(stored)
         out = []
-        for res, prep in zip(results, prepared):
+        for res in results:
             if res is None:
                 # result objects stay LIVE — the negotiated reply codec
                 # encodes them in _reply (no per-op pre-serialization)
                 res = dict(next(store_res))
-            if res.get("status", 500) < 400:
-                any_ok = True
             res.setdefault("resourceVersion", 0)
             out.append(res)
-        if any_ok:
-            # a 2xx op proves the kind exists (same gate as the single
-            # verbs' proving responses)
-            self.metrics.admit_resource(kind)
-        self._reply({"results": out})
+        return out
 
     def _bulk_op_sequential(self, kind: str, op) -> dict:
         """One bulk op through the exact single-verb chain (the dynamic-

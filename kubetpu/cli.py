@@ -221,7 +221,8 @@ def cmd_apiserver(args) -> int:
     # quota enforcement is admission-time (the reference's resourcequota
     # admission plugin): pod creates past a namespace's hard caps get 403;
     # the install also takes the per-namespace write lock so concurrent
-    # creates cannot race past hard
+    # creates cannot race past hard. Both engage only where a quota
+    # exists: a cluster without one pays an O(1) count per pod
     install_quota_admission(registry, store)
     telemetry = getattr(args, "telemetry", "off")
     server = APIServer(

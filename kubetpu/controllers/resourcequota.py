@@ -12,7 +12,8 @@ over non-terminal pods in the quota's namespace.
 ``quota_admission(store)`` builds the validating hook for
 ``apiserver.Registry``: on pod CREATE it recomputes usage live (the
 admission plugin's quota check is synchronous, not informer-lagged) and
-vetoes overflow.
+vetoes overflow. ``install_quota_admission`` registers it, with its write
+lock, so that it ENGAGES only where a quota exists (``quota_engages``).
 """
 
 from __future__ import annotations
@@ -84,11 +85,43 @@ class ResourceQuotaController(QueueController):
             pass   # re-synced on the echo
 
 
+def _hard_quotas(store: MemStore) -> list:
+    """Every ResourceQuota with ``hard`` set. No quota in the store (a
+    cluster that never made one) costs the O(1) ``store.count`` and no
+    list: a list of ANY kind walks every object of the store under its
+    lock."""
+    if not store.count(RESOURCE_QUOTAS):
+        return []
+    return [q for _k, q in store.list(RESOURCE_QUOTAS)[0] if q.hard]
+
+
+def quota_engages(store: MemStore):
+    """The ``engages`` predicate of quota admission's hook and write lock
+    (``Registry.has_dynamic_admission``): True when a namespace of the
+    batch's objects holds a quota with ``hard`` set — read from the store
+    as it stands, so a quota created later engages from its commit on and
+    a deleted one lets go, with no restart. Where it answers False the
+    hook would return at its first line for every object of the batch,
+    and there is nothing for the lock to make atomic."""
+
+    def engages(kind: str, objs) -> bool:
+        if kind != PODS:
+            return False
+        quota_ns = {q.namespace for q in _hard_quotas(store)}
+        return bool(quota_ns) and any(
+            getattr(o, "namespace", "") in quota_ns for o in objs
+        )
+
+    return engages
+
+
 def quota_admission(store: MemStore):
     """Validating-hook factory for apiserver.Registry: reject pod creates
     that would exceed any ResourceQuota in the namespace (admission is
     synchronous against the LIVE store, like the reference's quota
-    evaluator — informer lag cannot let a burst slip past hard).
+    evaluator — informer lag cannot let a burst slip past hard). With no
+    ResourceQuota in the store it returns after one O(1) count, before any
+    list; with one somewhere it lists quotas, then the pods, as ever.
 
     The check alone is NOT race-free: two concurrent POSTs can both read
     usage below ``hard`` and both create. Install via
@@ -101,8 +134,7 @@ def quota_admission(store: MemStore):
         if kind != PODS or old is not None:
             return    # creates only (updates don't add pods)
         quotas = [
-            q for _k, q in store.list(RESOURCE_QUOTAS)[0]
-            if q.namespace == obj.namespace and q.hard
+            q for q in _hard_quotas(store) if q.namespace == obj.namespace
         ]
         if not quotas:
             return
@@ -152,6 +184,14 @@ def quota_write_lock():
 def install_quota_admission(registry, store: MemStore) -> None:
     """Wire quota enforcement onto an apiserver admission registry: the
     live-usage validating hook plus the per-namespace write lock that makes
-    check+create atomic under concurrency."""
-    registry.add_validating_hook(quota_admission(store), kinds=(PODS,))
-    registry.add_write_lock(quota_write_lock(), kinds=(PODS,))
+    check+create atomic under concurrency. Both are registered with
+    ``quota_engages``: a pods ``:bulk`` batch none of whose namespaces
+    holds a quota takes the verb's one-lock pass, any other the sequential
+    chain; the single verbs run the chain always."""
+    engages = quota_engages(store)
+    registry.add_validating_hook(
+        quota_admission(store), kinds=(PODS,), engages=engages,
+    )
+    registry.add_write_lock(
+        quota_write_lock(), kinds=(PODS,), engages=engages,
+    )

@@ -62,6 +62,9 @@ struct StoreObject {
   long long body_hits[kNumCodecs];
   long long body_misses[kNumCodecs];
   std::unordered_map<std::string, Entry>* objects;
+  // live objects per kind, kept at every insert/erase of `objects` — what
+  // count(kind) answers without a walk
+  std::unordered_map<std::string, long long>* kind_counts;
   std::deque<Event>* events;
 };
 
@@ -234,6 +237,7 @@ PyObject* store_create(StoreObject* self, PyObject* args) {
   self->rv += 1;
   Py_INCREF(obj);
   (*self->objects)[mk] = {obj, self->rv, ++self->seq_counter};
+  (*self->kind_counts)[kind] += 1;
   push_event(self, 0, kind, key, obj);
   return PyLong_FromLongLong(self->rv);
 }
@@ -264,6 +268,7 @@ PyObject* store_update(StoreObject* self, PyObject* args) {
     it->second.rv = self->rv;  // seq unchanged: updates do not reorder
   } else {
     (*self->objects)[mk] = {obj, self->rv, ++self->seq_counter};
+    (*self->kind_counts)[kind] += 1;
   }
   push_event(self, existed ? 1 : 0, kind, key, obj);
   return PyLong_FromLongLong(self->rv);
@@ -281,6 +286,7 @@ PyObject* store_delete(StoreObject* self, PyObject* args) {
   }
   PyObject* old = it->second.obj;
   self->objects->erase(it);
+  (*self->kind_counts)[kind] -= 1;
   self->rv += 1;
   push_event(self, 2, kind, key, old);
   Py_DECREF(old);
@@ -296,6 +302,15 @@ PyObject* store_get(StoreObject* self, PyObject* args) {
     return Py_BuildValue("(OL)", Py_None, 0LL);
   }
   return Py_BuildValue("(OL)", it->second.obj, it->second.rv);
+}
+
+// count(kind) -> live objects of the kind, O(1): a lookup, never a walk.
+PyObject* store_count(StoreObject* self, PyObject* args) {
+  const char* kind;
+  if (!PyArg_ParseTuple(args, "s", &kind)) return nullptr;
+  auto it = self->kind_counts->find(kind);
+  return PyLong_FromLongLong(it == self->kind_counts->end() ? 0
+                                                            : it->second);
 }
 
 // list(kind[, label_terms, field_terms]) — selector terms are evaluated
@@ -670,6 +685,7 @@ PyObject* store_load_snapshot(StoreObject* self, PyObject* args) {
   if (!seq) return nullptr;
   for (auto& kv : *self->objects) Py_DECREF(kv.second.obj);
   self->objects->clear();
+  self->kind_counts->clear();
   for (auto& e : *self->events) {
     Py_DECREF(e.obj);
     for (int c = 0; c < kNumCodecs; ++c) Py_XDECREF(e.bodies[c]);
@@ -688,8 +704,14 @@ PyObject* store_load_snapshot(StoreObject* self, PyObject* args) {
       return nullptr;
     }
     Py_INCREF(obj);
-    (*self->objects)[map_key(kind, key)] = {obj, obj_rv,
-                                            ++self->seq_counter};
+    auto mk = map_key(kind, key);
+    auto slot = self->objects->find(mk);
+    if (slot == self->objects->end()) {
+      (*self->kind_counts)[kind] += 1;
+    } else {
+      Py_DECREF(slot->second.obj);  // repeated key: the later item wins
+    }
+    (*self->objects)[mk] = {obj, obj_rv, ++self->seq_counter};
   }
   Py_DECREF(seq);
   self->rv = rv;
@@ -721,6 +743,7 @@ PyObject* store_new(PyTypeObject* type, PyObject* args, PyObject*) {
     self->body_misses[c] = 0;
   }
   self->objects = new std::unordered_map<std::string, Entry>();
+  self->kind_counts = new std::unordered_map<std::string, long long>();
   self->events = new std::deque<Event>();
   return (PyObject*)self;
 }
@@ -732,6 +755,7 @@ void store_dealloc(StoreObject* self) {
     for (int c = 0; c < kNumCodecs; ++c) Py_XDECREF(e.bodies[c]);
   }
   delete self->objects;
+  delete self->kind_counts;
   delete self->events;
   Py_TYPE(self)->tp_free((PyObject*)self);
 }
@@ -741,6 +765,7 @@ PyMethodDef store_methods[] = {
     {"update", (PyCFunction)store_update, METH_VARARGS, nullptr},
     {"delete", (PyCFunction)store_delete, METH_VARARGS, nullptr},
     {"get", (PyCFunction)store_get, METH_VARARGS, nullptr},
+    {"count", (PyCFunction)store_count, METH_VARARGS, nullptr},
     {"list", (PyCFunction)store_list, METH_VARARGS, nullptr},
     {"list_page", (PyCFunction)store_list_page, METH_VARARGS, nullptr},
     {"events_since", (PyCFunction)store_events_since, METH_VARARGS, nullptr},

@@ -37,7 +37,7 @@ import collections
 import os
 import threading
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Callable
 
 from . import faultpoints
 
@@ -128,7 +128,7 @@ class WatchEvent:
 
 class _PyCore:
     """Pure-Python core: the same micro-interface as the native StoreCore
-    (create/update/delete/get/list/events_since[+bulk]/
+    (create/update/delete/get/count/list/events_since[+bulk]/
     event_bodies_since[+bulk]/resource_version), same exception types
     (KeyError/ValueError/LookupError — mapped by the wrapper).
 
@@ -144,6 +144,9 @@ class _PyCore:
         # updates), the paged list walk's cursor axis; matches the native
         # core's Entry.seq
         self._objects: dict[tuple[str, str], tuple[Any, int, int]] = {}
+        # live objects per kind, kept at every insert/pop of ``_objects``:
+        # what ``count`` answers without a walk
+        self._kind_counts: collections.Counter = collections.Counter()
         self._seq = 0
         self._events: collections.deque = collections.deque(maxlen=history)
         self._compacted_through = 0
@@ -161,6 +164,7 @@ class _PyCore:
         self._rv += 1
         self._seq += 1
         self._objects[(kind, key)] = (obj, self._rv, self._seq)
+        self._kind_counts[kind] += 1
         self._emit(0, kind, key, obj)
         return self._rv
 
@@ -177,6 +181,7 @@ class _PyCore:
         if got is None:
             self._seq += 1
             seq = self._seq
+            self._kind_counts[kind] += 1
         else:
             seq = got[2]                 # updates do not reorder
         self._objects[(kind, key)] = (obj, self._rv, seq)
@@ -187,6 +192,7 @@ class _PyCore:
         got = self._objects.pop((kind, key), None)
         if got is None:
             raise KeyError(f"{kind}/{key} not found")
+        self._kind_counts[kind] -= 1
         self._rv += 1
         self._emit(2, kind, key, got[0])
         return self._rv
@@ -194,6 +200,10 @@ class _PyCore:
     def get(self, kind: str, key: str):
         got = self._objects.get((kind, key))
         return (None, 0) if got is None else (got[0], got[1])
+
+    def count(self, kind: str) -> int:
+        """Live objects of ``kind``, O(1): a lookup, never a walk."""
+        return self._kind_counts[kind]
 
     def list(self, kind: str, label_terms: tuple = (),
              field_terms: tuple = ()):
@@ -359,6 +369,9 @@ class _PyCore:
             (kind, key): (obj, obj_rv, seq)
             for seq, (kind, key, obj, obj_rv) in enumerate(items, start=1)
         }
+        self._kind_counts = collections.Counter(
+            kind for kind, _key in self._objects
+        )
         self._seq = len(self._objects)
         self._rv = rv
         self._events.clear()
@@ -593,7 +606,8 @@ class MemStore:
         return self._commit_locked("delete", kind, key)  # KeyError propagates
 
     # --------------------------------------------------------------- bulk
-    def bulk(self, kind: str, ops: list[dict]) -> list[dict]:
+    def bulk(self, kind: str, ops: list[dict],
+             guard: Callable[[], bool] | None = None) -> list[dict] | None:
         """Apply a list of create/update/delete/get ops under ONE lock
         acquisition (the bulk verb's storage half: N writes pay one lock
         round instead of N). Ops are dicts ``{"op": "create|update|delete|
@@ -601,9 +615,18 @@ class MemStore:
         positional, one ``{"status", "resourceVersion", "error"?,
         "object"?}`` per op with the SAME per-object conflict/absence
         semantics as the single-op verbs (a mid-batch conflict fails only
-        its own op — later ops still apply)."""
+        its own op — later ops still apply).
+
+        ``guard`` is asked once, under the SAME lock acquisition that
+        applies the batch (the lock is reentrant, so it may read the
+        store): when it answers False nothing is applied and None comes
+        back. That is how the apiserver's bulk verb decides on its one-lock
+        pass without a window: whatever the store committed before this
+        batch's first write, the guard has seen."""
         out: list[dict] = []
         with self._lock:
+            if guard is not None and not guard():
+                return None
             for op in ops:
                 verb, key = op.get("op"), op.get("key")
                 try:
@@ -756,6 +779,15 @@ class MemStore:
     def get(self, kind: str, key: str):
         with self._lock:
             return self._core.get(kind, key)
+
+    def count(self, kind: str) -> int:
+        """How many live objects of ``kind`` the store holds, O(1): both
+        cores keep the number at create / delete / snapshot load (WAL
+        recovery and a follower's apply go through the same verbs), so
+        "is there any ResourceQuota" costs a lookup where ``list`` walks
+        every object of every kind."""
+        with self._lock:
+            return self._core.count(kind)
 
     @staticmethod
     def _parse_selectors(label_selector: str, field_selector: str):
