@@ -43,7 +43,7 @@ if not os.environ.get("KUBETPU_NO_X64"):
     jax.config.update("jax_enable_x64", True)
 
 # ONE placement rule for JAX's persistent compilation cache, for every entry
-# point (bench.py, chip_smoke.py, ``python -m kubetpu ...`` and
+# point (chip_smoke.py, ``python -m kubetpu ...`` and
 # ``python -m kubetpu.perf`` all import this package first): whoever launches
 # the process places the cache through JAX_COMPILATION_CACHE_DIR, which jax
 # reads into its config at import, and then nothing is set here. Otherwise

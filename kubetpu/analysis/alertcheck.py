@@ -4,11 +4,11 @@ PR 16's anomaly sentinel judges live series against a DECLARATIVE rule
 table (``telemetry/rules.py``): every budget, burn threshold, outlier
 trip point, and window lives on a ``Rule`` and nowhere else. That split
 is what makes the alerting reviewable — one file answers "when does this
-page?" — and what keeps the bench-scaled variants honest:
+page?" — and what keeps the run-scaled variants honest:
 ``fast_rules()`` derives its windows from the SAME rows production
 evaluates, so a threshold that drifts into an evaluator is invisible to
 the table, untested by the scaled suite, and silently different between
-``kubetpu scheduler --sentinel on`` and the bench acceptance run.
+``kubetpu scheduler --sentinel on`` and a perf-harness run.
 
 AL001 pins the seam on the evaluation side (``telemetry/sentinel.py``):
 
@@ -72,7 +72,7 @@ class AlertThresholdLiteral(Checker):
         "The sentinel's alerting contract is a DECLARATIVE rule table "
         "(telemetry/rules.py): budgets, burn thresholds, outlier trip "
         "points and windows live on Rule rows and nowhere else, so one "
-        "file answers 'when does this page?' and the bench-scaled "
+        "file answers 'when does this page?' and the run-scaled "
         "fast_rules() variants provably evaluate the same policy as "
         "production. A literal comparison inside an evaluator — "
         "`if burn > 6.0` instead of `if burn > rule.burn_threshold` — "

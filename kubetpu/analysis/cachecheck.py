@@ -55,7 +55,7 @@ class UnscopedEpochFlush(Checker):
         "call added anywhere else — or a raw node_epoch assignment — "
         "silently reverts the 100k-node add-wave path to a full re-encode "
         "storm per event: nothing errors, the cache still 'works', and "
-        "the scale-frontier admission p99s decay until a bench run "
+        "the scale-frontier admission p99s decay until a measured run "
         "notices. Call invalidate_nodes(added=node) for appends; route "
         "genuine full flushes through the blessed handlers so the scope "
         "decision stays reviewable in one place."

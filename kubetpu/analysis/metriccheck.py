@@ -258,8 +258,8 @@ class MetricDeclaredLabelValues(Checker):
     rationale = (
         "The staged-latency histograms carry a CLOSED label contract: "
         "scheduler_e2e_scheduling_duration_seconds{stage} is registered "
-        "with declared={'stage': E2E_STAGES}, and every dashboard, bench "
-        "field and benchdiff comparison joins on exactly those stage "
+        "with declared={'stage': E2E_STAGES}, and every dashboard and "
+        "result field joins on exactly those stage "
         "names. The registry rejects unknown values at .labels() time, "
         "but that only fires when the emitting line runs — a typo'd "
         "stage on a rare path (bind_rtt vs bind_rt) would silently "

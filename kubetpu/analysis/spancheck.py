@@ -78,8 +78,7 @@ class ProfilerTraceBalance(Checker):
         "recording for the life of the process, swamping the trace "
         "directory and skewing every later measurement. start_trace "
         "appears only with a stop_trace in a `finally` block of the "
-        "same function (the tracing.device_profile contextmanager is "
-        "the blessed wrapper)."
+        "same function."
     )
 
     def collect(self, mod: ModuleInfo):

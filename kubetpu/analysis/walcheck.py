@@ -7,7 +7,7 @@ mutation called anywhere else — a new verb calling ``self._core.create``
 directly, a helper that grabs ``core = self._core`` and updates through
 the alias — commits state the WAL never saw: recovery silently loses the
 write, the replay chain's rv check explodes one record later, and the
-exactly-once binding parity the federation bench asserts is gone. This
+exactly-once binding parity the multi-process runs assert is gone. This
 checker moves that invariant to parse time, alias-resolving like WP001:
 any ``create``/``update``/``delete`` call whose receiver resolves to a
 store core (``self._core``, or a local name assigned from one) outside

@@ -56,7 +56,7 @@ class BareJsonOnWirePath(Checker):
         "the reply ignores what the client negotiated (a binary client "
         "gets undecodable JSON or — worse — a JSON client gets bytes it "
         "cannot parse), the payload escapes the "
-        "apiserver_wire_bytes_total accounting the bench ladder reads, "
+        "apiserver_wire_bytes_total accounting, "
         "and per-watcher re-serialization silently returns to the fan-"
         "out path the EventEncodeCache/body ring exist to protect. "
         "Route object bodies through kubetpu.api.codec. Diagnostics "

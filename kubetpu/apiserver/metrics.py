@@ -90,8 +90,7 @@ class APIServerMetrics:
         )
         # the wire-protocol evidence counter: payload bytes by codec and
         # direction (request bodies in, reply/stream bodies out) — the
-        # bench ladder's wire_bytes_per_pod numerator and the ≥60%
-        # byte-reduction acceptance read from here
+        # perf harness's wire_bytes_per_pod numerator
         self.wire_bytes = r.counter(
             "apiserver_wire_bytes_total",
             "Request and response wire payload bytes by codec and "
@@ -161,7 +160,7 @@ class APIServerMetrics:
 
     def replication_bytes_total(self, path: str | None = None) -> int:
         """Lifetime replication-feed egress bytes, optionally by path —
-        the chained-shipping bench's leader-egress probe."""
+        the leader-egress probe of a chained-shipping run."""
         total = 0
         for key, child in self.replication_bytes._children_snapshot():
             if path is not None and key[0] != path:

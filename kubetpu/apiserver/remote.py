@@ -104,9 +104,9 @@ class RemoteStore:
         # diagnostics scrape share it)
         self._reconnect_lock = threading.Lock()
         self.reconnect_counts: dict[str, int] = {}
-        # paged-relist evidence for the bench ladder: cumulative totals
-        # plus the last walk's shape (pages, wire bytes, largest page) —
-        # ListScaling's pages/relist and bytes/relist read from here
+        # paged-relist evidence: cumulative totals plus the last walk's
+        # shape (pages, wire bytes, largest page) — run_list_scaling's
+        # pages/relist and bytes/relist read from here
         self.relist_stats: dict[str, int] = {
             "relists": 0, "pages": 0, "bytes": 0, "max_page_bytes": 0,
         }
@@ -199,7 +199,7 @@ class RemoteStore:
     @property
     def wire_codec(self) -> str:
         """The codec request BODIES currently ride ("binary" only after
-        the server confirmed the dialect) — the bench's wire_codec tag."""
+        the server confirmed the dialect) — a result's wire_codec tag."""
         return codec.BINARY if self._wire_ok else codec.JSON
 
     # ------------------------------------------------------------ plumbing

@@ -101,7 +101,7 @@ class PackingWeights:
                           carries load (concentrates the workload into
                           fewer slices). Inert without a topology block.
 
-    Serialized into bench records (``WorkloadResult.packing_weights``) so a
+    Serialized into perf results (``WorkloadResult.packing_weights``) so a
     measured frontier is reproducible from its JSON alone.
     """
 

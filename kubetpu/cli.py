@@ -20,8 +20,6 @@ Commands (the control-plane binaries + tooling):
 - ``explain``             render a pod's scheduling flight-recorder record
                           (timeline + why-node-won / why-filtered) from a
                           scheduler's /debug/flightrecorder or a JSON dump
-- ``benchdiff``           compare two bench records with noise-aware
-                          thresholds; non-zero exit on regression
 - ``store fsck|compact``  durable-store tooling: offline integrity report /
                           WAL-into-snapshot compaction for a persistence dir
 - ``version``             print the framework version
@@ -1929,15 +1927,6 @@ def build_parser() -> argparse.ArgumentParser:
     st_compact.add_argument("--dir", required=True)
     st_compact.set_defaults(fn=cmd_store_compact)
 
-    bd = sub.add_parser(
-        "benchdiff",
-        help="compare two bench records metric-by-metric; non-zero exit "
-             "on a throughput or staged-p99 regression "
-             "(see python -m kubetpu.benchdiff)",
-    )
-    bd.add_argument("rest", nargs=argparse.REMAINDER)
-    bd.set_defaults(fn=None)
-
     coll = sub.add_parser(
         "collector",
         help="run the telemetry collector: span/metrics/flight-record "
@@ -2105,12 +2094,6 @@ def main(argv: Sequence[str] | None = None) -> int:
         from .analysis.__main__ import main as analyze_main
 
         return analyze_main(raw[1:]) or 0
-    if raw and raw[0] == "benchdiff":
-        # dispatch before argparse: REMAINDER drops leading flags
-        # (`kubetpu benchdiff --json a b` must reach the sub-CLI intact)
-        from .benchdiff import main as benchdiff_main
-
-        return benchdiff_main(raw[1:])
     args = build_parser().parse_args(argv)
     if args.command == "perf":
         from .perf.__main__ import main as perf_main

@@ -7,7 +7,8 @@ supervisor owning the full child lifecycle (``supervisor`` — THE
 ``subprocess.Popen`` seam, pinned by graftcheck PS001), and the standard
 topology builder (``cluster`` — apiserver + N scheduler replicas +
 optional collector + watch-fanout drivers), shared verbatim by the tier-1
-multi-process smoke, ``kubetpu up``, and the mp bench ladder.
+multi-process smoke, ``kubetpu up``, and the perf runner's multi-process
+drivers.
 """
 
 from .banner import (  # noqa: F401

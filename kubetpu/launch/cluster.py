@@ -10,7 +10,7 @@ together through readiness banners (nobody pre-picks a port):
 ``kubetpu up`` serves this topology interactively; the perf runner's
 ``run_workload_multiprocess`` drives a workload against it and joins on
 the store-verified binding parity. Both go through the same ChildSpec
-builders, so the tier-1 smoke, the CLI, and the bench ladder exercise ONE
+builders, so the tier-1 smoke, the CLI, and the perf runner exercise ONE
 spawn/readiness/shutdown path (the PR-13 dedup contract).
 """
 
@@ -162,8 +162,8 @@ class Cluster:
     replicas: int = 1
     apiservers: int = 1
     #: writer-lease duration handed to a REPLICATED plane's apiservers
-    #: (0 = the CLI default). The failover bench tunes this down so
-    #: failover_to_serving_s measures the protocol, not a lazy lease.
+    #: (0 = the CLI default). A failover test tunes this down so that it
+    #: exercises the protocol, not a lazy lease expiry.
     lease_duration_s: float = 0.0
     #: chained replication shipping: follower i>1 tails follower i-1's
     #: re-served feed instead of the leader (leader ships ONE stream; a

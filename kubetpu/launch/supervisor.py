@@ -4,7 +4,7 @@ THE spawn seam (graftcheck PS001): every ``subprocess.Popen`` in
 ``kubetpu/`` lives here, so child lifecycle — ephemeral-port readiness
 banners, health polling, log capture, restart policy, SIGTERM-cascade
 shutdown — is owned by one auditable module instead of re-grown ad hoc in
-every test/bench that needs a process. Generalizes the
+every test or runner that needs a process. Generalizes the
 spawn/banner-wait/timeout-kill pattern the PR-12 telemetry smoke proved.
 
 Lifecycle of one child:
@@ -28,7 +28,7 @@ Lifecycle of one child:
    PR-11 WAL path (flush + close after the listener stops: no torn tail).
    ``join(verify=…)`` runs a verification callback BETWEEN the phases,
    while the apiserver is still serving — the store-verified exactly-once
-   binding-parity check the mp bench ladder reports success through.
+   binding-parity check the multi-process runs report success through.
 
 The supervisor never daemonizes: children are direct children of the
 calling process, so a dead supervisor's children die with the test run
@@ -458,7 +458,7 @@ class Supervisor:
     # -------------------------------------------------------------- evidence
     def child_stats(self) -> dict:
         """{name: {pid, restarts, peak_rss_bytes?, cpu_seconds?}} — the
-        per-child resource evidence the mp bench records embed."""
+        per-child resource evidence a multi-process result embeds."""
         return {c.name: c.stats() for c in self.children}
 
     def __enter__(self) -> "Supervisor":

@@ -321,7 +321,7 @@ class SchedulerMetricsRegistry:
     def staged_percentiles(self, baseline: dict | None = None) -> dict | None:
         """Per-stage p50/p99 (ms) of the staged latency vector, scoped to
         the window since ``baseline`` (a ``snapshot_baseline``) — the
-        ``staged_latency_ms`` block every fullstack bench record carries.
+        ``staged_latency_ms`` block every fullstack perf result carries.
         None when no stage observed anything in the window."""
         base = (baseline or {}).get("e2e_stages", {})
         out = {}
@@ -334,9 +334,9 @@ class SchedulerMetricsRegistry:
         return out or None
 
     def snapshot(self, baseline: dict | None = None) -> dict:
-        """Post-run summary embedded in BENCH artifacts: p50/p99 from the
+        """Post-run summary embedded in perf results: p50/p99 from the
         histograms plus schedule_attempts by result — the numbers a
-        dashboard would derive from a scrape, pre-derived so every bench
+        dashboard would derive from a scrape, pre-derived so every result
         JSON is self-describing. With ``baseline`` (a
         ``snapshot_baseline``), everything is the DELTA since it."""
 

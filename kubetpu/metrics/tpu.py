@@ -7,7 +7,7 @@ hitting (a miss stalls a cycle by seconds), how many bytes the host→device
 encode ships, and where device wall time goes per cycle. ``SURVEY §5``'s
 span-per-cycle design joins these to the host trace by CYCLE ID: every
 ``record_cycle`` keeps a join record the trace exporter and the perf
-harness dump next to the bench JSON.
+harness dump next to the result JSON.
 
 Metric set (labels ``engine`` = greedy | batched):
 

@@ -96,7 +96,7 @@ def free_slices(
     num_slices: int,
 ) -> jnp.ndarray:
     """int32 — labeled slices with ≥1 valid node and ZERO requested
-    resources anywhere (the bench's ``slices_free_at_steady_state``)."""
+    resources anywhere (a result's ``slices_free_at_steady_state``)."""
     active, sizes = slice_occupancy(requested, node_valid, slice_id, num_slices)
     labeled_active = active[:num_slices] if num_slices else active[:0]
     labeled_sizes = sizes[:num_slices] if num_slices else sizes[:0]

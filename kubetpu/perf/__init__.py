@@ -5,7 +5,6 @@ from .runner import (
     WorkloadResult,
     run_label,
     run_workload,
-    run_workload_federated,
     run_workload_full_stack,
     run_workload_multiprocess,
     run_workload_trace,
@@ -27,7 +26,6 @@ __all__ = [
     "WorkloadResult",
     "run_label",
     "run_workload",
-    "run_workload_federated",
     "run_workload_full_stack",
     "run_workload_multiprocess",
     "run_workload_trace",

@@ -12,7 +12,6 @@ from . import (
     TEST_CASES,
     run_label,
     run_workload,
-    run_workload_federated,
     run_workload_multiprocess,
 )
 
@@ -73,13 +72,12 @@ def main(argv=None) -> None:
                          "alongside the workload — an HTTP collector, "
                          "traceparent on every RPC, both processes' "
                          "exporters on their cadence; the record embeds "
-                         "span totals + the drop counter (the "
-                         "TelemetryOverhead on/off comparison's 'on' half)")
+                         "span totals + the drop counter")
     ap.add_argument("--sentinel", default="off",
                     choices=["on", "off", "spike"],
                     help="fullstack or --trace: ride the anomaly "
                          "sentinel on the scheduler's cycle boundary "
-                         "(bench-scaled rule windows; the record embeds "
+                         "(run-scaled rule windows; the record embeds "
                          "its lifecycle stats and the clean/false-"
                          "positive verdict); 'spike' additionally "
                          "injects a one-shot scheduling stall mid-run "
@@ -106,26 +104,8 @@ def main(argv=None) -> None:
     ap.add_argument("--restart", default="on-failure:2",
                     metavar="never|on-failure[:max]",
                     help="multi-process only: per-scheduler supervisor "
-                         "restart policy — a replica killed by "
-                         "--kill-replica-at is respawned and re-federates")
-    ap.add_argument("--replicas", type=int, default=1,
-                    help="run N full scheduler replicas against one "
-                         "in-process apiserver (active-active federation, "
-                         "sched.federation) — each replica on its own loop "
-                         "thread; 1 = the ordinary single scheduler")
-    ap.add_argument("--partition", default="race",
-                    choices=["hash", "race", "lease"],
-                    help="federation partition mode (with --replicas > 1): "
-                         "hash = pods split by key hash (no overlap), race "
-                         "= all replicas race on every pod (CAS bind "
-                         "arbitrates, 409 losers requeue with conflict "
-                         "backoff), lease = epoch-fenced renewable "
-                         "partition leases over the pod keyspace")
-    ap.add_argument("--kill-replica-at", type=float, default=None,
-                    help="fraction of the measured pods (0..1) at which to "
-                         "kill the last replica mid-bench; the record then "
-                         "carries recovery_s (time for the survivors to "
-                         "re-absorb its partition)")
+                         "restart policy — a replica that dies is "
+                         "respawned and re-federates")
     ap.add_argument("--artifacts-dir", default=None,
                     help="dump per-workload diagnosis artifacts here: the "
                          "cycle trace as Perfetto-loadable Chrome-trace "
@@ -197,8 +177,6 @@ def main(argv=None) -> None:
         if not args.fullstack:
             ap.error("--processes requires --fullstack (there is no "
                      "direct-mode multi-process deployment)")
-        if args.kill_replica_at is not None and args.processes < 2:
-            ap.error("--kill-replica-at requires --processes >= 2")
         case = TEST_CASES[args.case]
         workloads = (
             [w for w in case.workloads if w.name == args.workload]
@@ -208,7 +186,6 @@ def main(argv=None) -> None:
             r = run_workload_multiprocess(
                 case, wl,
                 replicas=args.processes,
-                partition=args.partition,
                 wire=args.wire,
                 engine=args.engine,
                 max_batch=args.max_batch,
@@ -220,32 +197,7 @@ def main(argv=None) -> None:
                 telemetry=(args.telemetry == "on"),
                 watch_fanout=args.watch_fanout,
                 fanout_procs=args.fanout_procs,
-                kill_replica_at=args.kill_replica_at,
                 restart=args.restart,
-            )
-            print(json.dumps(r.to_json()))
-        return
-    if args.kill_replica_at is not None and args.replicas < 2:
-        # a 1-replica "kill" can never fire — a recovery measurement with
-        # no kill would be silently meaningless
-        ap.error("--kill-replica-at requires --replicas >= 2")
-    if args.replicas > 1 or args.kill_replica_at is not None:
-        # federated fullstack: N in-process schedulers, one apiserver
-        case = TEST_CASES[args.case]
-        workloads = (
-            [w for w in case.workloads if w.name == args.workload]
-            if args.workload else list(case.workloads)
-        )
-        for wl in workloads:
-            r = run_workload_federated(
-                case, wl,
-                replicas=max(args.replicas, 1),
-                partition=args.partition,
-                kill_replica_at=args.kill_replica_at,
-                max_batch=args.max_batch, timeout_s=args.timeout,
-                engine=args.engine,
-                bulk=(args.bulk == "on"),
-                flight_recorder=(args.flight_recorder == "on"),
             )
             print(json.dumps(r.to_json()))
         return

@@ -36,10 +36,8 @@ from . import workloads as W
 
 
 def round_latency_ms(v: float | None) -> float | None:
-    """THE latency rounding for bench artifacts (2 decimals) — one place,
-    used by WorkloadResult.to_json AND bench.py's stage lines, so a
-    benchdiff between a runner emission and a bench emission never sees
-    phantom rounding deltas."""
+    """THE latency rounding for persisted results (2 decimals) — one
+    place, so two emissions of one value never differ by rounding."""
     return None if v is None else round(float(v), 2)
 
 
@@ -105,7 +103,7 @@ class WorkloadResult:
     # shards_with_transfer (shards that received routed delta bytes)
     mesh_placement: dict | None = None
     # post-run metric snapshot (SchedulerMetricsRegistry.snapshot): p50/p99
-    # from the histograms + schedule_attempts by result — every BENCH json
+    # from the histograms + schedule_attempts by result — every result JSON
     # carries its own diagnosis
     metrics_snapshot: dict | None = None
     # per-pod staged latency attribution, measured-window scoped
@@ -128,8 +126,8 @@ class WorkloadResult:
     wire_codec: str = ""
     wire_bytes_per_pod: float | None = None
     watch_fanout: int = 0
-    # active-active federation (sched.federation; --replicas N
-    # --partition hash|race|lease): replica count, partition mode, total
+    # active-active federation (sched.federation, the multi-process
+    # drivers' replicas= / partition=): replica count, partition mode, total
     # CAS-bind conflicts + conflict rate (conflicted attempts / all bind
     # attempts), binding_parity (store-verified pods bound exactly once —
     # must equal measure_pods for a lossless run), lease transitions, and
@@ -146,8 +144,7 @@ class WorkloadResult:
     lease_transitions: int = 0
     recovery_s: float | None = None
     # telemetry-plane view when a run exported to a collector
-    # (--telemetry): ingested span totals and the drop counter the
-    # TelemetryOverhead gate asserts stayed zero
+    # (--telemetry): ingested span totals and the drop counter
     telemetry: dict | None = None
     # anomaly-sentinel view when a run rode the sentinel (--sentinel):
     # lifecycle stats (evaluations/fired/bundles), the per-alert final
@@ -174,32 +171,32 @@ class WorkloadResult:
     follower_lag_records: int | None = None
     # chained replication shipping (``--replication-chain``): follower i
     # tails follower i-1 instead of the leader, so the leader's egress is
-    # ONE follower's worth regardless of fan-out — the rung records the
+    # ONE follower's worth regardless of fan-out — the result records the
     # topology it ran and the leader's apiserver_replication_bytes_total
     # over the run (the egress claim's evidence)
     replication_chain: bool = False
     leader_replication_bytes: float | None = None
     # --- trace-shaped workloads (run_workload_trace) ---------------------
     # admission-latency SLO: p50/p99 of enqueue→bind over every pod the
-    # trace created, judged against the profile's declared budget — the
-    # scale-frontier metric benchdiff gates (slo_ok = p99 <= budget)
+    # trace created, judged against the profile's declared budget
+    # (slo_ok = p99 <= budget)
     admission_p50_ms: float | None = None
     admission_p99_ms: float | None = None
     slo_budget_ms: float | None = None
     slo_ok: bool | None = None
     # host-memory ceiling of the stage: max RSS sampled per cycle during
-    # the measured window (benchdiff gates +50% AND >256MB absolute)
+    # the measured window
     peak_rss_bytes: int = 0
     # the stage hit its wall budget and emitted a TRUNCATED-but-parseable
-    # record instead of eating the whole bench wall (the 100k-node rungs)
+    # record instead of running on (the 100k-node rungs)
     truncated: bool = False
     # trace bookkeeping: events replayed / pods created / deleted by the
     # trace / still unbound at the end / node count when it finished, and
     # the encode-cache re-encode accounting (scoped-invalidation evidence)
     trace_stats: dict | None = None
     # --- packing frontier (PR 19) ----------------------------------------
-    # utilization-vs-throughput evidence, engine-agnostic so the three-way
-    # PackingComparison ladder reads the same keys from every rung:
+    # utilization-vs-throughput evidence, engine-agnostic so a comparison
+    # of the three engines reads the same keys from every run:
     # distinct nodes carrying the measured pods once the run settled, the
     # fraction of high-priority (priority > 0) measured pods that actually
     # bound, and — packing cycles only — the warm-started solver's mean
@@ -212,16 +209,16 @@ class WorkloadResult:
     # --- node-topology axis (PR 20) --------------------------------------
     # slice-level fragmentation evidence on labeled fleets: the topology
     # mode the run used, total labeled TPU slices, how many were FULLY
-    # free when the trace settled (benchdiff gates a drop), the fraction
-    # of labeled slices left partially occupied (0 = perfectly defragged,
-    # benchdiff gates drift), and the p99 quorum→admitted gang latency
+    # free when the trace settled, the fraction of labeled slices left
+    # partially occupied (0 = perfectly defragged), and the p99
+    # quorum→admitted gang latency
     # from scheduler_gang_admission_duration_seconds
     topology: str = "off"
     slices_total: int | None = None
     slices_free_at_steady_state: int | None = None
     fragmentation_index: float | None = None
     gang_admission_p99_ms: float | None = None
-    # artifact paths written next to the bench JSON when tracing is on:
+    # artifact paths written next to the result JSON when tracing is on:
     # chrome trace, /metrics text, device-side cycle records
     artifacts: dict = field(default_factory=dict)
     # platform / device_kind / devices of the process that SCHEDULED — a
@@ -367,7 +364,7 @@ class WorkloadResult:
 def dump_diagnosis_artifacts(
     sched: "Scheduler", artifacts_dir: str, prefix: str
 ) -> dict[str, str]:
-    """Write the run's diagnosis artifacts next to the bench JSON: the
+    """Write the run's diagnosis artifacts next to the result JSON: the
     cycle trace as Perfetto-loadable Chrome-trace JSON, a /metrics text
     snapshot, and the device-side per-cycle counter records (joined to the
     trace spans by cycle id). Returns {artifact: path}."""
@@ -823,8 +820,7 @@ def run_workload(
     "on", or a jax.sharding.Mesh) — bit-identical assignments, N-chip
     capacity. ``flight_recorder`` toggles the scheduling flight recorder +
     per-pod staged latency attribution (``--flight-recorder off`` is the
-    overhead escape hatch; the bench's FlightRecorderOverhead line records
-    the measured on/off cost)."""
+    overhead escape hatch)."""
     if isinstance(case, str):
         case = W.TEST_CASES[case]
     if isinstance(workload, str):
@@ -1341,7 +1337,7 @@ def run_workload_trace(
     clock (2.0 = replay twice as fast). ``wall_budget_s``: hard stage wall
     — when exceeded the replay stops firing, the settle is skipped, and
     the record is emitted TRUNCATED but parseable (a hung 100k-node rung
-    must never eat the whole bench wall). ``scoped_invalidation=False``
+    must never run on unbounded). ``scoped_invalidation=False``
     pins the encode cache's pre-PR-14 full-epoch flush (the A/B control
     the node-wave evidence is measured against).
 
@@ -1799,7 +1795,7 @@ class _WatchFanout:
                         except Exception:
                             time.sleep(0.05)
             except Exception:
-                pass    # a dead extra watcher must not kill the bench
+                pass    # a dead extra watcher must not kill the run
 
         for _ in range(n):
             t = threading.Thread(target=loop, daemon=True)
@@ -1819,8 +1815,7 @@ def _sentinel_settle(sentinel, spike: "dict | None",
     fire→resolve acceptance — the rule windows slide past the spike and
     the clean streak closes the lifecycle), then fold the evidence into
     the record. ``spike`` carries the injected stall's wall window; with
-    it the report adds the fire-latency / bundle-coverage verdicts the
-    SentinelSpike bench stage asserts."""
+    it the report adds the fire-latency / bundle-coverage verdicts."""
     import time as _time
 
     deadline = _time.monotonic() + resolve_timeout_s
@@ -1925,14 +1920,13 @@ def run_workload_full_stack(
     cluster fan-out load the serialize-once body ring exists for).
     ``telemetry`` runs the FULL telemetry plane alongside the workload —
     a real HTTP collector, traceparent stamped on every RPC, both
-    processes' exporters on their 1 s cadence — so the
-    TelemetryOverhead_* comparison measures the whole tax, not a
+    processes' exporters on their 1 s cadence — the whole tax, not a
     cut-down one; the result carries the collector's span totals and
     drop counter.
     ``sentinel`` rides the anomaly sentinel (telemetry.sentinel) on the
-    scheduler's cycle boundary with bench-scaled rule windows
-    (rules.fast_rules) — the SentinelOverhead_* pair's "on" half; the
-    result carries its lifecycle stats (``clean`` = nothing fired).
+    scheduler's cycle boundary with run-scaled rule windows
+    (rules.fast_rules); the result carries its lifecycle stats
+    (``clean`` = nothing fired).
     ``sentinel_spike`` additionally injects a one-shot scheduling stall
     mid-measured-phase and reports the fire→bundle→resolve verdict
     (the acceptance scenario — NOT a judged throughput row)."""
@@ -2007,8 +2001,8 @@ def run_workload_full_stack(
         from ..telemetry.rules import fast_rules
         from ..telemetry.sentinel import Sentinel as _Sentinel
 
-        # bench-scaled windows (seconds, not minutes) so the lifecycle
-        # completes inside a bench stage; the declared budget only
+        # run-scaled windows (seconds, not minutes) so the lifecycle
+        # completes inside one run; the declared budget only
         # exists in spike mode — a clean run keeps the admission burn
         # rule dormant and judges the budget-less rules (outlier,
         # cache-collapse) for false positives instead
@@ -2258,298 +2252,6 @@ def run_workload_full_stack(
     )
 
 
-def run_workload_federated(
-    case: W.TestCase | str,
-    workload: W.Workload | str,
-    replicas: int = 2,
-    partition: str = "race",
-    profile: C.Profile | None = None,
-    max_batch: int = 1024,
-    timeout_s: float = 1800.0,
-    engine: str = "greedy",
-    stall_s: float = 15.0,
-    warmup: bool = True,
-    bulk: bool = True,
-    flight_recorder: bool = True,
-    partitions: int | None = None,
-    kill_replica_at: float | None = None,
-) -> WorkloadResult:
-    """The fullstack measurement under ACTIVE-ACTIVE FEDERATION: N full
-    scheduler replicas (each with its own RemoteStore connection, informer
-    bundle and dispatcher) race one in-process REST apiserver, each on its
-    own loop thread — the ``--replicas N --partition hash|race|lease``
-    deployment mode (sched.federation). ``replicas=1`` is the scaling
-    ladder's baseline (one scheduler through the identical harness).
-
-    ``kill_replica_at`` (0..1): when that fraction of the measured pods
-    has bound, the highest-index replica is killed mid-bench; the
-    measurement then ALSO reports ``recovery_s`` — kill → every remaining
-    pod bound by the survivors (the dead replica's partition re-absorbed).
-
-    Reported federation evidence: ``conflicts`` / ``conflict_rate``
-    (CAS-bind 409 losses + fenced stale-owner binds over all bind
-    attempts), ``binding_parity`` (store-verified count of measured pods
-    bound exactly once — the CAS store makes twice impossible, so parity
-    == measure_pods means none lost either), and ``lease_transitions``.
-    Supports the createNodes/createNamespaces/createPods/barrier op set
-    (SchedulingBasic's shape); richer ops raise."""
-    import threading as _threading
-
-    from ..apiserver import APIServer, RemoteStore
-    from ..client import StoreClient
-    from ..client.informers import NAMESPACES, NODES, PODS
-    from ..sched.federation import SchedulerFederation
-
-    if isinstance(case, str):
-        case = W.TEST_CASES[case]
-    if isinstance(workload, str):
-        workload = next(w for w in case.workloads if w.name == workload)
-    params = dict(workload.params)
-    supported = (
-        W.CreateNodesOp, W.CreateNamespacesOp, W.CreatePodsOp, W.BarrierOp,
-    )
-    for op in case.ops:
-        if not isinstance(op, supported):
-            raise NotImplementedError(
-                f"federated mode does not drive {type(op).__name__}"
-            )
-
-    srv = APIServer().start()
-    admin = RemoteStore(srv.url)
-
-    # one bound-count board shared by every replica's client: the monitor
-    # thread reads it, dispatcher worker threads of N replicas write it
-    board_lock = _threading.Lock()
-    bound_by_ns: dict[str, int] = {}
-
-    class _BoardClient(StoreClient):
-        def bind(self, pod, node_name) -> None:
-            super().bind(pod, node_name)
-            with board_lock:
-                bound_by_ns[pod.namespace] = (
-                    bound_by_ns.get(pod.namespace, 0) + 1
-                )
-
-        def bulk_bind(self, pairs) -> list:
-            errs = super().bulk_bind(pairs)
-            with board_lock:
-                for (pod, _node), err in zip(pairs, errs):
-                    if err is None:
-                        bound_by_ns[pod.namespace] = (
-                            bound_by_ns.get(pod.namespace, 0) + 1
-                        )
-            return errs
-
-    fed = SchedulerFederation(
-        lambda i: RemoteStore(srv.url),
-        replicas=replicas,
-        partition=partition,
-        partitions=partitions,
-        scheduler_kwargs=dict(
-            profile=profile or C.Profile(), max_batch=max_batch,
-            engine=engine, bulk=bulk, flight_recorder=flight_recorder,
-            feature_gates=(
-                dict(case.feature_gates) if case.feature_gates else None
-            ),
-        ),
-        client_factory=lambda s: _BoardClient(s),
-        informer_bulk=bulk,
-    )
-
-    def bound_now(namespaces: tuple[str, ...]) -> int:
-        with board_lock:
-            return sum(bound_by_ns.get(ns, 0) for ns in namespaces)
-
-    measured = 0
-    duration = 0.0
-    requests0 = 0
-    rpcs_total = 0
-    attempts0 = cycles0 = 0
-    recovery_s: float | None = None
-    killed = False
-    parity: int | None = None
-    measure_namespaces: tuple[str, ...] = ()
-    op_ns_counter = 0
-    stop = _threading.Event()
-    threads: list = []
-
-    def settle(
-        target: int, namespaces: tuple[str, ...], allow_kill: bool = False,
-    ) -> tuple[int, float]:
-        """Monitor the shared board until ``target`` pods of
-        ``namespaces`` bound (the replica threads do the work), firing the
-        mid-bench kill when requested. The kill arms ONLY in the measured
-        phase (``allow_kill``) — an init-phase settle must not consume it,
-        or recovery would measure the init tail and the whole measured
-        phase would run a replica short."""
-        nonlocal recovery_s, killed
-        start = bound_now(namespaces)
-        t0 = time.perf_counter()
-        deadline = t0 + timeout_s
-        last_progress = t0
-        done = 0
-        t_kill = None
-        kill_at = (
-            int(kill_replica_at * target)
-            if (kill_replica_at is not None and allow_kill) else None
-        )
-        while done < target:
-            now = time.perf_counter()
-            if now > deadline:
-                break
-            before = done
-            done = bound_now(namespaces) - start
-            if (
-                kill_at is not None and not killed and done >= kill_at
-                and len(fed.live()) > 1
-            ):
-                idx = fed.live()[-1].index
-                fed.kill(idx, close=False)
-                killed = True
-                t_kill = now
-            if done > before:
-                last_progress = now
-            elif now - last_progress > stall_s:
-                break
-            else:
-                time.sleep(0.005)
-        t_end = time.perf_counter()
-        if t_kill is not None and done >= target:
-            recovery_s = t_end - t_kill
-        return done, t_end - t0
-
-    try:
-        for op_i, op in enumerate(case.ops):
-            if isinstance(op, W.CreateNodesOp):
-                n = op.count or params[op.count_param]
-                factory = op.template or W.node_default
-                nodes = [factory(i, op.zones) for i in range(n)]
-                _bulk_create(
-                    admin, NODES, [(nd.name, nd) for nd in nodes], bulk=bulk,
-                )
-            elif isinstance(op, W.CreateNamespacesOp):
-                n = params[op.count_param] if op.count_param else op.count
-                _bulk_create(admin, NAMESPACES, [
-                    (f"{op.prefix}-{i}", t.Namespace(
-                        name=f"{op.prefix}-{i}", labels=op.labels,
-                    ))
-                    for i in range(n)
-                ], bulk=bulk)
-            elif isinstance(op, W.BarrierOp):
-                continue   # phases already settle to completion below
-            elif isinstance(op, W.CreatePodsOp):
-                count = params[op.count_param]
-                template = op.template or case.default_pod_template
-                ns = op.namespace or f"namespace-{op_ns_counter}"
-                op_ns_counter += 1
-                prefix = (
-                    f"{'measure' if op.collect_metrics else 'init'}-{op_i}"
-                )
-                if not threads:
-                    # first pod op: sync + (optionally) compile every
-                    # replica BEFORE its loop thread exists — warmup and
-                    # the loop must share the single-owner thread
-                    fed.start()
-                    for h in fed.live():
-                        h.informers.pump()
-                        if warmup:
-                            h.sched.warmup([
-                                template(f"warmup-{op_i}-{j}", ns)
-                                for j in range(
-                                    min(count, h.sched.max_batch)
-                                )
-                            ])
-                    threads = fed.run_threads(stop)
-                if op.collect_metrics:
-                    # accumulate: a case may carry several measured ops,
-                    # and parity must count every measured namespace
-                    measure_namespaces = measure_namespaces + (ns,)
-                    attempts0 = sum(
-                        h.sched.metrics.schedule_attempts
-                        for h in fed.handles
-                    )
-                    cycles0 = sum(
-                        h.sched.metrics.cycles for h in fed.handles
-                    )
-                    requests0 = srv.metrics.total_requests()
-                items = []
-                for j in range(count):
-                    pod = template(f"{prefix}-{ns}-{j}", ns)
-                    items.append((f"{ns}/{pod.name}", pod))
-                _bulk_create(admin, PODS, items, bulk=bulk)
-                if op.skip_wait:
-                    continue
-                done, secs = settle(
-                    count, (ns,), allow_kill=op.collect_metrics,
-                )
-                if op.collect_metrics:
-                    measured += done
-                    duration += secs
-                    rpcs_total += srv.metrics.total_requests() - requests0
-        # store-verified binding parity: every measured pod bound exactly
-        # once (the CAS bind makes twice impossible; parity ==
-        # measure_pods means none were lost to a dead replica or a
-        # conflict loop either). Inside the try: the server must still be
-        # up, and a failed parity read should surface, not mask.
-        stop.set()
-        for th in threads:
-            th.join(timeout=10)
-        if measure_namespaces:
-            items, _rv = admin.list(PODS)
-            parity = sum(
-                1 for key, pod in items
-                if pod.node_name
-                and key.split("/", 1)[0] in measure_namespaces
-            )
-    finally:
-        # teardown runs on EVERY path — an exception mid-ladder must not
-        # leak the apiserver thread/socket into the rest of the bench
-        stop.set()
-        for th in threads:
-            th.join(timeout=10)
-        for h in fed.handles:
-            if not h.alive:
-                fed.close_replica(h.index)
-        fed.close()
-        srv.close()
-
-    throughput = measured / duration if duration > 0 else 0.0
-    return WorkloadResult(
-        case_name=case.name,
-        workload_name=(
-            f"{workload.name}_fullstack_{replicas}sched_{partition}"
-        ),
-        threshold=workload.threshold,
-        threshold_note=workload.threshold_note,
-        measure_pods=sum(
-            params[op.count_param]
-            for op in case.ops
-            if isinstance(op, W.CreatePodsOp) and op.collect_metrics
-        ),
-        scheduled=measured,
-        duration_s=duration,
-        throughput=throughput,
-        vs_threshold=(
-            throughput / workload.threshold if workload.threshold else None
-        ),
-        attempts=sum(
-            h.sched.metrics.schedule_attempts for h in fed.handles
-        ) - attempts0,
-        cycles=sum(h.sched.metrics.cycles for h in fed.handles) - cycles0,
-        rpcs_per_scheduled_pod=(
-            rpcs_total / measured if measured else None
-        ),
-        flight_recorder=flight_recorder,
-        replicas=replicas,
-        partition=partition,
-        conflicts=fed.conflicts(),
-        conflict_rate=fed.conflict_rate(),
-        binding_parity=parity,
-        lease_transitions=fed.lease_transitions(),
-        recovery_s=recovery_s,
-    )
-
-
 def _children_device(cluster) -> dict:
     """The device stamp the scheduler children published on their
     readiness banners: the measuring parent of a multi-process run holds
@@ -2605,7 +2307,7 @@ class ParityError(AssertionError):
     """The store-verified exactly-once binding check failed: a measured
     pod is unbound (lost to a dead replica / conflict loop) after the run
     claimed completion. Raised — never just a field — so a lossy mp run
-    FAILS its bench stage and benchdiff treats it as a regression."""
+    FAILS instead of reporting a number."""
 
 
 def run_workload_multiprocess(
@@ -2955,20 +2657,19 @@ def run_list_scaling(
     wire: str = "binary",
     wall_budget_s: float = 120.0,
 ) -> dict:
-    """The read plane's LIST-at-scale evidence (the ``ListScaling_*``
-    bench rungs): one apiserver over a store pre-loaded with ``n_nodes``
-    nodes, then ``relists`` full paged walks through a RemoteStore — the
-    exact informer-relist path (limit/continue pages pinned to one
-    snapshot rv, per-page retry budget, serialize-once item bytes).
+    """The read plane's LIST-at-scale evidence: one apiserver over a store
+    pre-loaded with ``n_nodes`` nodes, then ``relists`` full paged walks
+    through a RemoteStore — the exact informer-relist path (limit/continue
+    pages pinned to one snapshot rv, per-page retry budget, serialize-once
+    item bytes).
 
-    Reports the per-relist wall p50/p99 (``list_p99_ms`` is what
-    benchdiff gates), the wire bytes and page count per relist off the
-    client's relist accounting, the max single page ever shipped, and
-    one unpaged-GET wall for the before/after context. Every walk is
+    Reports the per-relist wall p50/p99, the wire bytes and page count
+    per relist off the client's relist accounting, the max single page ever
+    shipped, and one unpaged-GET wall for the before/after context. Every walk is
     parity-checked against the node count — a paged walk that dropped or
     duplicated a key raises (a correctness failure must fail the stage,
     never land as a slow-but-green number). ``wall_budget_s`` caps the
-    stage: a rung that can't finish its relists returns a TRUNCATED but
+    run: one that can't finish its relists returns a TRUNCATED but
     parseable record carrying the walks it did complete."""
     from ..apiserver import APIServer, RemoteStore
     from ..client.informers import NODES
@@ -3305,387 +3006,6 @@ def run_trace_multiprocess(
     )
 
 
-def run_crash_recovery(
-    n_nodes: int = 5000,
-    n_pods: int = 50000,
-    watchers: int = 200,
-    bind_frac: float = 0.5,
-    wal_fsync: bool = True,
-    wal_wire: str = "binary",
-    dirpath: str | None = None,
-) -> dict:
-    """The durable-store recovery bench (ROADMAP item 2's scenario): build
-    a 5k-node / 50k-pod cluster in a WAL-backed store (bulk writes — the
-    group-commit path), bind ``bind_frac`` of the pods, then CRASH the
-    process (the store is abandoned un-closed, exactly what a kill leaves
-    behind) and measure:
-
-    - ``recovery_s``: wall time for a fresh store to replay snapshot+tail
-      with resourceVersion continuity;
-    - ``relist_storm_s``: ``watchers`` reconnecting watchers each taking a
-      BOUNDED relist from a pre-crash cursor (the tail events only, off
-      the repopulated ring) — plus the 410 full-relist cost one
-      compacted-cursor watcher pays, for contrast;
-    - ``binding_parity``: store-verified pods bound EXACTLY once after
-      recovery (must equal the pre-crash bind count — the exactly-once
-      check the federation bench also asserts)."""
-    import shutil
-    import tempfile
-
-    from ..api.wrappers import make_node, make_pod
-    from ..client.informers import NODES, PODS
-    from ..store.memstore import MemStore
-
-    own_dir = dirpath is None
-    dirpath = dirpath or tempfile.mkdtemp(prefix="kubetpu-wal-bench-")
-    try:
-        st = MemStore(persistence=dirpath, wal_fsync=wal_fsync,
-                      wal_wire=wal_wire)
-        t_pop0 = time.perf_counter()
-        chunk = 512
-        for i in range(0, n_nodes, chunk):
-            st.bulk(NODES, [
-                {"op": "create", "key": f"node-{j}",
-                 "object": make_node(f"node-{j}")}
-                for j in range(i, min(i + chunk, n_nodes))
-            ])
-        for i in range(0, n_pods, chunk):
-            st.bulk(PODS, [
-                {"op": "create", "key": f"bench/pod-{j}",
-                 "object": make_pod(f"pod-{j}", namespace="bench")}
-                for j in range(i, min(i + chunk, n_pods))
-            ])
-        n_bound = int(n_pods * bind_frac)
-        for i in range(0, n_bound, chunk):
-            keys = [f"bench/pod-{j}" for j in range(i, min(i + chunk, n_bound))]
-            gets = st.bulk(PODS, [{"op": "get", "key": k} for k in keys])
-            st.bulk(PODS, [
-                {"op": "update", "key": k,
-                 "object": g["object"].with_node(f"node-{j % n_nodes}"),
-                 "expect_rv": g["resourceVersion"]}
-                for j, (k, g) in enumerate(zip(keys, gets))
-            ])
-        populate_s = time.perf_counter() - t_pop0
-        pre_rv = st.resource_version
-        wal_stats = st.wal_stats()
-        # CRASH: abandon the store un-closed — in-memory state dies, the
-        # flushed log is what a killed process leaves on disk
-        del st
-
-        t0 = time.perf_counter()
-        st2 = MemStore(persistence=dirpath, wal_fsync=wal_fsync,
-                       wal_wire=wal_wire)
-        recovery_s = time.perf_counter() - t0
-        info = st2.recovery_info
-        assert st2.resource_version == pre_rv, (
-            f"rv continuity broken: {st2.resource_version} != {pre_rv}"
-        )
-        # exactly-once binding parity, store-verified (keys are unique by
-        # construction — the CAS store makes bound-twice impossible, so
-        # parity == the pre-crash bind count means none lost either)
-        parity = sum(
-            1 for _k, pod in st2.list(PODS)[0] if pod.node_name
-        )
-        # hard gate, like the rv assert above: a recovery that loses
-        # bindings must FAIL the stage (benchdiff treats an errored
-        # metric as a regression), never emit a green line with
-        # parity_ok=false that nothing gates on
-        assert parity == n_bound, (
-            f"binding parity broken after recovery: {parity} != {n_bound}"
-        )
-        # the relist storm: every reconnecting watcher resumes from a
-        # pre-crash cursor inside the replayed tail — a BOUNDED relist
-        cursor = max(info.snapshot_rv, pre_rv - 1000)
-        t1 = time.perf_counter()
-        delivered = 0
-        for _ in range(watchers):
-            events, _cur = st2._events_since(PODS, cursor)
-            delivered += len(events)
-        relist_storm_s = time.perf_counter() - t1
-        # contrast: what ONE watcher whose cursor predates the compaction
-        # horizon pays after its 410 — a full list of the bucket
-        t2 = time.perf_counter()
-        full_items, _rv = st2.list(PODS)
-        full_relist_s = time.perf_counter() - t2
-        st2.close()
-        return {
-            "n_nodes": n_nodes,
-            "n_pods": n_pods,
-            "bound": n_bound,
-            "binding_parity": parity,
-            "parity_ok": parity == n_bound,
-            "rv": pre_rv,
-            "populate_s": round(populate_s, 3),
-            "recovery_s": round(recovery_s, 3),
-            "recovered_writes_per_s": round(
-                (info.snapshot_objects + info.replayed) / recovery_s, 1
-            ) if recovery_s > 0 else None,
-            "snapshot_rv": info.snapshot_rv,
-            "snapshot_objects": info.snapshot_objects,
-            "replayed": info.replayed,
-            "truncated_bytes": info.truncated_bytes,
-            "watchers": watchers,
-            "relist_storm_s": round(relist_storm_s, 4),
-            "relist_events_delivered": delivered,
-            "full_relist_objects": len(full_items),
-            "full_relist_s": round(full_relist_s, 4),
-            "wal_fsync": wal_fsync,
-            "wal_wire": wal_wire,
-            "wal_records": (wal_stats or {}).get("records_appended"),
-            "wal_bytes": (wal_stats or {}).get("bytes_appended"),
-            "wal_fsyncs": (wal_stats or {}).get("fsyncs"),
-        }
-    finally:
-        if own_dir:
-            shutil.rmtree(dirpath, ignore_errors=True)
-
-
-def run_replicated_failover(
-    n_nodes: int = 5000,
-    n_pods: int = 50000,
-    apiservers: int = 3,
-    bind_frac: float = 0.5,
-    wire: str = "binary",
-    lease_duration_s: float = 0.5,
-    timeout_s: float = 300.0,
-    serve_timeout_s: float = 60.0,
-    child_env: dict | None = None,
-) -> dict:
-    """The replicated read plane's failover-by-log-position bench — the
-    hot-standby answer to ``run_crash_recovery``'s cold restart, on the
-    SAME 5k-node / 50k-pod durability shape but with every process REAL
-    (1 leader + N-1 follower apiservers under the launch supervisor):
-
-    - drive the write storm (bulk creates + CAS binds of ``bind_frac`` of
-      the pods) through the leader over HTTP while a sampler thread reads
-      each follower's ``/replication/status`` — the PEAK ``lagMs`` /
-      ``lagRecords`` under the storm is ``follower_lag_ms`` /
-      ``follower_lag_records`` (the read plane's honesty counter);
-    - wait for every follower to catch the leader's rv, then SIGKILL the
-      leader (restart policy "never" — nobody respawns it);
-    - ``failover_to_serving_s``: kill → a follower won the writer lease
-      by log position AND serves a successful full list AND accepts a
-      probe write. This is the number the cold ``recovery_s`` wall is
-      judged against — a hot standby that already holds the state must
-      beat a process that replays the WAL from disk;
-    - binding parity, store-verified on the NEW leader: every CAS-bound
-      pod bound exactly once across the failover (a miss raises — the
-      stage fails, never a green line nothing gates on).
-
-    The lease is tuned short (``lease_duration_s``) so the measurement is
-    the protocol — position probe, epoch-fenced CAS — not a lazy lease
-    expiry."""
-    import os as _os
-    import threading as _threading
-
-    import kubetpu as _pkg
-
-    from ..api.wrappers import make_node, make_pod
-    from ..apiserver import RemoteStore
-    from ..client.informers import NODES, PODS
-    from ..launch import Cluster
-    from ..store.memstore import bulk_result_error
-
-    if apiservers < 2:
-        raise ValueError("failover needs at least one follower apiserver")
-    repo_root = _os.path.dirname(_os.path.dirname(_os.path.abspath(
-        _pkg.__file__
-    )))
-    cluster = Cluster(
-        replicas=0, apiservers=apiservers, wire=wire,
-        lease_duration_s=lease_duration_s, env=child_env, cwd=repo_root,
-    )
-    lag_peak = {"ms": 0.0, "records": 0}
-    samples = [0]
-    stop = _threading.Event()
-
-    def _checked_bulk(admin, kind, ops):
-        for res in admin.bulk(kind, ops):
-            err = bulk_result_error(res)
-            if err is not None:
-                raise err
-
-    cluster.start()
-    try:
-        leader_url = cluster.api_url
-        follower_urls = list(cluster.api_urls[1:])
-
-        def _sampler() -> None:
-            while not stop.wait(0.3):
-                for u in follower_urls:
-                    st = _replication_status(u)
-                    if not st:
-                        continue
-                    samples[0] += 1
-                    lag_peak["ms"] = max(
-                        lag_peak["ms"], float(st.get("lagMs") or 0.0)
-                    )
-                    lag_peak["records"] = max(
-                        lag_peak["records"],
-                        int(st.get("lagRecords") or 0),
-                    )
-
-        sampler = _threading.Thread(target=_sampler, daemon=True)
-        sampler.start()
-        admin = RemoteStore(leader_url, wire=wire)
-        # ---- the write storm: the durability shape, through the leader
-        chunk = 512
-        t_pop0 = time.perf_counter()
-        for i in range(0, n_nodes, chunk):
-            _checked_bulk(admin, NODES, [
-                {"op": "create", "key": f"node-{j}",
-                 "object": make_node(f"node-{j}")}
-                for j in range(i, min(i + chunk, n_nodes))
-            ])
-        for i in range(0, n_pods, chunk):
-            _checked_bulk(admin, PODS, [
-                {"op": "create", "key": f"bench/pod-{j}",
-                 "object": make_pod(f"pod-{j}", namespace="bench")}
-                for j in range(i, min(i + chunk, n_pods))
-            ])
-        n_bound = int(n_pods * bind_frac)
-        for i in range(0, n_bound, chunk):
-            keys = [
-                f"bench/pod-{j}" for j in range(i, min(i + chunk, n_bound))
-            ]
-            gets = admin.bulk(PODS, [{"op": "get", "key": k} for k in keys])
-            _checked_bulk(admin, PODS, [
-                {"op": "update", "key": k,
-                 "object": g["object"].with_node(
-                     f"node-{int(k.rsplit('-', 1)[1]) % n_nodes}"
-                 ),
-                 "expect_rv": g["resourceVersion"]}
-                for k, g in zip(keys, gets)
-            ])
-        populate_s = time.perf_counter() - t_pop0
-        pre_rv = int(
-            (_replication_status(leader_url) or {}).get("resourceVersion")
-            or 0
-        )
-        if pre_rv <= 0:
-            raise RuntimeError("leader /replication/status unreadable")
-        # ---- every follower caught up: the failover measures the
-        # protocol, not residual shipping
-        t_catch0 = time.perf_counter()
-        deadline = t_catch0 + timeout_s
-        while True:
-            rvs = [
-                int((_replication_status(u) or {}).get("resourceVersion")
-                    or 0)
-                for u in follower_urls
-            ]
-            if all(rv >= pre_rv for rv in rvs):
-                break
-            if time.perf_counter() > deadline:
-                raise RuntimeError(
-                    f"followers never caught rv {pre_rv}: {rvs}"
-                )
-            time.sleep(0.05)
-        catch_up_s = time.perf_counter() - t_catch0
-        stop.set()
-        sampler.join(timeout=5)
-        # the bound set, read from the READ plane (a follower), pre-kill
-        items, _rv = RemoteStore(follower_urls[0], wire=wire).list(PODS)
-        pre_bound = sum(1 for _k, pod in items if pod.node_name)
-        assert pre_bound == n_bound, (
-            f"follower read plane lost binds pre-kill: "
-            f"{pre_bound} != {n_bound}"
-        )
-        # ---- SIGKILL the leader; measure kill -> a follower SERVES
-        cluster.supervisor.kill("apiserver")
-        t0 = time.perf_counter()
-        serve_deadline = t0 + serve_timeout_s
-        new_leader = None
-        while time.perf_counter() < serve_deadline and new_leader is None:
-            for u in follower_urls:
-                st = _replication_status(u)
-                if st and st.get("role") == "leader":
-                    new_leader = u
-                    break
-            if new_leader is None:
-                time.sleep(0.02)
-        if new_leader is None:
-            raise RuntimeError(
-                f"no follower promoted within {serve_timeout_s}s"
-            )
-        elected_s = time.perf_counter() - t0
-        admin2 = RemoteStore(new_leader, wire=wire)
-        post_bound = -1
-        post_rv = 0
-        while time.perf_counter() < serve_deadline:
-            try:
-                items2, post_rv = admin2.list(PODS)
-                post_bound = sum(
-                    1 for _k, pod in items2 if pod.node_name
-                )
-                break
-            except Exception:
-                time.sleep(0.02)
-        probe_ok = False
-        attempt = 0
-        while time.perf_counter() < serve_deadline and not probe_ok:
-            try:
-                admin2.create(
-                    PODS, f"failover/probe-{attempt}",
-                    make_pod(f"probe-{attempt}", namespace="failover"),
-                )
-                probe_ok = True
-            except Exception:
-                attempt += 1
-                time.sleep(0.02)
-        failover_to_serving_s = time.perf_counter() - t0
-        if not probe_ok:
-            raise RuntimeError(
-                f"new leader {new_leader} never accepted the probe write "
-                f"within {serve_timeout_s}s"
-            )
-        # hard gates, run_crash_recovery-style: a failover that lost
-        # bindings or rv continuity FAILS the stage
-        assert post_bound == n_bound, (
-            f"binding parity broken across failover: "
-            f"{post_bound} != {n_bound}"
-        )
-        assert post_rv >= pre_rv, (
-            f"rv continuity broken across failover: "
-            f"{post_rv} < {pre_rv}"
-        )
-        # the epoch fence lands at the lease CAS, which completes just
-        # after the role flip that let the probe through — wait briefly
-        # so the record carries the fenced epoch, without gating the
-        # serving wall on it
-        new_st = _replication_status(new_leader) or {}
-        fence_deadline = time.perf_counter() + 5.0
-        while (
-            not new_st.get("promotions")
-            and time.perf_counter() < fence_deadline
-        ):
-            time.sleep(0.05)
-            new_st = _replication_status(new_leader) or new_st
-        return {
-            "n_nodes": n_nodes,
-            "n_pods": n_pods,
-            "apiservers": apiservers,
-            "bound": n_bound,
-            "binding_parity": post_bound,
-            "parity_ok": post_bound == n_bound,
-            "rv": pre_rv,
-            "new_leader_rv": post_rv,
-            "populate_s": round(populate_s, 3),
-            "catch_up_s": round(catch_up_s, 3),
-            "elected_s": round(elected_s, 3),
-            "failover_to_serving_s": round(failover_to_serving_s, 3),
-            "follower_lag_ms": round(lag_peak["ms"], 3),
-            "follower_lag_records": lag_peak["records"],
-            "lag_samples": samples[0],
-            "lease_duration_s": lease_duration_s,
-            "epoch": new_st.get("epoch"),
-            "promotions": new_st.get("promotions"),
-        }
-    finally:
-        stop.set()
-        cluster.shutdown()
-
-
 def run_wal_overhead(
     n_writes: int = 20000,
     chunk: int = 256,
@@ -3694,8 +3014,7 @@ def run_wal_overhead(
 ) -> dict:
     """Steady-state WAL cost: the SAME bulk create+bind write sequence
     against a persistent store and a memory-only one; the throughput
-    ratio (and ``wal_overhead_frac``) is the price of durability —
-    benchdiff-gated so a WAL hot-path regression trips CI."""
+    ratio (and ``wal_overhead_frac``) is the price of durability."""
     import shutil
     import tempfile
 

@@ -1274,7 +1274,7 @@ def train_serve_churn_trace(
 class TraceProfile:
     """A named trace shape: generator + params + initial cluster size +
     the admission SLO budget its record is judged against. ``events()`` is
-    the deterministic op sequence; ``scaled()`` derives bench rungs (the
+    the deterministic op sequence; ``scaled()`` derives larger rungs (the
     50k/100k ladder) without re-declaring the shape. ``slices > 0`` stamps
     every node (initial fleet AND wave nodes — one grammar,
     trace_topology_labels) with rack/TPU-slice labels so the scenario can
@@ -1402,8 +1402,8 @@ _trace(TraceProfile(
 _case(TestCase(
     name="BinPacking",
     source="PR 19: utilization-vs-throughput frontier workload (no "
-           "reference config — skewed sizes + priority tiers built for "
-           "the PackingComparison three-engine ladder)",
+           "reference config — skewed sizes + priority tiers built to "
+           "compare the three engines)",
     default_pod_template=pod_binpack,
     ops=(
         CreateNodesOp("initNodes"),
@@ -1411,9 +1411,9 @@ _case(TestCase(
         CreatePodsOp("measurePods", collect_metrics=True),
     ),
     workloads=(
-        # no pods/s threshold: the workload's verdict is the benchdiff
-        # frontier — nodes_used_at_steady_state and priority_slo_hit_rate
-        # against the greedy baseline, not a reference throughput floor
+        # no pods/s threshold: the workload's verdict is
+        # nodes_used_at_steady_state and priority_slo_hit_rate against the
+        # greedy baseline, not a reference throughput floor
         Workload("200Nodes",
                  {"initNodes": 200, "initPods": 50, "measurePods": 300}),
         Workload("1000Nodes_3000Pods",

@@ -47,9 +47,7 @@ first pod, the "as-popped view" for later ones — flagged
 ``view: "cycle-start"`` on every record); the ACTUAL assignment recorded is
 always the scan's. The extra kernel is a single parallel (P,N) evaluation —
 a fraction of the P-step sequential scan — and the whole recorder sits
-behind ``Scheduler(flight_recorder=False)`` / ``--flight-recorder off``,
-with the measured on/off cost recorded by the bench's
-``FlightRecorderOverhead`` line (<5% fullstack budget).
+behind ``Scheduler(flight_recorder=False)`` / ``--flight-recorder off``.
 """
 
 from __future__ import annotations

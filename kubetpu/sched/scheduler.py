@@ -1027,7 +1027,6 @@ class Scheduler:
                 if self.sentinel is not None:
                     # the sentinel rides the cycle boundary: at most one
                     # rule evaluation per interval, on the owner's thread
-                    # (the SentinelOverhead bench pair prices exactly this)
                     self.sentinel.maybe_evaluate()
 
     def _schedule_batch_inner(
@@ -1873,7 +1872,7 @@ class Scheduler:
                 if stages:
                     # the per-pod staged latency vector lands in the
                     # {stage} histograms at bind ack — the staged p50/p99
-                    # every fullstack bench record carries
+                    # every fullstack perf result carries
                     children = self._stage_children
                     for stage, seconds in stages.items():
                         child = children.get(stage)

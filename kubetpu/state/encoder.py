@@ -71,9 +71,8 @@ def shard_aligned(n: int, multiple: int) -> int:
     """Round a padded node capacity up to a per-shard bucket boundary: a
     mesh of ``multiple`` shards needs capacity % multiple == 0 or the
     sharded resident block degrades to replication. ONE place computes
-    this (runtime.encode_batch_static and the bench's capacity planner
-    both call it), so a mesh's bucket padding can never disagree with the
-    encoder's — at 100k nodes a mismatched bucket re-pads ~100 MB of
+    this (runtime.encode_batch_static calls it), so a mesh's bucket padding
+    can never disagree with the encoder's — at 100k nodes a mismatched bucket re-pads ~100 MB of
     node-axis tensors per cycle."""
     if multiple <= 1:
         return n

@@ -1094,7 +1094,8 @@ class MemStore:
                 self._wal_lock = None
 
     def wal_stats(self) -> "dict | None":
-        """Append-side counters for metrics/bench (None when off)."""
+        """Append-side counters for metrics and run_wal_overhead (None when
+        off)."""
         import math
 
         with self._lock:
@@ -1108,8 +1109,8 @@ class MemStore:
                 "bytes_appended": wal.bytes_appended,
                 "fsyncs": wal.fsyncs,
                 "records_since_snapshot": wal.records_since_snapshot,
-                # the WALOverhead_* bench records embed this: the p99
-                # group-commit fsync in ms (None before the first fsync).
+                # the p99 group-commit fsync in ms (None before the first
+                # fsync).
                 # p50 rides along as the sentinel bundle's WAL stat feed —
                 # a stall diagnosis needs the baseline next to the tail
                 "fsync_p50_ms": (

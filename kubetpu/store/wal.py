@@ -198,7 +198,7 @@ class DirLock:
 
 @dataclass
 class RecoveryInfo:
-    """What one recovery did — surfaced by fsck and the recovery bench."""
+    """What one recovery did — surfaced by fsck."""
 
     snapshot_rv: int = 0
     snapshot_objects: int = 0
@@ -240,7 +240,7 @@ class WriteAheadLog:
         self._seg_fp: str | None = None     # fingerprint the segment pinned
         self._dirty = False                 # appended-but-not-fsynced bytes
         self._last_rv = base_rv             # highest rv this log has seen
-        # counters for /metrics + the WALOverhead bench line
+        # counters for /metrics and MemStore.wal_stats
         self.records_appended = 0
         self.bytes_appended = 0
         self.fsyncs = 0
@@ -248,8 +248,7 @@ class WriteAheadLog:
         # store_wal_fsync_duration_seconds: the durability tax per group
         # commit (10 µs … ~1.3 s — a battery-backed controller acks in
         # tens of µs, a contended spindle can take hundreds of ms); the
-        # apiserver mounts it on /metrics and WALOverhead_* bench records
-        # embed its p99
+        # apiserver mounts it on /metrics and wal_stats reports its p99
         self.fsync_hist = Histogram(
             "store_wal_fsync_duration_seconds",
             "WAL group-commit fsync latency in seconds.",

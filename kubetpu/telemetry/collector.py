@@ -33,8 +33,7 @@ fingerprints match, JSON otherwise). The collector:
   per-process id, bounded per process).
 
 Ingest is bounded: per-process span rings drop oldest-first and count
-drops (``kubetpu_collector_spans_dropped_total`` — the TelemetryOverhead
-bench stage asserts it stayed zero).
+drops (``kubetpu_collector_spans_dropped_total``).
 """
 
 from __future__ import annotations
@@ -202,7 +201,7 @@ class Collector:
     def ingest(self, payload: dict) -> dict:
         """One export batch from one process. Returns {"ok", "dropped"}
         — ``dropped`` is the process's lifetime span-drop count, so an
-        exporter (and the bench gate) can see loss without a scrape."""
+        exporter can see loss without a scrape."""
         if not isinstance(payload, dict):
             raise ValueError("export payload must be a mapping")
         name = str(payload.get("process") or "")

@@ -86,7 +86,7 @@ class Rule:
 
     def scaled(self, time_scale: float) -> "Rule":
         """The same rule with every window shrunk by ``time_scale`` —
-        the bench spike stage runs real wall-clock and cannot wait five
+        a spike run is on the real wall clock and cannot wait five
         minutes for a long window to drain. Thresholds are untouched:
         only WHEN is scaled, never HOW MUCH."""
         return replace(
@@ -290,6 +290,6 @@ def default_rules() -> tuple[Rule, ...]:
 
 
 def fast_rules(time_scale: float = 0.05) -> tuple[Rule, ...]:
-    """DEFAULT_RULES with windows scaled for a real-wall-clock bench or
+    """DEFAULT_RULES with windows scaled for a real-wall-clock perf or
     integration run (0.05 → 1.5s/15s burn windows). Same thresholds."""
     return tuple(r.scaled(time_scale) for r in DEFAULT_RULES)

@@ -2,7 +2,7 @@
 observability stack.
 
 Every earlier telemetry layer is passive: spans, histograms, flight
-records and bench records exist, but a blown admission SLO or an fsync
+records and perf results exist, but a blown admission SLO or an fsync
 stall is only discovered post-hoc, after the evidence (queue state,
 cache stats, the outlier cycle's trace slice) is gone. The sentinel
 closes that loop in-process:
@@ -31,8 +31,9 @@ closes that loop in-process:
 
 Drive model: a loop-owned component (the scheduler) calls
 ``maybe_evaluate()`` at its cycle boundary — zero threads, overhead on
-the owner's clock so the bench pair can price it; a thread-served
-component (the apiserver) calls ``start()`` for a cadence thread.
+the owner's clock, where an on/off pair of runs can price it; a
+thread-served component (the apiserver) calls ``start()`` for a cadence
+thread.
 Escape hatch by construction: a component without a sentinel performs
 zero extra work.
 """
@@ -762,7 +763,7 @@ class Sentinel:
             return list(self.bundles)
 
     def stats(self) -> dict:
-        """The bench/runner view (WorkloadResult.sentinel)."""
+        """The perf runner's view (WorkloadResult.sentinel)."""
         with self._lock:
             alerts = list(self._alerts.values())
             out = {

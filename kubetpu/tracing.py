@@ -10,8 +10,6 @@ Reference surfaces:
   ids/parents/attributes and land in a bounded in-memory buffer an exporter
   can drain (``Tracer.drain``); the scheduler joins device + host work by
   cycle id, the OTel-span-per-cycle design SURVEY §5 prescribes.
-- JAX profiler: ``device_profile`` wraps ``jax.profiler.trace`` so a
-  perf investigation captures XLA device traces alongside the host spans.
 
 Single-owner like the scheduler loop: span entry/exit runs on the loop
 thread, so the parent stack is a plain list (no contextvars in the hot
@@ -401,14 +399,3 @@ class PhaseClock:
         return seconds, dict(self.entries), self.iterations
 
 
-@contextmanager
-def device_profile(log_dir: str):
-    """Capture an XLA device trace for the enclosed block (JAX profiler —
-    the TPU side of a latency investigation; view with tensorboard/xprof)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

@@ -80,39 +80,6 @@ def test_chip_smoke_refuses_without_a_tpu():
     assert reason and "cpu" in reason[0], p.stderr
 
 
-def test_bench_main_without_a_tpu_exits_nonzero(capsys):
-    import bench
-
-    assert bench.main() != 0
-    out = capsys.readouterr()
-    assert out.out == "", "nothing may run under a device metric's name"
-    assert "needs a TPU" in out.err and "cpu" in out.err
-
-
-def test_bench_failed_stage_makes_the_exit_code_say_so(monkeypatch):
-    """A stage that raises still prints its line; the run then exits 1."""
-    import bench
-
-    import kubetpu
-
-    monkeypatch.setattr(kubetpu, "device_stamp", lambda: {
-        "platform": "tpu", "device_kind": "fake", "devices": 1})
-    monkeypatch.setattr(bench, "STAGES", [(
-        "SchedulingBasic", "500Nodes", "greedy", "direct", 128,
-        False, True, False)])
-
-    def boom(*_a, **_k):
-        raise RuntimeError("stage blew up")
-
-    monkeypatch.setattr(bench, "run_stage", boom)
-    for name in dir(bench):
-        if name.startswith("_run_") and name.endswith(("_stages", "_stage")):
-            monkeypatch.setattr(bench, name, lambda: None)
-    monkeypatch.setattr(bench, "FAILED", [])
-    assert bench.main() == 1
-    assert bench.FAILED[0] == "SchedulingBasic_500Nodes_greedy"
-
-
 # ---------------------------------------------------------------------------
 # device stamps
 # ---------------------------------------------------------------------------

@@ -21,6 +21,16 @@ from .cluster_gen import random_cluster
 RESOURCES = [(t.CPU, 1), (t.MEMORY, 1)]
 
 
+def test_the_tests_oracle_is_the_benchmark_s_reference():
+    """One oracle: parity here and ``correct`` in a cell mean the same."""
+    import importlib
+
+    import benchmark.reference.oracle as reference
+
+    assert oracle is reference
+    assert importlib.import_module("tests.oracle") is reference
+
+
 def run_both(cache, pending, profile, **oracle_kwargs):
     snap = cache.update_snapshot()
     batch = encode_batch(snap, pending, profile)

@@ -529,7 +529,7 @@ def test_chained_follower_and_stale_epoch_fence():
 
 
 def test_run_list_scaling_smoke():
-    """The ListScaling bench runner at toy scale: multiple pages per
+    """run_list_scaling at toy scale: multiple pages per
     relist, the client relist accounting populated, every walk
     parity-checked, the unpaged baseline recorded."""
     from kubetpu.perf.runner import run_list_scaling

@@ -208,3 +208,69 @@ def test_extended_resource_workload():
     r = run_workload("SchedulingWithExtendedResource", "fast", timeout_s=60,
                      warmup=False)
     assert r.scheduled == 10
+
+
+# --------------------------------------------- latency rounding + p99 window
+
+def test_single_rounding_site_for_latency():
+    """Every latency a result persists rounds through ONE helper:
+    identical inputs give identical persisted values."""
+    from kubetpu.perf.runner import WorkloadResult, round_latency_ms
+
+    assert round_latency_ms(None) is None
+    assert round_latency_ms(39.6789) == 39.68
+    r = WorkloadResult(
+        case_name="c", workload_name="w", threshold=None, measure_pods=1,
+        scheduled=1, duration_s=1.0, throughput=1.0, vs_threshold=None,
+        attempts=1, cycles=1, p99_attempt_latency_ms=39.6789,
+    )
+    assert r.to_json()["p99_attempt_latency_ms"] == round_latency_ms(39.6789)
+
+
+def test_measured_p99_helper_scopes_to_window():
+    """Satellite: the p99 window-scoping rule ('a large init phase must
+    not dominate the reported p99s') extracted into a directly-tested
+    helper shared by both runner call sites and the staged percentiles."""
+    from kubetpu.metrics import SchedulerMetricsRegistry, window_quantile_ms
+
+    m = SchedulerMetricsRegistry()
+    h = m.pod_scheduling_sli_duration
+    for _ in range(100):
+        h.labels("1").observe(10.0)        # the init phase: huge latencies
+    base = m.snapshot_baseline()
+    for _ in range(100):
+        h.labels("1").observe(0.010)       # the measured phase: 10ms
+    windowed = window_quantile_ms(h, base["sli_duration"], 0.99)
+    unscoped = window_quantile_ms(h, None, 0.99)
+    assert windowed < 100.0 < unscoped     # init excluded vs dominated
+    # empty window → None, not NaN
+    base2 = m.snapshot_baseline()
+    assert window_quantile_ms(h, base2["sli_duration"], 0.99) is None
+
+    # the runner's wrapper applies exactly this scoping
+    from kubetpu.perf.runner import measured_p99_ms
+
+    class FakeSched:
+        class metrics:
+            class prom:
+                pod_scheduling_sli_duration = h
+
+    assert measured_p99_ms(FakeSched, None) is None
+    got = measured_p99_ms(FakeSched, base)
+    assert got == pytest.approx(windowed)
+
+
+def test_staged_percentiles_window_scoped():
+    from kubetpu.metrics import SchedulerMetricsRegistry
+
+    m = SchedulerMetricsRegistry()
+    h = m.e2e_scheduling_duration
+    h.labels("kernel").observe(5.0)            # init-phase outlier
+    base = m.snapshot_baseline()
+    for _ in range(10):
+        h.labels("kernel").observe(0.001)
+        h.labels("e2e").observe(0.004)
+    staged = m.staged_percentiles(base)
+    assert set(staged) == {"kernel", "e2e"}
+    assert staged["kernel"]["p99"] < 100.0     # the 5s outlier is excluded
+    assert m.staged_percentiles(m.snapshot_baseline()) is None

@@ -255,7 +255,6 @@ def test_wire_checker_covers_hot_path_modules_not_exempt_surfaces():
         "kubetpu/api/codec.py",         # the seam encodes by design
         "kubetpu/cli.py",               # human-facing CLI output
         "kubetpu/sched/diagnostics.py",  # debug endpoints
-        "kubetpu/benchdiff.py",         # bench-record tooling
     ):
         assert f not in covered, f"WP001 wrongly covers exempt {f}"
 
@@ -299,7 +298,7 @@ def test_wal_checker_covers_the_store_wrapper_not_the_replay_side():
 def test_proc_checker_covers_kubetpu_but_not_the_launch_seam():
     """PS001 (process-spawn seam discipline) walks all of kubetpu/ — the
     modules that historically grew ad-hoc subprocess harnesses (perf,
-    cli, bench entry points) included — and does NOT walk the seam
+    cli) included — and does NOT walk the seam
     itself. Pinned against the ACTUAL walk, and against the seam still
     SPAWNING: a supervisor refactored away from Popen would leave PS001
     guarding air while nothing in the repo could start a child."""

@@ -622,7 +622,7 @@ def test_explain_fetches_from_the_collector(capsys):
 
 # ---------------------------------------------------------------------------
 # the multi-process smoke: the ROADMAP-1 slice, on the PR-13 launch
-# subsystem — the tier-1 smoke and the mp bench ladder exercise the SAME
+# subsystem — the tier-1 smoke and the perf runner exercise the SAME
 # spawn/banner/readiness/cascade code (kubetpu.launch.Supervisor)
 # ---------------------------------------------------------------------------
 
