@@ -816,6 +816,10 @@ def _scheduler_iteration(sched, informers, is_leader=lambda: True,
                 deliveries = pump()
                 sched.schedule_batch()
                 sched._drain_bind_completions()
+            else:
+                # not (or no longer) the leader: what is in flight must not
+                # be bound by this process, nor stay popped
+                sched.abandon_inflight()
             if sp is not None:
                 sp.discard = not (deliveries or sched.loop_work != work0)
     return once
@@ -931,7 +935,9 @@ def cmd_scheduler(args) -> int:
     sched = Scheduler(
         client, cfg=cfg, engine=args.engine,
         max_batch=getattr(args, "max_batch", 1024),
-        pipeline=(args.pipeline == "on"),
+        # this process IS a loop: it runs the two-stage cycle, so a batch's
+        # device program runs under the loop's flush, drain and pump
+        pipeline=True,
         encode_cache=(args.encode_cache == "on"),
         bulk=(args.bulk == "on"),
         mesh=mesh,
@@ -1045,12 +1051,19 @@ def cmd_scheduler(args) -> int:
     try:
         return _make_loop(once, stop=stop, clock=sched.loop_clock)()
     finally:
-        if exporter is not None:
-            exporter.close()
-        if membership is not None:
-            membership.release()
-        if diag is not None:
-            diag.close()
+        try:
+            # the cycle in flight is completed, and its binds and Events
+            # written, before the leases go: a SIGTERM (a rolling restart)
+            # drops no popped pod. An iteration that was not the leader
+            # left nothing in flight
+            sched.close()
+        finally:
+            if exporter is not None:
+                exporter.close()
+            if membership is not None:
+                membership.release()
+            if diag is not None:
+                diag.close()
 
 
 def cmd_controller_manager(args) -> int:
@@ -1698,12 +1711,6 @@ def build_parser() -> argparse.ArgumentParser:
     schd.add_argument("--config", default="", help="KubeSchedulerConfiguration file")
     schd.add_argument("--engine", default="greedy",
                       choices=["greedy", "batched", "packing"])
-    schd.add_argument("--pipeline", default="off", choices=["on", "off"],
-                      help="two-stage pipelined cycles with a device-"
-                           "resident node block and dirty-row delta "
-                           "uploads; assignments stay pod-for-pod "
-                           "identical to the serial loop ('off' is the "
-                           "debugging escape hatch)")
     schd.add_argument("--encode-cache", default="on", choices=["on", "off"],
                       help="event-time template-keyed pod encoding: static "
                            "tensor rows built at informer delivery and "

@@ -52,6 +52,10 @@ ENGINES = (
     "packing",          # constraint-based packing (cluster objectives)
 )
 
+#: what the loop's next call did with a cycle dispatched ahead of it: the
+#: ONLY legal values of {result} on scheduler_pipeline_cycles_total.
+PIPELINE_RESULTS = ("applied", "replayed")
+
 
 def window_quantile_ms(
     hist: Histogram, baseline: Histogram | None = None, q: float = 0.99
@@ -122,6 +126,20 @@ class SchedulerMetricsRegistry:
             "topology spread constraint, so that the spread encode and the "
             "spread kernels ran for them; a cycle with none adds nothing.",
         )
+        self.pipeline_cycles = r.counter(
+            "scheduler_pipeline_cycles_total",
+            "Scheduling cycles whose device program was dispatched ahead "
+            "of the loop's next call (the two-stage cycle), by what the "
+            "next call did with the result: applied as it stood, or "
+            "thrown away and replayed serially because the cluster moved "
+            "under it. A cycle launched and synced in one call (nothing "
+            "queued behind it, a mixed-profile pop, a replay) adds nothing.",
+            labels=("result",),
+            declared={"result": PIPELINE_RESULTS},
+        )
+        for result in PIPELINE_RESULTS:
+            # both on the first scrape, at zero: a delta meets no gap
+            self.pipeline_cycles.labels(result)
         self.preemption_attempts = r.counter(
             "scheduler_preemption_attempts_total",
             "Total preemption attempts in the cluster till now",

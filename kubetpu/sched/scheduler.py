@@ -202,18 +202,30 @@ class Scheduler:
         successful bind, ``FailedScheduling`` on an unschedulable
         attempt — schedule_one.go's recorder.Eventf calls); None = no
         events.
-        ``pipeline``: run the two-stage pipelined cycle with a device-
-        resident node block and dirty-row delta uploads (JAX async
-        dispatch overlaps the next batch's host encode with the current
-        batch's device program). Assignments are pod-for-pod identical to
-        the serial loop — a cycle whose state changed under it is replayed
-        — so ``pipeline=False`` is purely a debugging escape hatch.
+        ``pipeline``: which of the class's two kinds of caller this is, not
+        a tuning knob. ``True`` is for a LOOP that calls ``schedule_batch``
+        again and can take a cycle's counts one call late (the served
+        ``kubetpu scheduler`` always; ``run_until_idle``): the two-stage
+        cycle dispatches batch N+1's device program and returns, so the
+        caller's bind flush, Event write, drain and pump for batch N run
+        while the chip works, and the next call syncs (JAX's asynchronous
+        dispatch is the only concurrency; the loop stays one thread). A
+        cycle whose cluster state moved under it is thrown away and
+        replayed serially, so assignments are pod for pod the serial
+        loop's (``scheduler_pipeline_cycles_total{result}`` counts both
+        outcomes); a batch with nothing queued behind it is answered in
+        the call that popped it. ``False`` (the default) is for a ONE-SHOT
+        caller that needs the batch it handed in answered before the call
+        returns: the tests, ``chip_smoke.py``, the perf drivers,
+        placement. It is also what a replay and a mixed-profile pop run.
+        Both ride the same ``_launch_cycle`` / ``_finish_cycle`` and the
+        same device-resident node block with dirty-row delta uploads.
         ``encode_cache``: event-time incremental pod encoding — static
         tensor rows are template-keyed, built when the informer delivers
         the pod, and gathered (not rebuilt) at cycle time; node events
         invalidate by epoch. Cached encodes are bit-identical to fresh
-        ones, so ``encode_cache=False`` is a debugging escape hatch like
-        ``pipeline=False``.
+        ones, so ``encode_cache=False`` is purely a debugging escape
+        hatch.
         ``bulk``: opportunistic API-plane micro-batching — the dispatcher
         accumulates a cycle's API writes and flushes them at the cycle
         boundary as per-call-type bulk RPCs (a cycle's binds become one
@@ -1143,7 +1155,10 @@ class Scheduler:
         try:
             # pre-encode this batch while the in-flight cycle runs on
             # device, then sync it, then patch + dispatch this one
-            static = self._pre_encode(profile, infos)
+            t_pre = time.perf_counter()
+            with self.tracer.span("encode", cycle=cycle_id, stage="static"):
+                static = self._pre_encode(profile, infos)
+            pre_encode_s = time.perf_counter() - t_pre
             res = self._complete_inflight()
         except Exception:
             # a failure completing the PREVIOUS cycle must not strand the
@@ -1156,7 +1171,8 @@ class Scheduler:
         # (same reporting shape as the serial loop's multi-profile error
         # path: state consistent, counts lost to the raise)
         self._inflight = self._launch_cycle(
-            profile, infos, cycle_id, static=static, pipelined=True
+            profile, infos, cycle_id, static=static, pipelined=True,
+            pre_encode_s=pre_encode_s,
         )
         return res
 
@@ -1254,6 +1270,11 @@ class Scheduler:
             or self._snapshot.namespaces_generation != inflight.ns_gen
             or (dra.generation, dra.claims_version) != inflight.dra_gen
         )
+        if inflight.pipelined:
+            # a cycle dispatched ahead: applied as it stood, or replayed
+            self.metrics.prom.pipeline_cycles.labels(
+                "replayed" if stale else "applied"
+            ).inc()
         if stale:
             self.metrics.pipeline_replays += 1
             # let the stale program finish before its input buffers can be
@@ -1267,6 +1288,23 @@ class Scheduler:
             )
             return self._finish_cycle(replay)
         return self._finish_cycle(inflight)
+
+    def abandon_inflight(self) -> None:
+        """Give up the cycle in flight, if there is one, binding nothing:
+        wait for its device program (its input buffers may be donated by
+        the next encode), drop the result and requeue its pods with error
+        status, so none stays in the queue's in-flight set. For a loop that
+        may no longer bind — the served loop calls it in every iteration it
+        is not the leader."""
+        inflight, self._inflight = self._inflight, None
+        if inflight is None:
+            return
+        try:
+            jax.block_until_ready(inflight.assignments)
+        except Exception:
+            pass    # nothing of the result is used
+        for info in inflight.batch_infos:
+            self.queue.add_unschedulable(info, error=True)
 
     def _profile_cycle(
         self, profile: C.Profile, batch_infos: list[QueuedPodInfo]
@@ -1284,10 +1322,14 @@ class Scheduler:
         cycle_id: int,
         static: "rt.StaticBatch | None" = None,
         pipelined: bool = False,
+        pre_encode_s: float = 0.0,
     ) -> _InflightCycle:
         """Snapshot → encode (or finalize a pre-encoded StaticBatch) →
         dispatch the assign program. Does NOT block on the device: JAX async
-        dispatch returns immediately; ``_finish_cycle`` syncs."""
+        dispatch returns immediately; ``_finish_cycle`` syncs.
+        ``pre_encode_s``: what ``_pre_encode`` already spent on this batch,
+        so that the ``PreFilter`` point holds the batch's whole host encode
+        in a two-stage cycle as in a serial one."""
         from ..metrics.tpu import jit_cache_size
 
         t0 = self.clock()
@@ -1342,7 +1384,7 @@ class Scheduler:
                     )
             # the host encode builds per-pod state ahead of filtering —
             # the PreFilter role in the reference's extension-point map
-            encode_s = time.perf_counter() - t_enc
+            encode_s = time.perf_counter() - t_enc + pre_encode_s
             prom.framework_extension_point_duration.labels(
                 "PreFilter", "Success", profile.name
             ).observe(encode_s)
