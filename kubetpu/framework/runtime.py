@@ -239,6 +239,9 @@ class SpreadEncodeStamp:
     signatures: int
     domains: int
     constrained_pods: int
+    #: of those, the pods with at least one ScheduleAnyway constraint: the
+    #: ones the soft spread score runs for
+    soft_pods: int
 
 
 class StaleStaticEncode(Exception):
@@ -1165,11 +1168,14 @@ def finalize_batch(
             groups=_groups_memo[0] if _groups_memo else None,
         )
         if sp is not None:
+            used = sp.sig_idx[:P] >= 0              # (P, C) constraint slots
             spread_stamp = SpreadEncodeStamp(
                 start=t_spread, end=time.perf_counter(),
                 signatures=sp.num_sigs, domains=sp.max_domains,
-                constrained_pods=int(
-                    (sp.sig_idx[:P] >= 0).any(axis=1).sum()
+                constrained_pods=int(used.any(axis=1).sum()),
+                soft_pods=int(
+                    (used & (sp.action[:P] == enc_spread.SOFT))
+                    .any(axis=1).sum()
                 ),
             )
             spread_dev = SpreadDevice(

@@ -16,7 +16,7 @@ layouts (pkg/scheduler/metrics/metrics.go):
   extension_point="Filter+Score" at the framework level instead; the host
   spread encode is timed as plugin="PodTopologySpread",
   extension_point="PreFilter" (``Scheduler._launch_cycle``), beside
-  spread_constrained_pods_total
+  spread_constrained_pods_total and spread_soft_constrained_pods_total
 - schedule_attempts_total{result, profile}, preemption_attempts_total,
   preemption_victims (:267 ExponentialBuckets(1, 2, 7)), pending_pods{queue}
 """
@@ -125,6 +125,12 @@ class SchedulerMetricsRegistry:
             "Pods of the scheduling cycles that carried or inherited a "
             "topology spread constraint, so that the spread encode and the "
             "spread kernels ran for them; a cycle with none adds nothing.",
+        )
+        self.spread_soft_constrained_pods = r.counter(
+            "scheduler_spread_soft_constrained_pods_total",
+            "Of those, the pods with at least one ScheduleAnyway constraint: "
+            "the soft spread score ran for them in every step of the assign "
+            "scan and in the explain kernel.",
         )
         self.pipeline_cycles = r.counter(
             "scheduler_pipeline_cycles_total",

@@ -105,7 +105,7 @@ def _explain_kernel(device_batch, params, assignments):
 
         from ..framework import runtime as rt
 
-        def kernel(b, p, idx):
+        def explain_kernel(b, p, idx):
             # filter_components is recomputed inside feasible_and_scores,
             # but the two subgraphs are identical pure computations and
             # XLA CSEs them — measured: both ≈ feasible_and_scores alone
@@ -140,7 +140,7 @@ def _explain_kernel(device_batch, params, assignments):
             )[:, 0]                                              # (P,)
             return feasible, reject, top_vals, top_idx, win
 
-        _EXPLAIN_JIT = jax.jit(kernel, static_argnames=("p",))
+        _EXPLAIN_JIT = jax.jit(explain_kernel, static_argnames=("p",))
     return _EXPLAIN_JIT(device_batch, params, assignments)
 
 
@@ -154,10 +154,11 @@ def _explain_masks_kernel(device_batch, params):
 
         from ..framework import runtime as rt
 
-        def kernel(b, p):
+        def explain_masks_kernel(b, p):
             return rt.filter_components(b, p)[:5]
 
-        _EXPLAIN_MASKS_JIT = jax.jit(kernel, static_argnames=("p",))
+        _EXPLAIN_MASKS_JIT = jax.jit(
+            explain_masks_kernel, static_argnames=("p",))
     return _EXPLAIN_MASKS_JIT(device_batch, params)
 
 

@@ -1381,6 +1381,7 @@ class Scheduler:
                         cycle=cycle_id, signatures=stamp.signatures,
                         domains=stamp.domains,
                         constrained_pods=stamp.constrained_pods,
+                        soft_pods=stamp.soft_pods,
                     )
             # the host encode builds per-pod state ahead of filtering —
             # the PreFilter role in the reference's extension-point map
@@ -1638,6 +1639,9 @@ class Scheduler:
             # beside the attempts it is read as a share of
             prom.spread_constrained_pods.inc(
                 batch.spread_encode.constrained_pods
+            )
+            prom.spread_soft_constrained_pods.inc(
+                batch.spread_encode.soft_pods
             )
 
         try:

@@ -1,10 +1,13 @@
 """The share of the greedy assign program's device time spent under the
 spread path's named scopes (``spread_filter``, ``spread_score``,
-``spread_counts_update``), at the size of ``topologyspread-5k``: 5000 nodes in
-three zones, ``existing`` bound color=blue pods, ``real`` pending pods of the
-template padded to 1024.
+``spread_counts_update``), at the size of a spread cell's configuration
+(``topologyspread-5k.saturate`` unless ``--workload`` names another, e.g.
+``preferredspread-5k.saturate``, whose soft score is the ``spread_score``
+scope): its nodes in its zones, ``existing`` bound pods of its measured
+template, ``real`` pending pods of the template padded to 1024.
 
-    python3 tools/spread_scope_share.py [nodes [existing [real]]]
+    python3 tools/spread_scope_share.py [--workload CELL] \
+        [nodes [existing [real]]]
 
 ONE synthetic batch, not a run of the cell: the harness's
 ``xplane.reduce_trace`` keeps no scope, and the trace's op events carry none
@@ -14,6 +17,7 @@ count of instructions by scope). It turns the persistent compile cache off for
 its own process: the cache's key leaves op metadata out, so a hit would hand
 back a program compiled before the scopes existed, without their names.
 """
+import argparse
 import collections
 import json
 import os
@@ -31,27 +35,33 @@ import kubetpu  # noqa: E402,F401
 
 jax.config.update("jax_enable_compilation_cache", False)
 from benchmark.harness import templates, xplane  # noqa: E402
-from benchmark.harness.templates_spread import (  # noqa: E402
-    pod_with_topology_spreading)
+from benchmark.harness.manifest import Cell, load_manifest  # noqa: E402
 from kubetpu.assign.greedy import greedy_assign_device  # noqa: E402
 from kubetpu.framework import config as C  # noqa: E402
 from kubetpu.framework import runtime as rt  # noqa: E402
 from kubetpu.state.snapshot import Cache  # noqa: E402
 
 SCOPES = ("spread_filter", "spread_counts_update", "spread_score")
-nodes = int(sys.argv[1]) if len(sys.argv) > 1 else 5000
-existing = int(sys.argv[2]) if len(sys.argv) > 2 else 15000
-real = int(sys.argv[3]) if len(sys.argv) > 3 else 128
+ap = argparse.ArgumentParser()
+ap.add_argument("--workload", default="topologyspread-5k.saturate")
+ap.add_argument("nodes", type=int, nargs="?")
+ap.add_argument("existing", type=int, nargs="?", default=15000)
+ap.add_argument("real", type=int, nargs="?", default=128)
+args = ap.parse_args()
+config = Cell(load_manifest(), args.workload).config
+nodes = args.nodes or config["nodes"]
+existing, real = args.existing, args.real
 runs = 5
-zones = ("moon-1", "moon-2", "moon-3")
+zones = tuple(config["zones"])
+measured = config["measured_pods"]
+template = templates.resolve(templates.POD_TEMPLATES, measured["template"])
 cache = Cache()
 for i in range(nodes):
     cache.add_node(templates.node_default(i, zones))
 for j in range(existing):
-    cache.add_pod(pod_with_topology_spreading(
-        f"e{j}", "namespace-1").with_node(f"scheduler-perf-{j % nodes}"))
-pending = [pod_with_topology_spreading(f"p{j}", "namespace-1")
-           for j in range(real)]
+    cache.add_pod(template(f"e{j}", measured["namespace"]).with_node(
+        f"scheduler-perf-{j % nodes}"))
+pending = [template(f"p{j}", measured["namespace"]) for j in range(real)]
 profile = C.Profile()
 snap = cache.update_snapshot()
 t0 = time.perf_counter()
@@ -59,6 +69,7 @@ batch = rt.encode_batch(snap, pending, profile, pad_pods=1024)
 print(json.dumps({"encode_s": time.perf_counter() - t0,
                   "spread_encode_s": batch.spread_encode.end
                   - batch.spread_encode.start,
+                  "workload": args.workload,
                   "device": kubetpu.device_stamp()}), flush=True)
 params = rt.score_params(profile, batch.resource_names)
 compiled = greedy_assign_device.lower(batch.device, params).compile()
