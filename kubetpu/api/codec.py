@@ -79,19 +79,22 @@ class UnsupportedWireError(ValueError):
 
 class _KindPlan:
     """Per-kind encode/decode plan: ordered fields with their global
-    name ids, defaults (MISSING = required, always encoded) and type
-    hints (decode-side coercion shares the scheme's strict rules)."""
+    name ids and defaults (MISSING = required, always encoded); for the
+    decode, each field id's name and compiled coercer (the scheme's own
+    closures: one set of strict rules for both codecs) and the kind's
+    defaulting hook."""
 
-    __slots__ = ("kind_id", "kind", "cls", "fields", "by_fid")
+    __slots__ = ("kind_id", "kind", "cls", "fields", "by_fid", "defaulter")
 
     def __init__(self, kind_id: int, kind: str, cls: type,
                  name_ids: dict[str, int]) -> None:
         self.kind_id = kind_id
         self.kind = kind
         self.cls = cls
-        hints = scheme.type_hints(cls)
+        coercers = scheme.field_coercers(cls)
+        self.defaulter = scheme.defaulter_for(cls)
         self.fields: list[tuple[int, str, Any]] = []
-        self.by_fid: dict[int, tuple[str, Any]] = {}
+        self.by_fid: dict[int, tuple[str, Callable[[Any], Any]]] = {}
         for f in dataclasses.fields(cls):
             if f.default is not dataclasses.MISSING:
                 default = f.default
@@ -101,7 +104,7 @@ class _KindPlan:
                 default = dataclasses.MISSING
             fid = name_ids[f.name]
             self.fields.append((fid, f.name, default))
-            self.by_fid[fid] = (f.name, hints[f.name])
+            self.by_fid[fid] = (f.name, coercers[f.name])
 
 
 class _Tables:
@@ -459,20 +462,24 @@ def _unpack(buf: bytes, pos: int, t: _Tables) -> tuple[Any, int]:
         if kid >= len(t.plans_by_id):
             raise UnsupportedWireError(f"unknown kind id {kid}")
         plan = t.plans_by_id[kid]
+        by_fid = plan.by_fid
         kwargs: dict[str, Any] = {}
         for _ in range(nf):
             fid = _unpack_H(buf, pos)[0]
             pos += 2
             raw, pos = _unpack(buf, pos, t)
-            got = plan.by_fid.get(fid)
+            got = by_fid.get(fid)
             if got is None:
                 raise scheme.SchemeError(
                     f"{plan.kind}: unknown field id {fid} "
                     "(strict decoding)"
                 )
-            name, hint = got
-            kwargs[name] = scheme.coerce_value(raw, hint)
-        return scheme.apply_defaults(plan.cls(**kwargs)), pos
+            name, coerce = got
+            kwargs[name] = coerce(raw)
+        obj = plan.cls(**kwargs)
+        if plan.defaulter is not None:
+            obj = plan.defaulter(obj)
+        return obj, pos
     if tag == 0xAF:                     # bigint
         n = buf[pos]
         pos += 1

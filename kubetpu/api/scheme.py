@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import types
 import typing
 from typing import Any
 
@@ -101,8 +102,13 @@ def register_conversion(kind: str, api_version: str, fn) -> None:
 
 
 def register_defaults(cls: type, fn) -> None:
-    """Run ``fn(obj) -> obj`` after every decode of ``cls``."""
+    """Run ``fn(obj) -> obj`` after every decode of ``cls``. The binary
+    codec's plan holds the hook per kind, so a registration rebuilds its
+    tables as a kind's does (the fingerprint does not move: a hook is no
+    part of the wire)."""
+    global _GENERATION
     _DEFAULTERS[cls] = fn
+    _GENERATION += 1
 
 
 def _apply_defaults(obj: Any) -> Any:
@@ -164,86 +170,190 @@ def _resolve_hints(cls: type) -> dict[str, Any]:
     return cached
 
 
-def _coerce(value: Any, hint: Any) -> Any:
-    """Rebuild tuples/enums/nested dataclasses from the field annotation."""
+# ------------------------------------------------------ compiled coercion
+#
+# A field's annotation never changes between two objects, so what it asks
+# of a value is worked out ONCE: ``_coercer(hint)`` turns the annotation
+# into a closure tree (union arms, tuple items, enums, nested dataclasses,
+# dict values, the strict primitive leaves) and every decode of every
+# object of the kind calls the closure. Both decode paths go through it:
+# ``_decode_into`` (JSON, manifests, the v1 conversions) and the binary
+# codec's per-kind plan (``field_coercers``), so the two codecs cannot
+# drift on what a field accepts.
+#
+# What every closure holds, whatever the annotation (the rules the
+# interpreting decoder applied before it looked at the hint): ``None``
+# passes; an already-typed object passes (the binary codec materializes
+# nested objects before coercion: its object tag carries the kind); a
+# kind-tagged dict decodes by its tag. A closure tries the annotated shape
+# first, which those three can never have, and falls back to them.
+
+_UNION_ORIGINS = (typing.Union, types.UnionType)
+_NOT_ANNOTATED = object()
+
+#: annotation -> closure; annotations hash by value, so two kinds that
+#: spell ``tuple[str, ...]`` share one closure
+_COERCERS: dict[Any, Any] = {}
+
+
+def _untyped(value: Any) -> Any:
+    """The passes that do not depend on the annotation, else
+    ``_NOT_ANNOTATED``."""
     if value is None:
         return None
-    if dataclasses.is_dataclass(value) and not isinstance(value, type):
-        # already typed: the binary codec materializes nested objects
-        # before coercion (its object tag carries the kind), so a typed
-        # value passes straight through the same strict path
+    if hasattr(type(value), "__dataclass_fields__"):
         return value
     if isinstance(value, dict) and "kind" in value:
         return decode(value)
+    return _NOT_ANNOTATED
+
+
+def _leaf_coercer(hint: type, exact: tuple[type, ...], accepts) -> Any:
+    """Primitive leaves are type-checked against the annotation — strict
+    decoding covers field types, not just unknown kinds/fields. A bool is
+    an int to ``isinstance`` and is refused as one; int is accepted where
+    float is annotated (JSON has one number type)."""
+    name = hint.__name__
+    refuse_bool = hint is not bool
+
+    def coerce(value: Any) -> Any:
+        if type(value) in exact:
+            return value
+        got = _untyped(value)
+        if got is not _NOT_ANNOTATED:
+            return got
+        if not isinstance(value, accepts) or (
+            refuse_bool and isinstance(value, bool)
+        ):
+            raise SchemeError(f"expected {name}, got {value!r}")
+        return value
+    return coerce
+
+
+def _passthrough(value: Any) -> Any:
+    got = _untyped(value)
+    return value if got is _NOT_ANNOTATED else got
+
+
+def _build_coercer(hint: Any) -> Any:
     origin = typing.get_origin(hint)
-    if origin in (typing.Union, getattr(__import__("types"), "UnionType", ())):
-        for arm in typing.get_args(hint):
-            if arm is type(None):
-                continue
-            try:
-                return _coerce(value, arm)
-            except (SchemeError, TypeError, ValueError):
-                continue
-        raise SchemeError(f"no union arm of {hint} accepts {value!r}")
+    args = typing.get_args(hint)
+    if origin in _UNION_ORIGINS:
+        arms = tuple(_coercer(a) for a in args if a is not type(None))
+
+        def coerce_union(value: Any) -> Any:
+            if value is None:
+                return None
+            if isinstance(value, dict) and "kind" in value:
+                return decode(value)    # its own errors, not an arm's
+            for arm in arms:
+                try:
+                    return arm(value)
+                except (SchemeError, TypeError, ValueError):
+                    continue
+            raise SchemeError(f"no union arm of {hint} accepts {value!r}")
+        return coerce_union
     if origin is tuple:
-        if not isinstance(value, list):
-            raise SchemeError(f"expected array for {hint}, got {value!r}")
-        args = typing.get_args(hint)
         if len(args) == 2 and args[1] is Ellipsis:
-            return tuple(_coerce(v, args[0]) for v in value)
-        if args:
-            return tuple(
-                _coerce(v, args[i % len(args)]) for i, v in enumerate(value)
-            )
-        return tuple(value)
+            item = _coercer(args[0])
+
+            def coerce_items(value: list) -> tuple:
+                return tuple([item(v) for v in value])
+        elif args:
+            items = tuple(_coercer(a) for a in args)
+            n = len(items)
+
+            def coerce_items(value: list) -> tuple:
+                return tuple([items[i % n](v) for i, v in enumerate(value)])
+        else:
+            coerce_items = tuple
+
+        def coerce_tuple(value: Any) -> Any:
+            if isinstance(value, list):
+                return coerce_items(value)
+            got = _untyped(value)
+            if got is _NOT_ANNOTATED:
+                raise SchemeError(f"expected array for {hint}, got {value!r}")
+            return got
+        return coerce_tuple
     if isinstance(hint, type) and issubclass(hint, enum.Enum):
-        return hint(value)
+        def coerce_enum(value: Any) -> Any:
+            if type(value) is not str:
+                got = _untyped(value)
+                if got is not _NOT_ANNOTATED:
+                    return got
+            return hint(value)
+        return coerce_enum
     if isinstance(hint, type) and dataclasses.is_dataclass(hint):
-        if isinstance(value, dict):
-            return _decode_into(hint, value)
-        raise SchemeError(f"expected object for {hint.__name__}, got {value!r}")
+        def coerce_object(value: Any) -> Any:
+            if type(value) is hint:
+                return value
+            got = _untyped(value)
+            if got is not _NOT_ANNOTATED:
+                return got
+            if isinstance(value, dict):
+                return _decode_into(hint, value)
+            raise SchemeError(
+                f"expected object for {hint.__name__}, got {value!r}"
+            )
+        return coerce_object
     if origin is dict:
-        if not isinstance(value, dict):
-            raise SchemeError(f"expected object for {hint}, got {value!r}")
-        args = typing.get_args(hint)
-        if args:
-            return {str(k): _coerce(v, args[1]) for k, v in value.items()}
-        return value
-    # Primitive leaves are type-checked against the annotation — strict
-    # decoding covers field types, not just unknown kinds/fields. bool is
-    # checked before int (bool is an int subclass); int is accepted where
-    # float is annotated (JSON has one number type).
+        item = _coercer(args[1]) if args else None
+
+        def coerce_dict(value: Any) -> Any:
+            if isinstance(value, dict):
+                if "kind" in value:
+                    return decode(value)
+                if item is None:
+                    return value
+                return {str(k): item(v) for k, v in value.items()}
+            got = _untyped(value)
+            if got is _NOT_ANNOTATED:
+                raise SchemeError(f"expected object for {hint}, got {value!r}")
+            return got
+        return coerce_dict
     if hint is bool:
-        if not isinstance(value, bool):
-            raise SchemeError(f"expected bool, got {value!r}")
-        return value
+        return _leaf_coercer(bool, (bool,), bool)
     if hint is int:
-        if isinstance(value, bool) or not isinstance(value, int):
-            raise SchemeError(f"expected int, got {value!r}")
-        return value
+        return _leaf_coercer(int, (int,), int)
     if hint is float:
-        if isinstance(value, bool) or not isinstance(value, (int, float)):
-            raise SchemeError(f"expected float, got {value!r}")
-        return value
+        return _leaf_coercer(float, (float, int), (int, float))
     if hint is str:
-        if not isinstance(value, str):
-            raise SchemeError(f"expected str, got {value!r}")
-        return value
-    return value
+        return _leaf_coercer(str, (str,), str)
+    return _passthrough
+
+
+def _coercer(hint: Any) -> Any:
+    fn = _COERCERS.get(hint)
+    if fn is None:
+        fn = _COERCERS[hint] = _build_coercer(hint)
+    return fn
 
 
 def coerce_value(value: Any, hint: Any) -> Any:
-    """Public face of the field-coercion rules (tuple rebuild, enum
-    reconstruction, strict primitive checks) — the binary codec decodes
-    through the SAME rules as the JSON path, so the two codecs cannot
-    drift on what a field accepts."""
-    return _coerce(value, hint)
+    """One value against one annotation, by the field-coercion rules
+    (tuple rebuild, enum reconstruction, strict primitive checks)."""
+    return _coercer(hint)(value)
 
 
-def apply_defaults(obj: Any) -> Any:
-    """Run the kind's registered defaulting hook (every decode path —
+def field_coercers(cls: type) -> dict[str, Any]:
+    """Field name -> compiled coercer for a registered class: built on
+    the class's first decode and kept on it beside its resolved hints.
+    The binary codec's per-kind plan holds these same closures."""
+    cached = cls.__dict__.get("__kubetpu_coercers__")
+    if cached is None:
+        hints = _resolve_hints(cls)
+        cached = {
+            f.name: _coercer(hints[f.name]) for f in dataclasses.fields(cls)
+        }
+        setattr(cls, "__kubetpu_coercers__", cached)
+    return cached
+
+
+def defaulter_for(cls: type) -> Any:
+    """The kind's registered defaulting hook, or None (every decode path —
     JSON and binary — must apply the same defaults)."""
-    return _apply_defaults(obj)
+    return _DEFAULTERS.get(cls)
 
 
 def type_hints(cls: type) -> dict[str, Any]:
@@ -252,17 +362,17 @@ def type_hints(cls: type) -> dict[str, Any]:
 
 
 def _decode_into(cls: type, data: dict) -> Any:
-    hints = _resolve_hints(cls)
-    field_names = {f.name for f in dataclasses.fields(cls)}
+    coercers = field_coercers(cls)
     kwargs: dict[str, Any] = {}
     for key, raw in data.items():
         if key in ("kind", "apiVersion"):
             continue
-        if key not in field_names:
+        coerce = coercers.get(key)
+        if coerce is None:
             raise SchemeError(
                 f"{cls.__name__}: unknown field {key!r} (strict decoding)"
             )
-        kwargs[key] = _coerce(raw, hints[key])
+        kwargs[key] = coerce(raw)
     return cls(**kwargs)
 
 

@@ -26,6 +26,8 @@ sides never have to agree in advance.
 from __future__ import annotations
 
 import http.client
+import threading
+import time
 from typing import Any
 
 from ..api import codec
@@ -75,8 +77,6 @@ class RemoteStore:
         span per request so the apiserver's server span joins it. False
         (the default, ``--telemetry off``) is byte-identical to the
         pre-telemetry wire: no header, no parameter, no span."""
-        import threading
-
         if wire not in ("binary", "json"):
             raise ValueError(f"wire must be binary|json, got {wire!r}")
         self.base = base_url.rstrip("/")
@@ -111,6 +111,15 @@ class RemoteStore:
             "relists": 0, "pages": 0, "bytes": 0, "max_page_bytes": 0,
         }
         self.last_relist: "dict[str, int] | None" = None
+        # the decode clock: what ``codec.loads`` of response bodies costs
+        # this process, one ``perf_counter`` pair a RESPONSE. A cell
+        # ``[seconds, bytes, seconds of watch_bulk replies alone]`` per
+        # thread, written by its thread alone (no lock a response), summed
+        # per caller at scrape time: "loop" is the thread that built this
+        # client (in ``kubetpu scheduler`` the one that runs the loop),
+        # "worker" any other (the dispatcher's)
+        self._owner_thread = threading.get_ident()
+        self._decode_cells: list[tuple[str, list]] = []
 
     # ------------------------------------------------- reconnect policy
     @staticmethod
@@ -149,6 +158,85 @@ class RemoteStore:
             )
         return "".join(lines)
 
+    # ------------------------------------------------------- decode clock
+    def _decode_cell(self) -> list:
+        """This thread's ``[seconds, bytes, watch seconds]``."""
+        cell = getattr(self._local, "decode", None)
+        if cell is None:
+            cell = self._local.decode = [0.0, 0, 0.0]
+            caller = (
+                "loop" if threading.get_ident() == self._owner_thread
+                else "worker"
+            )
+            with self._reconnect_lock:
+                self._decode_cells.append((caller, cell))
+        return cell
+
+    def _decode(self, raw: bytes, resp_ct: "str | None") -> Any:
+        """One response body → its tree, by its Content-Type, on the
+        decode clock. (On the JSON wire this is ``json.loads``: the typed
+        decode happens in ``codec.as_object``, outside the clock.)"""
+        cell = self._decode_cell()
+        t0 = time.perf_counter()
+        try:
+            return codec.loads(
+                raw or b"{}", codec.codec_for_content_type(resp_ct)
+            )
+        finally:
+            cell[0] += time.perf_counter() - t0
+            cell[1] += len(raw)
+
+    @property
+    def watch_decode_s(self) -> float:
+        """Decode seconds of ``watch_bulk`` replies alone (the informers'
+        poll), every thread's."""
+        with self._reconnect_lock:
+            return sum(cell[2] for _caller, cell in self._decode_cells)
+
+    def decode_metrics_text(self) -> str:
+        """Prometheus text for the decode clock, a diagnostics metrics
+        source beside the reconnect counter. The watch family carries the
+        scheduler's name: its informers' pump is ``watch_bulk``'s one
+        caller."""
+        with self._reconnect_lock:
+            cells = list(self._decode_cells)
+        seconds = {"loop": 0.0, "worker": 0.0}
+        size = {"loop": 0, "worker": 0}
+        watch_s = 0.0
+        for caller, (s, n, w) in cells:
+            seconds[caller] += s
+            size[caller] += n
+            watch_s += w
+        lines = [
+            "# HELP apiserver_client_decode_seconds_total Seconds this "
+            "client spent decoding response bodies (codec.loads), by the "
+            "thread that decoded: loop built the client, worker is any "
+            "other.\n"
+            "# TYPE apiserver_client_decode_seconds_total counter\n"
+        ]
+        lines += [
+            f'apiserver_client_decode_seconds_total{{caller="{c}"}} '
+            f"{seconds[c]:.6f}\n" for c in seconds
+        ]
+        lines.append(
+            "# HELP apiserver_client_decoded_bytes_total Bytes of the "
+            "response bodies decoded, by the same threads.\n"
+            "# TYPE apiserver_client_decoded_bytes_total counter\n"
+        )
+        lines += [
+            f'apiserver_client_decoded_bytes_total{{caller="{c}"}} '
+            f"{size[c]}\n" for c in size
+        ]
+        lines.append(
+            "# HELP scheduler_watch_decode_seconds_total Seconds the "
+            "informers' pump spent decoding watch_bulk replies: the "
+            "decode's part of the loop's pump_rpc phase.\n"
+            "# TYPE scheduler_watch_decode_seconds_total counter\n"
+            f"scheduler_watch_decode_seconds_total "
+            f"{watch_s:.6f}\n"
+        )
+        return "".join(lines)
+
     def _retried_get(self, path: str, budget: int, reason_for):
         """One idempotent GET hardened for apiserver restarts: a
         transient transport failure (past ``_request``'s single provably-
@@ -161,7 +249,6 @@ class RemoteStore:
         RemoteUnavailableError — the caller's catch-and-retry keeps the
         component alive at its own cadence."""
         import random
-        import time
 
         for attempt in range(budget + 1):
             if attempt:
@@ -277,9 +364,7 @@ class RemoteStore:
             # follower write redirect: the reply body names the leader
             payload = {}
             try:
-                payload = codec.loads(
-                    raw or b"{}", codec.codec_for_content_type(resp_ct)
-                )
+                payload = self._decode(raw, resp_ct)
             except Exception:  # noqa: BLE001 — fall through to the error below
                 pass
             leader = (payload.get("leader") or "").rstrip("/")
@@ -291,17 +376,13 @@ class RemoteStore:
             self._write_base = base = leader
         if status < 400:
             try:
-                return codec.loads(
-                    raw or b"{}", codec.codec_for_content_type(resp_ct)
-                )
+                return self._decode(raw, resp_ct)
             except codec.UnsupportedWireError as e:
                 raise RemoteStoreError(f"undecodable response: {e}") \
                     from None
         payload = {}
         try:
-            payload = codec.loads(
-                raw or b"{}", codec.codec_for_content_type(resp_ct)
-            )
+            payload = self._decode(raw, resp_ct)
         except Exception:
             pass
         reason = payload.get("error", f"HTTP {status}")
@@ -380,15 +461,13 @@ class RemoteStore:
         on any transport error; everything else surfaces as
         RemoteUnavailableError for the caller to decide. Returns
         (status, raw body, response content type)."""
-        import time as _time
-
         wire_out = codec.BINARY if self._wire_ok else codec.JSON
         data = codec.dumps(body, wire_out) if body is not None else None
         # ``ctx`` is the caller's per-LOGICAL-request trace context: the
         # provably-safe retry below and _request's 415/JSON re-issue both
         # re-send with the same trace + span ids
         headers = self._request_headers(wire_out, ctx)
-        t_span = _time.perf_counter() if ctx is not None else 0.0
+        t_span = time.perf_counter() if ctx is not None else 0.0
         last: Exception | None = None
         for attempt in range(2):
             try:
@@ -415,7 +494,7 @@ class RemoteStore:
                     # same trace id + this span id as its parent
                     self._tracer.record(
                         f"rpc.{method}", start=t_span,
-                        end=_time.perf_counter(), per_item=True,
+                        end=time.perf_counter(), per_item=True,
                         path=path.partition("?")[0], status=status,
                         trace_id=ctx.trace_id, span_id=ctx.span_id,
                     )
@@ -556,9 +635,12 @@ class RemoteStore:
         relists just that kind — the other buckets' deliveries still
         land)."""
         qs = ",".join(f"{k}:{rv}" for k, rv in cursors.items())
+        cell = self._decode_cell()
+        decode_s0 = cell[0]
         res = self._watch_request(
             f"/apis/?watch=1&buckets={qs}&timeoutSeconds={timeout_s}",
         )
+        cell[2] += cell[0] - decode_s0
         out: dict = {}
         for kind, bucket in res["buckets"].items():
             if bucket.get("code") == 410:
@@ -674,8 +756,6 @@ class RemoteStreamWatcher:
         stream_timeout_s: float = 120.0,
     ) -> None:
         import collections
-        import threading
-
         self._store = store
         self._kind = kind
         self._rv = since_rv
@@ -805,8 +885,6 @@ class RemoteStreamWatcher:
                 return
 
     def poll(self) -> list[WatchEvent]:
-        import threading
-
         out: list[WatchEvent] = []
         while self._queue:
             tag, payload = self._queue.popleft()

@@ -796,12 +796,16 @@ def _scheduler_iteration(sched, informers, is_leader=lambda: True,
     def pump() -> int:
         with tracer.span("pump") as sp:
             rpc_s0 = clock.seconds["pump_rpc"]
+            decode_s0 = informers.watch_decode_s
             deliveries = informers.pump()
             if sp is not None:
                 sp.discard = not deliveries
                 sp.attrs.update(
                     deliveries=deliveries,
                     rpc_s=round(clock.seconds["pump_rpc"] - rpc_s0, 6),
+                    # the reply's decode, inside rpc_s; the rest of rpc_s
+                    # is the wait for the apiserver and the read
+                    decode_s=round(informers.watch_decode_s - decode_s0, 6),
                 )
         return deliveries
 
@@ -1008,8 +1012,11 @@ def cmd_scheduler(args) -> int:
             diag = DiagnosticsServer(
                 sched, port=diag_port,
                 # restart visibility: the client's watch-path reconnect
-                # counter rides the scheduler's /metrics page
-                metrics_sources=(store.reconnect_metrics_text,),
+                # counter rides the scheduler's /metrics page, and its
+                # decode clock (what the wire decode costs the loop thread
+                # and the dispatcher's worker)
+                metrics_sources=(store.reconnect_metrics_text,
+                                 store.decode_metrics_text),
             )
         except OSError as e:
             # a second scheduler on the host (HA standby) must not die on

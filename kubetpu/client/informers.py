@@ -294,6 +294,13 @@ class SchedulerInformers:
     def _polling(self):
         return self._clock.phase("pump_rpc")
 
+    @property
+    def watch_decode_s(self) -> float:
+        """Seconds of ``pump_rpc`` so far that decoded ``watch_bulk``
+        replies (``RemoteStore``'s decode clock); 0 over a store in this
+        process, which has no wire."""
+        return getattr(self.store, "watch_decode_s", 0.0)
+
     def _pump_bulk(self) -> int | None:
         """One batched watch poll for every reflector's cursor. None =
         ineligible (caller falls back to per-kind steps)."""

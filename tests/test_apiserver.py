@@ -564,3 +564,127 @@ def test_apply_accepts_v1_manifest_over_rest(server, tmp_path):
     assert out.returncode == 0, out.stdout + out.stderr
     pod, _ = server.store.get(PODS, "default/upstream")
     assert pod is not None and pod.requests_dict()["cpu"] == 100
+
+
+# ------------------------------------------------------- the decode clock
+
+def _decode_page(remote, sched):
+    """The scheduler's /metrics page with the client's sources mounted as
+    ``kubetpu scheduler`` mounts them."""
+    from kubetpu.metrics.textparse import parse_prometheus_text
+    from kubetpu.sched.diagnostics import DiagnosticsServer
+
+    diag = DiagnosticsServer(sched, port=0, metrics_sources=(
+        remote.reconnect_metrics_text, remote.decode_metrics_text))
+    try:
+        return parse_prometheus_text(diag.metrics_text())
+    finally:
+        diag.close()
+
+
+def test_decode_clock_times_a_watch_reply_and_names_the_thread(server):
+    """ISSUE 35: RemoteStore times ``codec.loads`` of every response body,
+    once a response; the watch_bulk replies' part is on the scheduler's
+    page and on the ``pump`` span, inside ``rpc_s``; a response decoded on
+    another thread than the one that built the client is the worker's."""
+    from kubetpu import cli
+
+    WATCH = "scheduler_watch_decode_seconds_total"
+    SECONDS = "apiserver_client_decode_seconds_total"
+    BYTES = "apiserver_client_decoded_bytes_total"
+    remote = RemoteStore(server.url)
+    remote.create(NODES, "n0", make_node("n0", cpu_milli=64000))
+    sched = Scheduler(
+        StoreClient(remote), profile=C.minimal_profile(),
+        dispatcher_workers=0, clock=FakeClock(),
+    )
+    informers = SchedulerInformers(remote, sched)
+    informers.start()
+    once = cli._scheduler_iteration(sched, informers)
+    once()                      # an idle iteration: one empty reply
+    before = _decode_page(remote, sched)
+    for name in (WATCH, SECONDS, BYTES):
+        assert before.value(name, **(
+            {} if name == WATCH else {"caller": "worker"})) is not None
+    assert before.value(SECONDS, caller="worker") == 0      # from scrape one
+    for j in range(200):
+        remote.create(PODS, f"default/p{j}",
+                      make_pod(f"p{j}", cpu_milli=100, labels={"a": "b"}))
+    mid = _decode_page(remote, sched)
+    once()                      # delivers the 200 pods in ONE reply
+    after = _decode_page(remote, sched)
+    assert after.value(WATCH) > mid.value(WATCH) >= before.value(WATCH) > 0
+    assert after.value(BYTES, caller="loop") \
+        > mid.value(BYTES, caller="loop") + 200 * 20
+    # the watch decode is the loop thread's, and only part of what it decodes
+    assert after.value(WATCH) <= after.value(SECONDS, caller="loop")
+    assert informers.watch_decode_s == remote.watch_decode_s \
+        == pytest.approx(after.value(WATCH), abs=1e-5)
+    pumps = [sp for sp in sched.tracer.recent(1000) if sp.name == "pump"]
+    (pump,) = [sp for sp in pumps if sp.attrs["deliveries"] == 200]
+    assert 0 < pump.attrs["decode_s"] <= pump.attrs["rpc_s"]
+    assert pump.attrs["decode_s"] == pytest.approx(
+        after.value(WATCH) - mid.value(WATCH), abs=1e-5)
+
+    # a worker thread's decode lands under caller="worker"
+    got = []
+    worker = threading.Thread(target=lambda: got.append(remote.list(PODS)))
+    worker.start()
+    worker.join()
+    assert len(got[0][0]) == 200
+    final = _decode_page(remote, sched)
+    assert final.value(SECONDS, caller="worker") > 0
+    assert final.value(BYTES, caller="worker") > 200 * 20
+    assert final.value(BYTES, caller="loop") == \
+        after.value(BYTES, caller="loop")
+    assert final.value(WATCH) == after.value(WATCH)
+    sched.close()
+
+
+def test_decode_clock_loses_no_response_under_many_threads(server):
+    """Each thread writes a cell of its own, so no lock is taken a
+    response and no update is lost: the bytes add up exactly."""
+    import sys
+
+    from kubetpu.metrics.textparse import parse_prometheus_text
+
+    BYTES = "apiserver_client_decoded_bytes_total"
+    remote = RemoteStore(server.url)
+    remote.create(NODES, "n0", make_node("n0", cpu_milli=4000))
+
+    def page():
+        return parse_prometheus_text(remote.decode_metrics_text())
+
+    remote.get(NODES, "n0")             # confirms the dialect
+    b0 = page().value(BYTES, caller="loop")
+    remote.get(NODES, "n0")
+    one = page().value(BYTES, caller="loop") - b0
+    assert one > 0
+    threads, gets = 16, 40
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        workers = [
+            threading.Thread(target=lambda: [
+                remote.get(NODES, "n0") for _ in range(gets)])
+            for _ in range(threads)
+        ]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(w.is_alive() for w in workers)
+    assert page().value(BYTES, caller="worker") == threads * gets * one
+    assert len(remote._decode_cells) == threads + 1
+
+
+def test_a_store_in_this_process_has_no_decode_to_time():
+    store = MemStore()
+    sched = Scheduler(StoreClient(store), profile=C.minimal_profile(),
+                      dispatcher_workers=0)
+    informers = SchedulerInformers(store, sched)
+    informers.start()
+    assert informers.watch_decode_s == 0.0
+    sched.close()
