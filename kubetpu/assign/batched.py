@@ -234,23 +234,24 @@ def batched_assign_device(
                     spread_counts.dtype
                 )
         if pa_sums is not None:
-            pa = b.podaffinity
-            r_rows, d = pa_sums.shape
-            safe_choice = jnp.maximum(choice, 0)
-            dcol = pa.node_domain[:, safe_choice].T           # (P, R)
-            valid = (dcol >= 0) & accepted[:, None]
-            inc = jnp.where(valid, pa.update, 0)              # (P, R)
-            flat_ids = jnp.where(
-                valid,
-                jnp.arange(r_rows, dtype=jnp.int32)[None, :] * d
-                + jnp.maximum(dcol, 0),
-                r_rows * d,                                   # drop bucket
-            )
-            flat = jax.ops.segment_sum(
-                inc.reshape(-1), flat_ids.reshape(-1),
-                num_segments=r_rows * d + 1,
-            )[: r_rows * d]
-            pa_sums = pa_sums + flat.reshape(r_rows, d)
+            with jax.named_scope("interpod_counts_update"):
+                pa = b.podaffinity
+                r_rows, d = pa_sums.shape
+                safe_choice = jnp.maximum(choice, 0)
+                dcol = pa.node_domain[:, safe_choice].T       # (P, R)
+                valid = (dcol >= 0) & accepted[:, None]
+                inc = jnp.where(valid, pa.update, 0)          # (P, R)
+                flat_ids = jnp.where(
+                    valid,
+                    jnp.arange(r_rows, dtype=jnp.int32)[None, :] * d
+                    + jnp.maximum(dcol, 0),
+                    r_rows * d,                               # drop bucket
+                )
+                flat = jax.ops.segment_sum(
+                    inc.reshape(-1), flat_ids.reshape(-1),
+                    num_segments=r_rows * d + 1,
+                )[: r_rows * d]
+                pa_sums = pa_sums + flat.reshape(r_rows, d)
         if nom_active is not None:
             idx = b.nominated_pod_idx
             consumed = (idx >= 0) & accepted[jnp.maximum(idx, 0)]

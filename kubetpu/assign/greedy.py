@@ -163,15 +163,16 @@ def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
             # interpodaffinity updateWithPod (filtering.go:75): scatter the
             # assigned pod's increments into each row at the chosen node's
             # domain (no-op when the node lacks the row's topology key).
-            pa = b.podaffinity
-            r = pa_sums.shape[0]
-            dcol = jnp.where(
-                chosen >= 0, pa.node_domain[:, jnp.maximum(chosen, 0)], -1
-            )                                                   # (R,)
-            inc = jnp.where(dcol >= 0, pa.update[i], 0)
-            pa_sums = pa_sums.at[
-                jnp.arange(r), jnp.maximum(dcol, 0)
-            ].add(inc)
+            with jax.named_scope("interpod_counts_update"):
+                pa = b.podaffinity
+                r = pa_sums.shape[0]
+                dcol = jnp.where(
+                    chosen >= 0, pa.node_domain[:, jnp.maximum(chosen, 0)], -1
+                )                                               # (R,)
+                inc = jnp.where(dcol >= 0, pa.update[i], 0)
+                pa_sums = pa_sums.at[
+                    jnp.arange(r), jnp.maximum(dcol, 0)
+                ].add(inc)
         if nom_active is not None:
             # assume deletes the nomination (schedule_one.go:307): once the
             # scan assigns a nomination's own pod, stop charging it

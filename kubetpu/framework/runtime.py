@@ -227,6 +227,26 @@ class EncodedBatch:
     # topology spread constraint (else None): what the scheduler observes
     # and records as the ``encode-spread`` span
     spread_encode: "SpreadEncodeStamp | None" = None
+    # the inter-pod affinity encode of this batch, when the encoder ran and
+    # found a term (else None): observed and recorded as the
+    # ``encode-podaffinity`` span
+    podaffinity_encode: "PodAffinityEncodeStamp | None" = None
+
+
+@dataclass(frozen=True)
+class PodAffinityEncodeStamp:
+    """``encode_pod_affinity`` as ``finalize_batch`` ran it: start and end
+    on ``time.perf_counter`` (the tracer's clock), and what it built."""
+
+    start: float
+    end: float
+    rows: int
+    domains: int
+    #: the batch's real pods with at least one filter slot (incoming
+    #: required affinity or anti-affinity, an existing pod's anti-affinity)
+    filter_pods: int
+    #: the batch's real pods with at least one weighted score slot
+    score_pods: int
 
 
 @dataclass(frozen=True)
@@ -1120,7 +1140,9 @@ def finalize_batch(
         snapshot.pods_with_affinity == 0
         and not any(enc_podaffinity.has_any_affinity(p) for p in pods)
     )
+    pa_stamp = None
     if want_pa:
+        t_pa = time.perf_counter()
         pa = enc_podaffinity.encode_pod_affinity(
             nt, pods,
             hard_pod_affinity_weight=(
@@ -1132,6 +1154,16 @@ def finalize_batch(
             groups=groups_of(),
         )
         if pa is not None:
+            pa_stamp = PodAffinityEncodeStamp(
+                start=t_pa, end=time.perf_counter(),
+                rows=pa.num_rows, domains=pa.max_domains,
+                filter_pods=int((
+                    (pa.fa_rows[:P] >= 0).any(axis=1)
+                    | (pa.ra_rows[:P] >= 0).any(axis=1)
+                    | (pa.ea_rows[:P] >= 0).any(axis=1)
+                ).sum()),
+                score_pods=int((pa.score_rows[:P] >= 0).any(axis=1).sum()),
+            )
             # host numpy leaves — the single batched device_put below ships
             # the whole pytree in one dispatch instead of ~30
             pa_dev = PodAffinityDevice(
@@ -1345,6 +1377,7 @@ def finalize_batch(
         upload_bytes=pod_block_bytes + node_upload,
         resident_bytes=resident_bytes,
         spread_encode=spread_stamp,
+        podaffinity_encode=pa_stamp,
     )
 
 
@@ -1509,11 +1542,12 @@ def filter_components(
     if pa is not None:
         pa_state = pa.base_sums if pa_sums is None else pa_sums
         if p.filter_interpod and pa.has_filter_work:
-            pa_ok = jax.vmap(
-                lambda fr, fs, rr, er: PA.affinity_filter_pod(
-                    pa, pa_state, fr, fs, rr, er
-                )
-            )(pa.fa_rows, pa.fa_self, pa.ra_rows, pa.ea_rows)
+            with jax.named_scope("interpod_filter"):
+                pa_ok = jax.vmap(
+                    lambda fr, fs, rr, er: PA.affinity_filter_pod(
+                        pa, pa_state, fr, fs, rr, er
+                    )
+                )(pa.fa_rows, pa.fa_self, pa.ra_rows, pa.ea_rows)
     return static, fit, ports_ok, spread_ok, pa_ok, sp_counts, pa_state
 
 
@@ -1606,9 +1640,12 @@ def feasible_and_scores(
             )(sp.sig_idx, sp.action, sp.max_skew, sp.ignored, mask)
         total = total + p.w_spread * spread_sc
     if pa is not None and p.w_interpod and pa.has_score_work:
-        pa_sc = jax.vmap(
-            lambda sr, sv, m: PA.affinity_score_pod(pa, pa_state, sr, sv, m)
-        )(pa.score_rows, pa.score_vals, mask)
+        with jax.named_scope("interpod_score"):
+            pa_sc = jax.vmap(
+                lambda sr, sv, m: PA.affinity_score_pod(
+                    pa, pa_state, sr, sv, m
+                )
+            )(pa.score_rows, pa.score_vals, mask)
         total = total + p.w_interpod * pa_sc
     if p.w_dra and b.dra_score_raw is not None:
         # DynamicResources prioritized-list score + DefaultNormalizeScore

@@ -16,7 +16,9 @@ layouts (pkg/scheduler/metrics/metrics.go):
   extension_point="Filter+Score" at the framework level instead; the host
   spread encode is timed as plugin="PodTopologySpread",
   extension_point="PreFilter" (``Scheduler._launch_cycle``), beside
-  spread_constrained_pods_total and spread_soft_constrained_pods_total
+  spread_constrained_pods_total and spread_soft_constrained_pods_total;
+  the host inter-pod affinity encode as plugin="InterPodAffinity",
+  extension_point="PreFilter", beside podaffinity_pods_total{work}
 - schedule_attempts_total{result, profile}, preemption_attempts_total,
   preemption_victims (:267 ExponentialBuckets(1, 2, 7)), pending_pods{queue}
 """
@@ -55,6 +57,10 @@ ENGINES = (
 #: what the loop's next call did with a cycle dispatched ahead of it: the
 #: ONLY legal values of {result} on scheduler_pipeline_cycles_total.
 PIPELINE_RESULTS = ("applied", "replayed")
+
+#: which inter-pod affinity kernel had work for a pod: the ONLY legal values
+#: of {work} on scheduler_podaffinity_pods_total.
+PODAFFINITY_WORK = ("filter", "score")
 
 
 def window_quantile_ms(
@@ -132,6 +138,21 @@ class SchedulerMetricsRegistry:
             "the soft spread score ran for them in every step of the assign "
             "scan and in the explain kernel.",
         )
+        self.podaffinity_pods = r.counter(
+            "scheduler_podaffinity_pods_total",
+            "Pods of the scheduling cycles for which an inter-pod affinity "
+            "kernel had work, by the kernel: filter (an incoming required "
+            "affinity or anti-affinity term, or an existing pod's "
+            "anti-affinity that matches the pod) or score (a weighted count "
+            "row: the pod's preferred terms, or an existing pod's preferred "
+            "or required term that matches it). A pod may count under both; "
+            "a cycle without an affinity term adds nothing.",
+            labels=("work",),
+            declared={"work": PODAFFINITY_WORK},
+        )
+        for work in PODAFFINITY_WORK:
+            # both on the first scrape, at zero: a delta meets no gap
+            self.podaffinity_pods.labels(work)
         self.pipeline_cycles = r.counter(
             "scheduler_pipeline_cycles_total",
             "Scheduling cycles whose device program was dispatched ahead "

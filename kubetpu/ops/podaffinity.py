@@ -7,8 +7,18 @@ Reference checks (pkg/scheduler/framework/plugins/interpodaffinity/):
   count > 0 where all term keys exist; self-affinity escape when the global
   map is empty and the pod matches its own terms, filtering.go:414).
 - Score (scoring.go:240): Σ over topology maps at the node's values, then
-  min-max normalize over filtered nodes (scoring.go:258):
-  ``int64(100 · (s − min) / (max − min))``, 0 when max == min.
+  min-max normalize over filtered nodes (scoring.go:258), 0 when max == min.
+  As the code computes it: ``int64(float64(100 · (s − min)) / float64(max −
+  min))``, the multiplication FIRST and in integers, then one float64
+  division, then truncation. ``benchmark/reference/oracle.py``
+  ``interpod_scores`` computes the same (``int(MAX * (raw − mn) / (mx −
+  mn))``), and the two are compared exactly
+  (``tests/test_preferredaffinity_served.py``, on the CPU in tier-1 and on
+  the chip by hand). Upstream's order is an open question until
+  ``/root/reference`` is on a machine: if scoring.go divides first
+  (``float64(MaxNodeScore) * (float64(s − min) / float64(max − min))``) the
+  two orders differ by one at 8 of the 20,300 pairs 0 ≤ s ≤ d ≤ 200 (29/50,
+  29/100, 57/100, 58/100, 87/150, 58/200, 114/200, 116/200; PERF.md 7).
 
 All counts live in the carried ``sums (R, D)`` state (interned count rows ×
 topology domains — see state.podaffinity); the kernels are pure gathers.

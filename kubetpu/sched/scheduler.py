@@ -1383,6 +1383,21 @@ class Scheduler:
                         constrained_pods=stamp.constrained_pods,
                         soft_pods=stamp.soft_pods,
                     )
+                stamp = batch.podaffinity_encode
+                if stamp is not None:
+                    # the affinity path's host time, named: a cycle without
+                    # an affinity term observes nothing
+                    prom.plugin_execution_duration.labels(
+                        C.INTER_POD_AFFINITY, "PreFilter", "Success"
+                    ).observe(stamp.end - stamp.start)
+                    self.tracer.record(
+                        "encode-podaffinity", stamp.start, stamp.end,
+                        parent_id=self.tracer.current_id, off_stack=False,
+                        cycle=cycle_id, rows=stamp.rows,
+                        domains=stamp.domains,
+                        filter_pods=stamp.filter_pods,
+                        score_pods=stamp.score_pods,
+                    )
             # the host encode builds per-pod state ahead of filtering —
             # the PreFilter role in the reference's extension-point map
             encode_s = time.perf_counter() - t_enc + pre_encode_s
@@ -1642,6 +1657,13 @@ class Scheduler:
             )
             prom.spread_soft_constrained_pods.inc(
                 batch.spread_encode.soft_pods
+            )
+        if batch.podaffinity_encode is not None:
+            prom.podaffinity_pods.labels("filter").inc(
+                batch.podaffinity_encode.filter_pods
+            )
+            prom.podaffinity_pods.labels("score").inc(
+                batch.podaffinity_encode.score_pods
             )
 
         try:

@@ -59,7 +59,9 @@ def test_the_entry_is_the_api_plane_s_and_lists_three_cells():
                 if m["name"] == "pump_decode_share"]
     assert {k: entry[k] for k in pump_decode_share.META} == \
         pump_decode_share.META
-    assert entry["better"] == "lower" and entry["workloads"] == CELLS
+    # membership, not the whole list: a later PR appends its cell (PR 36 did)
+    assert entry["better"] == "lower"
+    assert set(CELLS) <= set(entry["workloads"])
     assert layer_reader("pump_decode_share") is pump_decode_share
     for name in CELLS:
         assert entry in Cell(manifest, name).per_layer
