@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ..framework import runtime as rt
+from ..ops import podaffinity as PA
 
 
 def _pod_view(b: rt.DeviceBatch, i) -> rt.DeviceBatch:
@@ -105,27 +106,16 @@ def _spread_view(sp, i):
     )
 
 
-@partial(jax.jit, static_argnames=("params",))
-def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
-    """Run the greedy scan. Returns ``(assignments (P,) int32 node index or
-    -1, final_state)`` where final_state is the post-batch
-    ``(requested, nonzero_requested, pod_count)`` — the cache applies it as
-    the batch's assume step.
-
-    Buffer-donation note: the scan CARRY is double-buffered by XLA itself
-    (loop state aliases in place inside the compiled program), so the hot
-    per-step node-state updates never copy. The INPUT node block must NOT
-    be donated here: in pipeline mode those buffers are the device-resident
-    cluster state (runtime.ResidentNodeState) reused by the next cycle's
-    delta scatter, and the post-cycle preemption PostFilter re-reads them
-    through the cycle context. Donation of the node-state buffers happens
-    at the one seam where they are provably unreferenced — the resident
-    scatter (runtime._scatter_node_rows)."""
+def greedy_scan(b: rt.DeviceBatch, params: rt.ScoreParams):
+    """The scan, not jitted: ``(assignments, final_state, pa_counts)`` where
+    ``pa_counts`` is the carried ``ops.podaffinity.NodeCounts`` (None without
+    inter-pod affinity): ``final_state[5]`` as the steps read it. See
+    ``greedy_assign_device``."""
 
     n = b.alloc.shape[0]
     node_iota = jnp.arange(n, dtype=jnp.int32)
 
-    def step(state, i):
+    def step(state, pa_counts, i):
         (requested, nonzero, pod_count, node_ports, spread_counts, pa_sums,
          nom_active) = state
         view = _pod_view(b, i)
@@ -134,7 +124,7 @@ def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
             requested=requested, nonzero_requested=nonzero,
             pod_count=pod_count, node_ports=node_ports,
             spread_counts=spread_counts, pa_sums=pa_sums,
-            nominated_active=nom_active,
+            nominated_active=nom_active, pa_counts=pa_counts,
         )
         mask, score = mask[0], score[0]
         feasible = jnp.any(mask)
@@ -163,8 +153,8 @@ def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
             # interpodaffinity updateWithPod (filtering.go:75): scatter the
             # assigned pod's increments into each row at the chosen node's
             # domain (no-op when the node lacks the row's topology key).
+            pa = b.podaffinity
             with jax.named_scope("interpod_counts_update"):
-                pa = b.podaffinity
                 r = pa_sums.shape[0]
                 dcol = jnp.where(
                     chosen >= 0, pa.node_domain[:, jnp.maximum(chosen, 0)], -1
@@ -173,6 +163,7 @@ def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
                 pa_sums = pa_sums.at[
                     jnp.arange(r), jnp.maximum(dcol, 0)
                 ].add(inc)
+            pa_counts = PA.node_counts_add(pa, pa_counts, dcol, inc)
         if nom_active is not None:
             # assume deletes the nomination (schedule_one.go:307): once the
             # scan assigns a nomination's own pod, stop charging it
@@ -182,7 +173,7 @@ def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
         return (
             requested, nonzero, pod_count, node_ports, spread_counts, pa_sums,
             nom_active,
-        ), chosen
+        ), pa_counts, chosen
 
     p = b.requests.shape[0]
     init = (
@@ -192,6 +183,12 @@ def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
         None if b.nominated_pod_idx is None
         else jnp.ones(b.nominated_pod_idx.shape[0], dtype=bool),
     )
+    # the counts as the kernels read them, beside the (R, D) sums: gathered
+    # once here, then kept true step by step with no gather
+    pa_counts = (
+        None if b.podaffinity is None
+        else PA.node_counts(b.podaffinity, b.podaffinity.base_sums)
+    )
     # padded pods are infeasible on every node (``pod_valid`` masks them):
     # their steps would choose -1 and leave the state as it is
     stop = jnp.max(jnp.where(
@@ -199,13 +196,34 @@ def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
     ))
 
     def body(i, carry):
-        state, assignments = carry
-        state, chosen = step(state, i)
-        return state, assignments.at[i].set(chosen)
+        state, pa_counts, assignments = carry
+        state, pa_counts, chosen = step(state, pa_counts, i)
+        return state, pa_counts, assignments.at[i].set(chosen)
 
-    final_state, assignments = jax.lax.fori_loop(
-        0, stop, body, (init, jnp.full(p, -1, dtype=jnp.int32))
+    final_state, pa_counts, assignments = jax.lax.fori_loop(
+        0, stop, body, (init, pa_counts, jnp.full(p, -1, dtype=jnp.int32))
     )
+    return assignments, final_state, pa_counts
+
+
+@partial(jax.jit, static_argnames=("params",))
+def greedy_assign_device(b: rt.DeviceBatch, params: rt.ScoreParams):
+    """Run the greedy scan. Returns ``(assignments (P,) int32 node index or
+    -1, final_state)`` where final_state is the post-batch
+    ``(requested, nonzero_requested, pod_count)`` — the cache applies it as
+    the batch's assume step.
+
+    Buffer-donation note: the scan CARRY is double-buffered by XLA itself
+    (loop state aliases in place inside the compiled program), so the hot
+    per-step node-state updates never copy. The INPUT node block must NOT
+    be donated here: in pipeline mode those buffers are the device-resident
+    cluster state (runtime.ResidentNodeState) reused by the next cycle's
+    delta scatter, and the post-cycle preemption PostFilter re-reads them
+    through the cycle context. Donation of the node-state buffers happens
+    at the one seam where they are provably unreferenced — the resident
+    scatter (runtime._scatter_node_rows)."""
+
+    assignments, final_state, _ = greedy_scan(b, params)
     return assignments, final_state
 
 

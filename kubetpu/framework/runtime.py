@@ -242,6 +242,10 @@ class PodAffinityEncodeStamp:
     end: float
     rows: int
     domains: int
+    #: CA + CR + CE + CS, the row-id slots a pod hands the kernels: with
+    #: ``rows`` and the batch's bucket, what ``ops.podaffinity.table_pays``
+    #: decides from
+    slots: int
     #: the batch's real pods with at least one filter slot (incoming
     #: required affinity or anti-affinity, an existing pod's anti-affinity)
     filter_pods: int
@@ -1157,6 +1161,7 @@ def finalize_batch(
             pa_stamp = PodAffinityEncodeStamp(
                 start=t_pa, end=time.perf_counter(),
                 rows=pa.num_rows, domains=pa.max_domains,
+                slots=PA.kernel_slots(pa),
                 filter_pods=int((
                     (pa.fa_rows[:P] >= 0).any(axis=1)
                     | (pa.ra_rows[:P] >= 0).any(axis=1)
@@ -1449,6 +1454,7 @@ def filter_components(
     spread_counts: jnp.ndarray | None = None,
     pa_sums: jnp.ndarray | None = None,
     nominated_active: jnp.ndarray | None = None,
+    pa_counts: "PA.NodeCounts | None" = None,
 ):
     """Per-plugin Filter masks, un-ANDed — the split preemption needs:
     failures of ``static`` / ``spread_ok`` / ``pa_ok`` are
@@ -1459,7 +1465,9 @@ def filter_components(
 
     Returns ``(static, fit, ports_ok, spread_ok, pa_ok, sp_counts,
     pa_state)``; mask entries are None when the plugin is disabled or has no
-    work.
+    work, and ``pa_state`` is what the affinity masks were read from, for
+    the score: the (R, D) sums or, where ``ops.podaffinity.table_pays`` or
+    the caller handed one, their ``NodeCounts``.
     """
     req = b.requested if requested is None else requested
     pc = b.pod_count if pod_count is None else pod_count
@@ -1541,6 +1549,11 @@ def filter_components(
     pa_ok = None
     if pa is not None:
         pa_state = pa.base_sums if pa_sums is None else pa_sums
+        if pa_counts is not None:
+            pa_state = pa_counts
+        elif PA.table_pays(pa):
+            # built here ONCE for the filter and the score of every pod
+            pa_state = PA.node_counts(pa, pa_state)
         if p.filter_interpod and pa.has_filter_work:
             with jax.named_scope("interpod_filter"):
                 pa_ok = jax.vmap(
@@ -1561,6 +1574,7 @@ def feasible_and_scores(
     spread_counts: jnp.ndarray | None = None,
     pa_sums: jnp.ndarray | None = None,
     nominated_active: jnp.ndarray | None = None,
+    pa_counts: "PA.NodeCounts | None" = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The full Filter + Score composition for a batch against ONE snapshot
     state (no inter-pod capacity coupling — that is the assignment engine's
@@ -1569,6 +1583,9 @@ def feasible_and_scores(
     Optional ``requested``/``nonzero_requested``/``pod_count`` override the
     batch's node usage — the greedy scan threads its running state through
     here so this one function is both the one-shot and the stepped semantics.
+    ``pa_counts`` is ``pa_sums`` as ``ops.podaffinity.NodeCounts``, from a
+    caller that keeps one (the scan); without it ``filter_components``
+    builds one where the batch is wide enough to pay for it.
     """
     req = b.requested if requested is None else requested
     nz = b.nonzero_requested if nonzero_requested is None else nonzero_requested
@@ -1583,6 +1600,7 @@ def feasible_and_scores(
             b, p, requested=requested, pod_count=pod_count,
             node_ports=node_ports, spread_counts=spread_counts,
             pa_sums=pa_sums, nominated_active=nominated_active,
+            pa_counts=pa_counts,
         )
     )
     mask = static

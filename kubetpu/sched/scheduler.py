@@ -1394,7 +1394,7 @@ class Scheduler:
                         "encode-podaffinity", stamp.start, stamp.end,
                         parent_id=self.tracer.current_id, off_stack=False,
                         cycle=cycle_id, rows=stamp.rows,
-                        domains=stamp.domains,
+                        domains=stamp.domains, slots=stamp.slots,
                         filter_pods=stamp.filter_pods,
                         score_pods=stamp.score_pods,
                     )

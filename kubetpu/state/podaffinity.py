@@ -171,9 +171,11 @@ class PodAffinityTensors:
     has_key: np.ndarray       # (R, N) bool
     base_sums: np.ndarray     # (R, D) int64
     update: np.ndarray        # (P, R) int64 — increment when pod p is assigned
-    # filtering — per-pod row-id slots (−1 unused) so kernels touch only the
-    # rows a pod actually uses, not all R (the dense (R, N) gather per scan
-    # step was the dominant cost at 5k nodes)
+    # filtering — per-pod row-id slots (−1 unused): a kernel reads only the
+    # rows a pod actually uses, not all R. The counts themselves come from
+    # ``ops.podaffinity.NodeCounts``, an (R, N) table gathered ONCE per
+    # evaluation and carried through the scan: element gathers are what
+    # costs at 5k nodes, per scan step or per (pod, slot, node) alike
     fa_rows: np.ndarray       # (P, CA) int32 row id, -1 unused
     fa_self: np.ndarray       # (P,) bool — pod matches all its own aff terms
     ra_rows: np.ndarray       # (P, CR) int32 row id, -1 unused

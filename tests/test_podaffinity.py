@@ -11,9 +11,11 @@ pytest.importorskip("jax")
 from kubetpu.api import types as t
 from kubetpu.api.wrappers import make_node, make_pod, pod_affinity_term
 from kubetpu.assign import greedy_assign
+from kubetpu.assign.greedy import greedy_scan
 from kubetpu.framework import config as C
 from kubetpu.framework import encode_batch, score_params
 from kubetpu.framework import runtime as rt
+from kubetpu.ops import podaffinity as PA
 from kubetpu.state import Cache
 
 from . import oracle
@@ -243,3 +245,243 @@ def test_affinity_self_escape_then_colocate():
     # cpu 600/1000 → one pod per node; same zone has exactly 2 nodes
     assert zone_of[got[1]] == z0
     assert got[2] is None or zone_of[got[2]] == z0
+
+
+# ------------------------------------------- the per-row node table (PR 37)
+
+def _term(key, app, **kw):
+    return pod_affinity_term(key, match_labels={"app": app}, **kw)
+
+
+def _preferred(weight, key, app):
+    return t.WeightedPodAffinityTerm(weight, _term(key, app))
+
+
+def _pending_for(feature, key, j):
+    """Pending pod j of a case: what ``feature`` asks for on top of a
+    required affinity to ``web`` and a preferred one to ``db``."""
+    labels = {"app": APPS[j % 3]}
+    aff = t.PodAffinity(required=(_term(key, "web"),),
+                        preferred=(_preferred(7, key, "db"),))
+    anti = None
+    if feature == "unused_slots":
+        # slots as wide as the widest pod; every third pod uses none, the
+        # others one or two of them
+        if j % 3 == 0:
+            return make_pod(f"p{j}", cpu_milli=100, labels=labels)
+        if j % 3 == 1:
+            aff = t.PodAffinity(required=(_term(key, "web"), _term(key, "db")))
+            anti = t.PodAffinity(required=(_term(key, "cache"),))
+    elif feature == "self_escape":
+        # no pod anywhere is ``solo``: the pods labelled solo pass by the
+        # escape (row_total == 0 and fa_self), the others fail everywhere
+        labels = {"app": "solo" if j % 2 == 0 else "web"}
+        aff = t.PodAffinity(required=(_term(key, "solo"),))
+    elif feature == "negative":
+        aff = t.PodAffinity(preferred=(_preferred(3, key, "web"),))
+        anti = t.PodAffinity(preferred=(
+            _preferred(60 + j, key, "db"), _preferred(5, HOST, "cache")))
+    return make_pod(
+        f"p{j}", cpu_milli=100, labels=labels,
+        affinity=t.Affinity(pod_affinity=aff, pod_anti_affinity=anti))
+
+
+def _table_case(key, feature, wide, seed=37):
+    """A seeded cluster on one side of the shape rule. ``wide``: twelve
+    pending pods over a handful of rows (R ≪ P × slots); else ONE pending
+    pod beside existing pods that own many rows it has no slot for (R > P ×
+    slots). With ``missing_key`` every fourth node lacks the topology key."""
+    rng = np.random.default_rng(seed)
+    cache = Cache()
+    names = []
+    for i in range(12):
+        labels = {HOST: f"n{i}", ZONE: f"z{i % 3}"}
+        if feature == "missing_key" and i % 4 == 3:
+            del labels[key]
+        names.append(f"n{i}")
+        cache.add_node(make_node(f"n{i}", cpu_milli=4000, labels=labels))
+    for j in range(30):
+        own = None
+        if not wide and j < 14:
+            # a row of its own for each: a preferred term towards an app no
+            # pending pod carries
+            own = t.Affinity(pod_affinity=t.PodAffinity(preferred=(
+                _preferred(j + 1, (ZONE, HOST)[j % 2], f"other-{j}"),)))
+        elif j % 5 == 0:
+            # existing pods' own terms: anti-affinity (EA rows) and a
+            # required affinity (SCH rows, scored by the hard weight)
+            own = t.Affinity(
+                pod_anti_affinity=t.PodAffinity(
+                    required=(_term(HOST, "cache"),)),
+                pod_affinity=t.PodAffinity(required=(_term(key, "db"),)))
+        cache.add_pod(make_pod(
+            f"e{j}", cpu_milli=int(rng.integers(0, 400)),
+            labels={"app": str(rng.choice(APPS))}, affinity=own,
+            node_name=names[int(rng.integers(0, 12))]))
+    pending = [_pending_for(feature, key, j) for j in range(12 if wide else 1)]
+    return cache.update_snapshot(), pending
+
+
+@pytest.mark.parametrize("wide", [True, False],
+                         ids=["rows_below_pod_slots", "rows_above_pod_slots"])
+@pytest.mark.parametrize(
+    "feature", ["missing_key", "unused_slots", "self_escape", "negative"])
+@pytest.mark.parametrize("key", [HOST, ZONE], ids=["hostname", "zone"])
+def test_table_path_equals_per_pod_path_and_oracle(key, feature, wide):
+    """The kernels with ``NodeCounts`` handed in, the kernels gathering per
+    (pod, slot, node) from the (R, D) sums, and the scalar oracle agree on
+    every node; the path ``filter_score_batch`` takes by itself is the one
+    the shapes call for."""
+    import jax
+
+    snap, pending = _table_case(key, feature, wide)
+    profile = affinity_profile()
+    batch = encode_batch(snap, pending, profile, pad=False)
+    params = score_params(profile, batch.resource_names)
+    pa = batch.device.podaffinity
+    assert PA.table_pays(pa) == wide
+    assert batch.podaffinity_encode.slots == PA.kernel_slots(pa)
+    comps = rt.filter_components(batch.device, params)
+    assert isinstance(comps[6], PA.NodeCounts) == wide
+    if feature == "missing_key":
+        assert (np.asarray(pa.node_domain) < 0).any()
+    if feature == "unused_slots":
+        assert (np.asarray(pa.fa_rows) < 0).any()
+        assert (np.asarray(pa.fa_rows) >= 0).any() == wide
+    if feature == "negative":
+        assert (np.asarray(pa.score_vals) < 0).any()
+
+    mask, total = rt.filter_score_batch(batch.device, params)
+    mask, total = np.asarray(mask), np.asarray(total)
+
+    def kernels(sums):
+        ok = jax.vmap(lambda fr, fs, rr, er: PA.affinity_filter_pod(
+            pa, sums, fr, fs, rr, er))(
+                pa.fa_rows, pa.fa_self, pa.ra_rows, pa.ea_rows)
+        sc = jax.vmap(lambda sr, sv, m: PA.affinity_score_pod(
+            pa, sums, sr, sv, m))(pa.score_rows, pa.score_vals, mask)
+        return np.asarray(ok), np.asarray(sc)
+
+    table_ok, table_sc = kernels(PA.node_counts(pa, pa.base_sums))
+    gather_ok, gather_sc = kernels(pa.base_sums)
+    assert (table_ok == gather_ok).all()
+    assert (table_sc == gather_sc).all()
+
+    infos = snap.node_infos()
+    n = len(infos)
+    escaped = 0
+    for i, pod in enumerate(pending):
+        want_ok = [oracle.interpod_filter(pod, infos, info) for info in infos]
+        assert table_ok[i, :n].tolist() == want_ok, pod.name
+        feas = [oracle.fits(pod, info) and ok
+                for info, ok in zip(infos, want_ok)]
+        assert mask[i, :n].tolist() == feas, pod.name
+        want_ip = oracle.interpod_scores(pod, infos, feas)
+        assert table_sc[i, :n].tolist() == want_ip, pod.name
+        for j, info in enumerate(infos):
+            want = oracle.least_allocated(
+                pod, info, [(t.CPU, 1), (t.MEMORY, 1)]) + 2 * want_ip[j]
+            assert total[i, j] == want, (pod.name, info.node.name)
+        escaped += pod.labels_dict().get("app") == "solo" and any(want_ok)
+    if feature == "self_escape":
+        # the escape was taken, and refused to the pod that matches no term
+        # of its own
+        assert escaped == (len(pending) + 1) // 2
+        assert not mask[1:2].any()
+
+
+@pytest.mark.parametrize("key", [HOST, ZONE], ids=["hostname", "zone"])
+def test_the_scan_s_carried_table_is_the_table_of_its_carried_sums(key):
+    """After each of K placements the ``NodeCounts`` the scan carries equals
+    the one rebuilt from the (R, D) sums it carries beside it
+    (``final_state[5]``): the compare-and-add of ``node_counts_add`` is the
+    scatter, seen from the nodes."""
+    import jax
+
+    snap, pending = _table_case(key, "missing_key", wide=True)
+    profile = affinity_profile()
+    batch = encode_batch(snap, pending, profile, pad=False)
+    params = score_params(profile, batch.resource_names)
+    b = batch.device
+    pa = b.podaffinity
+    scan = jax.jit(greedy_scan, static_argnames=("params",))
+    before = PA.node_counts(pa, pa.base_sums)
+    moved = 0
+    for k in range(1, len(pending) + 1):
+        first_k = np.arange(len(pending)) < k
+        assignments, final_state, counts = scan(
+            dataclasses.replace(b, pod_valid=np.asarray(b.pod_valid) & first_k),
+            params)
+        want = PA.node_counts(pa, final_state[5])
+        assert (np.asarray(counts.at_node) == np.asarray(want.at_node)).all()
+        assert (np.asarray(counts.row_total)
+                == np.asarray(want.row_total)).all()
+        assert (np.asarray(assignments)[k:] == -1).all()
+        moved += not (np.asarray(counts.at_node)
+                      == np.asarray(before.at_node)).all()
+        before = counts
+    # placements moved the table (a placement that moved nothing would make
+    # the equality above hold for free)
+    assert moved >= 3
+    assert (np.asarray(counts.at_node)[np.asarray(pa.node_domain) < 0]
+            == 0).all()
+
+
+def test_a_one_pod_view_gathers_from_the_sums_it_is_handed():
+    """Preemption's re-check: ``filter_components`` for one pod with the
+    scan's ``final_state[5]`` and no carried table gathers its own slots
+    from THOSE sums (R > P × slots: no table is built): its masks are the
+    ones the scan's own table gives."""
+    import jax
+    from kubetpu.assign.greedy import _pod_view
+
+    # many rows no pending pod has a slot for, and six pods to place
+    snap, _ = _table_case(ZONE, "self_escape", wide=False)
+    pending = [_pending_for("self_escape", ZONE, j) for j in range(6)]
+    profile = affinity_profile()
+    batch = encode_batch(snap, pending, profile, pad=False)
+    params = score_params(profile, batch.resource_names)
+    b = batch.device
+    _, final_state, counts = jax.jit(
+        greedy_scan, static_argnames=("params",))(b, params)
+    assert not (np.asarray(final_state[5])
+                == np.asarray(b.podaffinity.base_sums)).all()
+    differs = 0
+    for i in range(len(pending)):
+        view = _pod_view(b, i)
+        assert not PA.table_pays(view.podaffinity)
+        from_sums = rt.filter_components(view, params, pa_sums=final_state[5])
+        from_table = rt.filter_components(view, params, pa_counts=counts)
+        from_base = rt.filter_components(view, params)
+        assert from_sums[6] is final_state[5] and from_table[6] is counts
+        assert (np.asarray(from_sums[4]) == np.asarray(from_table[4])).all()
+        differs += not (np.asarray(from_sums[4])
+                        == np.asarray(from_base[4])).all()
+    # the placements changed some pod's affinity mask, so the equality above
+    # is not the base state's
+    assert differs
+
+
+def test_the_encode_span_says_what_the_shape_rule_saw():
+    """``encode-podaffinity`` carries ``slots`` beside ``rows``: with the
+    batch's bucket, what ``table_pays`` decides from."""
+    from kubetpu.sched import Scheduler
+
+    from .test_scheduler import FakeClient
+
+    snap, pending = _table_case(ZONE, "unused_slots", wide=True)
+    s = Scheduler(client=FakeClient(), profile=affinity_profile(),
+                  dispatcher_workers=0)
+    for info in snap.node_infos():
+        s.on_node_add(info.node)
+        for pod in info.pods.values():
+            s.on_pod_add(pod)
+    for j, pod in enumerate(pending):
+        s.on_pod_add(dataclasses.replace(pod, creation_index=j))
+    s.schedule_batch()
+    [span] = [sp for sp in s.tracer.drain()
+              if sp.name == "encode-podaffinity"]
+    batch = encode_batch(snap, pending, affinity_profile())
+    assert span.attrs["slots"] == PA.kernel_slots(batch.device.podaffinity)
+    assert span.attrs["slots"] >= 4
+    assert span.attrs["rows"] == batch.device.podaffinity.node_domain.shape[0]

@@ -1,6 +1,8 @@
 """The share of the greedy assign program's device time spent under the
 inter-pod affinity path's named scopes (``interpod_filter``,
-``interpod_score``, ``interpod_counts_update``), at the size of
+``interpod_score``, ``interpod_counts_update`` and, since PR 37,
+``interpod_node_counts``: the per-row node table's build before the loop and
+its compare-and-add in every step), at the size of
 ``preferredaffinity-5k.saturate``: its 5000 nodes, ``existing`` bound pods of
 its template packed 40 a node (alternately of ``sched-0`` and ``sched-1``),
 ``real`` pending pods of the template padded to 1024.
@@ -30,7 +32,8 @@ from kubetpu.framework import config as C  # noqa: E402
 from kubetpu.framework import runtime as rt  # noqa: E402
 from kubetpu.state.snapshot import Cache  # noqa: E402
 
-SCOPES = ("interpod_filter", "interpod_counts_update", "interpod_score")
+SCOPES = ("interpod_filter", "interpod_counts_update", "interpod_score",
+          "interpod_node_counts")
 PER_NODE = 40
 config = Cell(load_manifest(), "preferredaffinity-5k.saturate").config
 args = [int(a) for a in sys.argv[1:]]
@@ -53,6 +56,7 @@ stamp = batch.podaffinity_encode
 print(json.dumps({"encode_s": time.perf_counter() - t0,
                   "podaffinity_encode_s": stamp.end - stamp.start,
                   "rows": stamp.rows, "domains": stamp.domains,
+                  "slots": stamp.slots,
                   "device": kubetpu.device_stamp()}), flush=True)
 scope_share.report(batch, rt.score_params(profile, batch.resource_names),
                    SCOPES, real, existing)
