@@ -26,6 +26,7 @@ layouts (pkg/scheduler/metrics/metrics.go):
 from __future__ import annotations
 
 import math
+import time
 
 from ..tracing import LOOP_PHASES
 from .registry import Histogram, Registry, exponential_buckets
@@ -311,20 +312,65 @@ class SchedulerMetricsRegistry:
         for phase in LOOP_PHASES:
             self.loop_phase_seconds.labels(phase)
             self.loop_phase_entries.labels(phase)
+        # --- who ran: the CPU clocks beside the wall clocks ---------------
+        # one thread's CPU time is what it spent ON a core; its wall less
+        # its CPU it spent waiting: for the GIL, for a core, for a socket,
+        # for the chip. Series appear with their first reading (the loop's
+        # nine at the first scrape); a platform with no per-thread CPU
+        # clock leaves the CPU families without series
+        self.loop_phase_cpu_seconds = r.counter(
+            "scheduler_loop_phase_cpu_seconds_total",
+            "CPU seconds of the scheduler's loop thread by phase, beside "
+            "scheduler_loop_phase_seconds_total: at most the wall in every "
+            "phase, next to nothing in sleep, and the phases sum to the "
+            "thread's CPU time.",
+            labels=("phase",),
+            declared={"phase": LOOP_PHASES},
+        )
+        self.api_dispatcher_worker_seconds = r.counter(
+            "scheduler_api_dispatcher_worker_seconds_total",
+            "Wall seconds the API dispatcher's worker threads spent "
+            "executing what they took from the queue, by call type; with "
+            "several workers it can pass the elapsed time. Calls executed "
+            "inline on the loop thread are in the loop's phases instead.",
+            labels=("call_type",),
+        )
+        self.api_dispatcher_worker_cpu_seconds = r.counter(
+            "scheduler_api_dispatcher_worker_cpu_seconds_total",
+            "CPU seconds of the same threads over the same work: Python "
+            "run under the loop's GIL.",
+            labels=("call_type",),
+        )
+        self.process_cpu_seconds = r.counter(
+            "process_cpu_seconds_total",
+            "Total user and system CPU time spent in seconds, every thread "
+            "of the process: beyond the loop, the dispatcher's workers and "
+            "the diagnostics requests it holds the threads no Python clock "
+            "reaches (XLA's and the accelerator runtime's pools).",
+        )
 
-    def set_dispatcher_stats(self, stats: dict) -> None:
+    def set_dispatcher_stats(self, stats: dict, worker_clock=None) -> None:
         for event, value in stats.items():
             self.api_dispatcher_calls.labels(event).set(value)
+        for call_type, (wall, cpu) in (worker_clock or {}).items():
+            self.api_dispatcher_worker_seconds.labels(call_type).set_total(
+                wall)
+            if cpu is not None:
+                self.api_dispatcher_worker_cpu_seconds.labels(
+                    call_type).set_total(cpu)
 
-    def set_loop_clock(self, snapshot: tuple) -> None:
+    def set_loop_clock(self, snapshot: tuple, cpu_seconds=None) -> None:
         seconds, entries, iterations = snapshot
         for phase, value in seconds.items():
             self.loop_phase_seconds.labels(phase).set_total(value)
+        for phase, value in (cpu_seconds or {}).items():
+            self.loop_phase_cpu_seconds.labels(phase).set_total(value)
         for phase, value in entries.items():
             self.loop_phase_entries.labels(phase).set_total(value)
         self.loop_iterations.set_total(iterations)
 
     def expose(self) -> str:
+        self.process_cpu_seconds.set_total(time.process_time())
         return self.registry.expose()
 
     # --- convenience for the perf harness ---------------------------------

@@ -26,6 +26,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Protocol
 
 from ..api import types as t
+from ..tracing import thread_cpu
 
 
 class CallSkipped(Exception):
@@ -262,6 +263,9 @@ class APIDispatcher:
         #                            (bulk partial-409s land here per op)
         self._batches = 0          # bulk RPCs issued
         self._batched_calls = 0    # calls that rode a bulk RPC
+        # call_type -> [wall, CPU] seconds the WORKERS spent executing what
+        # they took from the queue (worker_clock)
+        self._worker_s: dict[str, list[float]] = {}
         self._closed = False
         if workers > 0:
             for i in range(workers):
@@ -420,6 +424,7 @@ class APIDispatcher:
             ready.append(call)
         if len(ready) >= 2:
             t_bulk = _time.perf_counter()
+            cpu_bulk = thread_cpu() if thread_cpu is not None else None
             for call in ready:
                 # the bulk RPC IS these calls' API phase: stamp its start
                 # (the per-call fallback restamps nothing — first write wins)
@@ -441,10 +446,15 @@ class APIDispatcher:
                     # one span for the whole micro-batch's API phase (the
                     # per-op fallbacks below record their own); pod
                     # attribution rides as a capped id list like the
-                    # apiserver's bulk request span
+                    # apiserver's bulk request span. cpu_s: of that phase,
+                    # what this thread ran (the encode and decode of two
+                    # bulk requests); the rest it waited for the apiserver
+                    # or for the GIL
+                    cpu = ({} if cpu_bulk is None else
+                           {"cpu_s": round(thread_cpu() - cpu_bulk, 6)})
                     tr.record(
                         f"api.{call_type}.bulk", start=t_bulk,
-                        end=_time.perf_counter(), n=len(ready),
+                        end=_time.perf_counter(), n=len(ready), **cpu,
                         pod_traces=[
                             tid for c in ready
                             if (tid := getattr(
@@ -482,12 +492,24 @@ class APIDispatcher:
             if item is _CLOSE:
                 self._q.task_done()  # keep join() balanced after close
                 return
+            # one wall pair and one CPU pair around each item: this thread
+            # runs under the loop's GIL, and nothing else times it
+            t0 = _time.perf_counter()
+            cpu0 = thread_cpu() if thread_cpu is not None else 0.0
             if isinstance(item, _BatchJob):
-                self._execute_batch(item.call_type, item.calls)
+                call_type = item.call_type
+                self._execute_batch(call_type, item.calls)
             else:
+                call_type = item[0]
                 call = self._pop(item)
                 if call is not None:
                     self._execute(call)
+            wall = _time.perf_counter() - t0
+            cpu = thread_cpu() - cpu0 if thread_cpu is not None else 0.0
+            with self._lock:
+                cell = self._worker_s.setdefault(call_type, [0.0, 0.0])
+                cell[0] += wall
+                cell[1] += cpu
             self._q.task_done()
 
     def sync(self) -> None:
@@ -522,4 +544,18 @@ class APIDispatcher:
                 "conflicts": self._conflicts,
                 "batches": self._batches,
                 "batched_calls": self._batched_calls,
+            }
+
+    def worker_clock(self) -> dict[str, tuple[float, float | None]]:
+        """call_type -> (wall seconds, CPU seconds) the worker threads
+        spent executing what they took from the queue, since the dispatcher
+        was built. Wall less CPU is the workers' own wait: for the
+        apiserver, for the GIL. With several workers the wall can pass the
+        elapsed time. A call executed INLINE (``workers=0``, a closed
+        dispatcher) ran on its caller's thread and is in neither. CPU is
+        None where the platform keeps no per-thread clock."""
+        with self._lock:
+            return {
+                call_type: (wall, cpu if thread_cpu is not None else None)
+                for call_type, (wall, cpu) in self._worker_s.items()
             }

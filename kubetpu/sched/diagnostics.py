@@ -25,6 +25,14 @@ small listener bound to one ``Scheduler``:
   firing → resolved, fingerprint-deduped) when ``--sentinel on``.
 - ``GET /debug/bundle`` triggered diagnostic bundles (summaries, or one
   full capture with ``?id=N``).
+
+Every request runs on a thread of its own, under the scheduler loop's GIL,
+and is gone before a scrape could find it; so the handler times itself
+with its thread's CPU clock, and ``/metrics`` carries
+``scheduler_diagnostics_requests_total{endpoint}`` and
+``scheduler_diagnostics_request_cpu_seconds_total{endpoint}``: what the
+observer takes from the observed (a request counts once it has been
+answered, so a scrape reads the ones before it).
 """
 
 from __future__ import annotations
@@ -36,6 +44,18 @@ from typing import Callable, Iterable
 from urllib.parse import parse_qs, urlsplit
 
 from ..metrics.health import HealthChecks
+from ..metrics.registry import Registry
+from ..tracing import thread_cpu
+
+#: the ONLY values of {endpoint} on scheduler_diagnostics_request*_total
+ENDPOINTS = ("metrics", "trace", "health", "debug", "other")
+
+
+def _endpoint(path: str) -> str:
+    head = path.strip("/").split("/", 1)[0]
+    if head in ("healthz", "readyz", "livez"):
+        return "health"
+    return head if head in ENDPOINTS[:-1] else "other"
 
 
 class _DiagHandler(BaseHTTPRequestHandler):
@@ -59,6 +79,7 @@ class _DiagHandler(BaseHTTPRequestHandler):
 
         parts = urlsplit(self.path)
         diag = self.server_ref
+        cpu0 = thread_cpu() if thread_cpu is not None else None
         try:
             res = diagnostics_response(
                 parts.path, parse_qs(parts.query, keep_blank_values=True),
@@ -98,6 +119,10 @@ class _DiagHandler(BaseHTTPRequestHandler):
         except Exception as e:  # noqa: BLE001 — diagnostics must not crash
             self._reply(f"internal error: {type(e).__name__}: {e}\n",
                         status=500)
+        finally:
+            diag.note_request(
+                _endpoint(parts.path),
+                None if cpu0 is None else thread_cpu() - cpu0)
 
 
 class DiagnosticsServer:
@@ -122,6 +147,24 @@ class DiagnosticsServer:
             self._sources.append(lambda: default_provider().expose())
         if scheduler is not None:
             self._install_scheduler_checks(scheduler)
+        # what this listener itself takes: every endpoint a series from the
+        # first scrape (the CPU family only where threads have a CPU clock)
+        self._own = Registry()
+        self._requests = self._own.counter(
+            "scheduler_diagnostics_requests_total",
+            "Requests this listener has answered, by endpoint.",
+            labels=("endpoint",), declared={"endpoint": ENDPOINTS},
+        )
+        self._request_cpu = self._own.counter(
+            "scheduler_diagnostics_request_cpu_seconds_total",
+            "CPU seconds of the request threads that answered them, run "
+            "under the scheduler loop's GIL.",
+            labels=("endpoint",), declared={"endpoint": ENDPOINTS},
+        )
+        for endpoint in ENDPOINTS:
+            self._requests.labels(endpoint)
+            if thread_cpu is not None:
+                self._request_cpu.labels(endpoint)
         handler = type("BoundDiagHandler", (_DiagHandler,), {
             "server_ref": self,
             "disable_nagle_algorithm": True,
@@ -173,6 +216,13 @@ class DiagnosticsServer:
         else:
             self.health.add_check(name, fn, endpoints=endpoints)
 
+    def note_request(self, endpoint: str, cpu_s: float | None) -> None:
+        """One answered request, and the CPU seconds its thread took (None
+        where the platform keeps no per-thread clock)."""
+        self._requests.labels(endpoint).inc()
+        if cpu_s is not None:
+            self._request_cpu.labels(endpoint).inc(cpu_s)
+
     # --------------------------------------------------------------- bodies
     def metrics_text(self) -> str:
         chunks = []
@@ -180,6 +230,7 @@ class DiagnosticsServer:
             chunks.append(self.scheduler.metrics_text())
         for source in self._sources:
             chunks.append(source())
+        chunks.append(self._own.expose())
         return "".join(chunks)
 
     def trace_json(self) -> dict:

@@ -2061,8 +2061,10 @@ class Scheduler:
         /metrics endpoint body). Dispatcher lifetime counters (added/
         executed/errors + bulk batch counts) are folded in at scrape time
         so the DiagnosticsServer surfaces API-write failures."""
-        self.metrics.prom.set_dispatcher_stats(self.dispatcher.stats())
-        self.metrics.prom.set_loop_clock(self.loop_clock.snapshot())
+        self.metrics.prom.set_dispatcher_stats(
+            self.dispatcher.stats(), self.dispatcher.worker_clock())
+        self.metrics.prom.set_loop_clock(
+            self.loop_clock.snapshot(), self.loop_clock.cpu_snapshot())
         text = self.metrics.prom.expose()
         if self.recorder is not None and hasattr(
             self.recorder, "metrics_text"

@@ -13,7 +13,10 @@ Reference surfaces:
 
 Single-owner like the scheduler loop: span entry/exit runs on the loop
 thread, so the parent stack is a plain list (no contextvars in the hot
-path). Opening a span costs two ``perf_counter`` calls, one ``Span`` and an
+path). Opening a span costs two ``perf_counter`` calls, two reads of the
+thread's CPU clock (``cpu_s`` in its attributes: of the span's duration, the
+part the thread was on a core; the rest it waited, for the GIL, for a core,
+for a socket or for the chip), one ``Span`` and an
 append to one of TWO bounded rings: the loop's ring holds what is recorded
 once per cycle or per loop iteration, the per-item ring what is recorded
 once per pod or per request (``Tracer.record(..., per_item=True)``: the
@@ -21,22 +24,47 @@ once per pod or per request (``Tracer.record(..., per_item=True)``: the
 thousand binds cannot evict the cycle that caused them. Readers see both,
 ordered by start.
 
-Beside the spans runs the ``PhaseClock``: the loop thread's wall time
-partitioned into named phases that sum to the elapsed time by construction
-(two dictionary additions and one clock read per switch, always on). The
-spans say WHEN something ran; the phase counters say how much of every
-second each part of the loop took, idle iterations included, which the
-spans leave out on purpose.
+Beside the spans runs the ``PhaseClock``: the loop thread's wall time AND
+its CPU time, each partitioned into named phases that sum to the elapsed
+time by construction (three dictionary additions and two clock reads per
+switch, the wall clock and the thread's CPU clock, always on). The spans
+say WHEN something ran; the phase counters say how much of every second
+each part of the loop took, and how much of that the thread ran, idle
+iterations included, which the spans leave out on purpose.
+
+The CPU clock is Linux's per-thread one. ``thread_cpu`` reads the calling
+thread's (the spans, the dispatcher's workers and the diagnostics
+listener's request threads time themselves with it); the ``PhaseClock``
+reads the LOOP thread's by its clock id, which any thread may read, because
+its scrape runs elsewhere. Where the platform keeps no such clock
+``thread_cpu`` is None and nothing is recorded under a CPU name.
 """
 
 from __future__ import annotations
 
 import collections
 import itertools
+import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable
+
+#: the calling thread's CPU seconds (user + system); None where the platform
+#: keeps no per-thread CPU clock
+thread_cpu: Callable[[], float] | None = getattr(time, "thread_time", None)
+
+
+def _cpu_clock_of(ident: int) -> int | None:
+    """The id of thread ``ident``'s CPU clock, which ``time.clock_gettime``
+    reads from ANY thread of the process; None where there is no such
+    clock."""
+    try:
+        clock_id = time.pthread_getcpuclockid(ident)
+        time.clock_gettime(clock_id)
+    except (AttributeError, OSError):
+        return None
+    return clock_id
 
 
 @dataclass
@@ -101,7 +129,9 @@ class Tracer:
         """Open a span; yields it so steps can attach attributes (or set
         ``discard``). A TOP-LEVEL span exceeding ``threshold_s`` logs its
         child breakdown (utiltrace's LogIfLong) unless ``log_long`` is
-        off — the served loop's iteration envelope is long by design."""
+        off — the served loop's iteration envelope is long by design.
+        The span closes with ``cpu_s`` among its attributes: the calling
+        thread's CPU time between open and close."""
         if not self.enabled:
             yield None
             return
@@ -113,11 +143,14 @@ class Tracer:
             start=self._clock(),
             attrs=dict(attrs),
         )
+        cpu0 = thread_cpu() if thread_cpu is not None else None
         self._stack.append(sp)
         try:
             yield sp
         finally:
             sp.end = self._clock()
+            if cpu0 is not None:
+                sp.attrs["cpu_s"] = round(thread_cpu() - cpu0, 6)
             self._stack.pop()
             if not sp.discard:
                 self._spans.append(sp)
@@ -338,22 +371,37 @@ LOOP_PHASES = (
 
 
 class PhaseClock:
-    """The loop thread's wall time, partitioned into ``LOOP_PHASES``.
+    """The loop thread's wall time and its CPU time, each partitioned into
+    ``LOOP_PHASES``.
 
     Every instant belongs to exactly one phase — the one ``switch`` named
     last — so the phases are SELF times and sum to the elapsed time by
-    construction. Switched only from the loop thread; ``snapshot`` may be
-    called from any thread (the /metrics scrape) and takes no lock."""
+    construction: ``seconds`` to the wall, ``cpu_seconds`` to the CPU time
+    of the thread that switches. CPU is at most wall in every phase, to the
+    clocks' grain; what is missing the thread spent off a core: asleep,
+    blocked on a socket or on the chip, or waiting for the GIL. Switched
+    only from the loop thread; ``snapshot`` and ``cpu_snapshot`` may be
+    called from any thread (the /metrics scrape) and take no lock."""
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self._clock = clock
         self.seconds: dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
+        self.cpu_seconds: dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
         self.entries: dict[str, int] = dict.fromkeys(LOOP_PHASES, 0)
         #: completed iterations of the served loop (``_make_loop``)
         self.iterations = 0
-        # (phase, the moment it began): ONE reference, so a reader on
-        # another thread never pairs one phase with another's start
-        self._running: tuple[str, float] = ("other", clock())
+        # the thread whose CPU clock is read: the one that switches. Taken
+        # to be the builder until ``switch`` finds another
+        self._owner = threading.get_ident()
+        cpu_id = _cpu_clock_of(self._owner)
+        # (phase, the moment it began, the id of the owner's CPU clock or
+        # None, that clock when the phase began): ONE reference, so a reader
+        # on another thread never pairs one phase with another's start, nor
+        # one thread's clock with another's reading
+        self._running: tuple[str, float, int | None, float] = (
+            "other", clock(), cpu_id,
+            time.clock_gettime(cpu_id) if cpu_id is not None else 0.0,
+        )
 
     @property
     def current(self) -> str:
@@ -361,16 +409,32 @@ class PhaseClock:
 
     def switch(self, phase: str, entries: int = 1) -> str:
         """End the running phase and begin ``phase``; returns the phase
-        that ended. One clock read. ``entries`` is what the visit counts
-        for: 0 resumes an interrupted phase, the ``events`` phase counts
-        the Events it writes."""
+        that ended. Two clock reads: the wall, and the CPU clock of the
+        calling thread. ``entries`` is what the visit counts for: 0 resumes
+        an interrupted phase, the ``events`` phase counts the Events it
+        writes."""
         now = self._clock()
-        prev, since = self._running
+        prev, since, cpu_id, cpu_since = self._running
+        cpu = 0.0
+        if cpu_id is not None:
+            ident = threading.get_ident()
+            if ident != self._owner:
+                # another thread has taken the loop over (a test's, or a
+                # loop started after its scheduler was built): its clock
+                # from here on. The phase that changes hands keeps its wall
+                # and gains no CPU: the two clocks share no origin
+                self._owner = ident
+                cpu_id = _cpu_clock_of(ident)
+                if cpu_id is not None:
+                    cpu = cpu_since = time.clock_gettime(cpu_id)
+            else:
+                cpu = time.clock_gettime(cpu_id)
         # the new phase first, the old one's seconds second: a scrape in
         # between reads a little too LITTLE, never a counter that later
         # steps back
-        self._running = (phase, now)
+        self._running = (phase, now, cpu_id, cpu)
         self.seconds[prev] += now - since
+        self.cpu_seconds[prev] += cpu - cpu_since
         self.entries[phase] += entries
         return prev
 
@@ -393,9 +457,22 @@ class PhaseClock:
         part added, so that a scrape in the middle of a long phase does
         not lose it. Lock-free: a read torn by a concurrent ``switch`` is
         off by one phase slice at most and is made good at the next."""
-        phase, since = self._running
+        phase, since = self._running[:2]
         seconds = dict(self.seconds)
         seconds[phase] += max(self._clock() - since, 0.0)
         return seconds, dict(self.entries), self.iterations
 
-
+    def cpu_snapshot(self) -> dict[str, float] | None:
+        """``cpu_seconds`` with the running phase's part added, read from
+        the LOOP thread's clock whichever thread calls; None where the
+        platform keeps no per-thread CPU clock. Lock-free like
+        ``snapshot``."""
+        phase, _, cpu_id, cpu_since = self._running
+        if cpu_id is None:
+            return None
+        cpu = dict(self.cpu_seconds)
+        try:
+            cpu[phase] += max(time.clock_gettime(cpu_id) - cpu_since, 0.0)
+        except OSError:
+            pass        # the thread has ended, and its clock with it
+        return cpu

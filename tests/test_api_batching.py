@@ -10,6 +10,7 @@ serialize-once cache never serves stale bytes after an object update.
 
 import dataclasses
 import threading
+import time
 
 import pytest
 
@@ -371,7 +372,90 @@ def test_dispatcher_stats_consistent_under_worker_concurrency():
     stats = d.stats()
     assert stats["added"] == stats["executed"] == n
     assert stats["errors"] == 0
+    # the workers' clock is summed under the same lock, per call type
+    (call_type, (busy, cpu)), = d.worker_clock().items()
+    assert call_type == "bind" and 0 < cpu <= busy + 0.05
     d.close()
+
+
+class _TimedBulkClient:
+    """A bulk bind that takes 50 ms: asleep (the apiserver's reply) or on
+    a core (the encode and decode of 1024 pods)."""
+
+    def __init__(self, how: str) -> None:
+        self.how = how
+
+    def bulk_bind(self, pairs):
+        end = time.perf_counter() + 0.05
+        if self.how == "sleeps":
+            time.sleep(0.05)
+        while time.perf_counter() < end:
+            pass
+        return [None] * len(pairs)
+
+    def bind(self, pod, node_name):
+        pass
+
+
+def _bind_a_batch(client, workers, tracer=None):
+    d = APIDispatcher(client, workers=workers, bulk=True, tracer=tracer)
+    for i in range(4):
+        d.add(BindCall(make_pod(f"p{i}"), "n0"))
+    d.sync()
+    clock = d.worker_clock()
+    assert d.stats()["executed"] == 4
+    d.close()
+    return clock
+
+
+def test_a_worker_that_waits_reports_busy_far_above_its_cpu():
+    (call_type, (busy, cpu)), = _bind_a_batch(
+        _TimedBulkClient("sleeps"), workers=2).items()
+    assert call_type == "bind"
+    assert busy >= 0.05 and cpu < 0.01
+
+
+def test_a_worker_that_computes_reports_cpu_near_its_busy_time():
+    from kubetpu.tracing import Tracer
+
+    for _ in range(3):      # a spin can lose its core on a shared machine
+        tr = Tracer()
+        busy, cpu = _bind_a_batch(
+            _TimedBulkClient("spins"), workers=2, tracer=tr)["bind"]
+        if cpu >= 0.8 * busy:
+            break
+    assert busy >= 0.05 and 0.8 * busy <= cpu <= busy + 1e-3
+    # the bulk span the worker records says the same of its API phase
+    (span,) = [sp for sp in tr.recent() if sp.name == "api.bind.bulk"]
+    assert 0.5 * span.duration_s <= span.attrs["cpu_s"] <= busy + 1e-3
+
+
+def _worker_series(text: str) -> dict:
+    from kubetpu.metrics.textparse import parse_prometheus_text
+
+    pm = parse_prometheus_text(text)
+    return {kind: pm.value(
+        f"scheduler_api_dispatcher_worker_{kind}_total", call_type="bind")
+        for kind in ("seconds", "cpu_seconds")}
+
+
+@pytest.mark.parametrize("workers", [0, 1])
+def test_the_workers_clock_is_on_metrics_and_an_inline_call_is_not(workers):
+    """``workers=0``: the batch ran on its caller's thread, inside one of
+    the loop's phases, and the loop's clock has it."""
+    client = _TimedBulkClient("sleeps")
+    assert (_bind_a_batch(client, workers=workers) == {}) == (workers == 0)
+    s = Scheduler(client, profile=C.minimal_profile(),
+                  dispatcher_workers=workers, bulk=True)
+    for i in range(2):
+        s.dispatcher.add(BindCall(make_pod(f"p{i}"), "n0"))
+    s.dispatcher.sync()
+    got = _worker_series(s.metrics_text())
+    s.close()
+    if workers == 0:
+        assert got == {"seconds": None, "cpu_seconds": None}
+    else:
+        assert got["seconds"] >= 0.05 and 0 <= got["cpu_seconds"] < 0.01
 
 
 def test_dispatcher_errors_surface_in_scheduler_metrics():

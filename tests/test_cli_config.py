@@ -212,7 +212,9 @@ def test_tracer_spans_nest_and_record():
     assert by_name["cycle"].parent_id is None
     assert by_name["encode"].parent_id == by_name["cycle"].span_id
     assert abs(by_name["assign"].duration_s - 0.03) < 1e-9
-    assert by_name["cycle"].attrs == {"pods": 4}
+    # beside what the caller gave, the thread's CPU time inside the span
+    assert by_name["cycle"].attrs == {
+        "pods": 4, "cpu_s": by_name["cycle"].attrs["cpu_s"]}
     assert root is not None and root.duration_s >= 0.06
 
 

@@ -1,8 +1,11 @@
 """The served loop's phase clock, its counters on /metrics, the loop's spans
-on /trace, and the two rings of the tracer (ISSUE 24)."""
+on /trace, and the two rings of the tracer (ISSUE 24); the CPU clock beside
+the wall clock on the loop thread, on its spans and on the diagnostics
+listener's request threads (ISSUE 38)."""
 
 import threading
 import time
+import urllib.error
 import urllib.request
 
 import pytest
@@ -21,6 +24,10 @@ from kubetpu.store import MemStore
 from kubetpu.tracing import LOOP_PHASES, PhaseClock, Tracer
 
 SECONDS = "scheduler_loop_phase_seconds_total"
+CPU_SECONDS = "scheduler_loop_phase_cpu_seconds_total"
+PROCESS_CPU = "process_cpu_seconds_total"
+DIAG_REQUESTS = "scheduler_diagnostics_requests_total"
+DIAG_CPU = "scheduler_diagnostics_request_cpu_seconds_total"
 ENTRIES = "scheduler_loop_phase_entries_total"
 ITERATIONS = "scheduler_loop_iterations_total"
 
@@ -79,6 +86,101 @@ def test_a_scrape_in_the_middle_of_a_phase_includes_its_elapsed_part():
     assert clock.snapshot()[0]["drain"] == 3.0
 
 
+# -------------------------------------------------------- the CPU clock
+
+def spin(seconds):
+    """Hold a core: what a pure-Python phase does."""
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_cpu_is_at_most_wall_in_every_phase_and_sums_to_the_thread_s():
+    clock = PhaseClock()
+    cpu0 = time.thread_time()
+    for k, phase in enumerate(LOOP_PHASES * 2):
+        clock.switch(phase)
+        spin(0.002) if k % 2 else time.sleep(0.002)
+    clock.switch("other")
+    thread_cpu = time.thread_time() - cpu0
+    for phase in LOOP_PHASES:
+        # to the clocks' grain: the two are read a microsecond apart
+        assert clock.cpu_seconds[phase] <= clock.seconds[phase] + 1e-3, phase
+    assert sum(clock.cpu_seconds.values()) == pytest.approx(
+        thread_cpu, abs=2e-3)
+    assert clock.cpu_snapshot().keys() == set(LOOP_PHASES)
+
+
+def test_a_sleeping_phase_has_wall_and_no_cpu_a_spinning_one_has_both():
+    for _ in range(3):      # a spin can lose its core on a shared machine
+        clock = PhaseClock()
+        with clock.phase("sleep"):
+            time.sleep(0.05)
+        with clock.phase("drain"):
+            spin(0.05)
+        wall, cpu = clock.seconds["drain"], clock.cpu_seconds["drain"]
+        if cpu >= 0.8 * wall:
+            break
+    assert clock.seconds["sleep"] >= 0.05
+    assert clock.cpu_seconds["sleep"] < 0.005
+    assert wall >= 0.05 and 0.8 * wall <= cpu <= wall + 1e-3
+
+
+def test_a_scrape_from_a_spinning_thread_leaks_no_cpu_into_any_phase():
+    """``cpu_snapshot`` reads the LOOP thread's clock, not its caller's."""
+    clock = PhaseClock()
+    clock.switch("pump_rpc")        # the loop thread, blocked on a socket
+    got = []
+
+    def scraper():
+        spin(0.1)
+        got.append(clock.cpu_snapshot())
+
+    th = threading.Thread(target=scraper)
+    th.start()
+    th.join()                       # blocked: no CPU of this thread's
+    assert got[0]["pump_rpc"] < 0.02
+    assert sum(got[0].values()) < 0.02
+
+
+def test_the_thread_that_switches_is_the_thread_that_is_clocked():
+    """A loop started on another thread than its scheduler's builder (the
+    tests' way): ``switch`` finds the new thread and reads ITS clock."""
+    clock = PhaseClock()            # built here ...
+
+    def loop():                     # ... switched there
+        clock.switch("cycle")
+        spin(0.05)
+        clock.switch("sleep")
+
+    spin(0.05)                      # the builder's CPU is nobody's phase
+    th = threading.Thread(target=loop)
+    th.start()
+    th.join()
+    assert 0.02 <= clock.cpu_seconds["cycle"] <= clock.seconds["cycle"] + 1e-3
+    assert clock.seconds["other"] >= 0.05
+    # the phase that changed hands kept its wall and gained no CPU
+    assert clock.cpu_seconds["other"] == 0.0
+    # the thread has ended: its clock is gone, the totals stay
+    assert clock.cpu_snapshot()["cycle"] == clock.cpu_seconds["cycle"]
+
+
+def test_a_span_carries_cpu_s_and_a_recorded_span_does_not():
+    tr = Tracer()
+    with tr.span("encode") as busy:
+        spin(0.02)
+    with tr.span("pump") as blocked:
+        time.sleep(0.02)
+    tr.record("bind", start=1.0, end=2.0, per_item=True)
+    tr.instant("invalidate")
+    assert 0.5 * 0.02 <= busy.attrs["cpu_s"] <= busy.duration_s + 1e-3
+    assert blocked.attrs["cpu_s"] < 0.005 <= blocked.duration_s
+    by_name = {e["name"]: e for e in tr.chrome_trace()["traceEvents"]}
+    assert by_name["encode"]["args"]["cpu_s"] == busy.attrs["cpu_s"]
+    assert "cpu_s" not in by_name["bind"]["args"]
+    assert "cpu_s" not in by_name["invalidate"]["args"]
+
+
 # ------------------------------------------------- a scheduler on a store
 
 def served(nodes=2, pods=0, **kw):
@@ -108,6 +210,31 @@ def test_all_nine_phases_are_on_metrics_from_the_first_scrape():
         assert pm.value(ENTRIES, phase=phase) == 0
     assert len(LOOP_PHASES) == 9
     assert pm.value(ITERATIONS) == 0
+    s.close()
+
+
+def test_the_cpu_families_are_on_metrics_from_the_first_scrape():
+    _, s, _ = served()
+    pm = parse_prometheus_text(s.metrics_text())
+    for phase in LOOP_PHASES:
+        assert pm.value(CPU_SECONDS, phase=phase) is not None, phase
+    assert 0 < pm.value(PROCESS_CPU) <= time.process_time()
+    s.close()
+
+
+def test_the_loop_s_spans_carry_cpu_s_and_the_per_pod_spans_do_not():
+    st, s, once = served(pods=5)
+    once()
+    assert len(bound(st)) == 5
+    by_name = {}
+    for sp in s.tracer.recent(1000):
+        by_name.setdefault(sp.name, []).append(sp)
+    for name in ("loop-iteration", "pump", "encode", "explain",
+                 "bind-dispatch", "drain"):
+        (sp,) = by_name[name]
+        assert 0 <= sp.attrs["cpu_s"] <= sp.duration_s + 1e-3, name
+    assert len(by_name["bind"]) == 5
+    assert all("cpu_s" not in sp.attrs for sp in by_name["bind"])
     s.close()
 
 
@@ -247,6 +374,60 @@ def test_the_real_loop_for_two_seconds_sums_to_the_wall():
     assert after.value(ITERATIONS) > before.value(ITERATIONS)
     assert after.value(ENTRIES, phase="events") == 40 == len(bound(st))
     assert after.value(SECONDS, phase="sleep") > 0
+    # the CPU clock of the thread that ran the loop, read by the scrapes'
+    # threads: at most the wall in every phase, and next to none asleep
+    window = b1 - a0
+    for p in LOOP_PHASES:
+        wall = after.value(SECONDS, phase=p) - before.value(SECONDS, phase=p)
+        cpu = (after.value(CPU_SECONDS, phase=p)
+               - before.value(CPU_SECONDS, phase=p))
+        assert 0 <= cpu <= wall + 0.01 * window, p
+        if p == "sleep":
+            assert cpu <= 0.05 * wall
+    assert after.value(PROCESS_CPU) > before.value(PROCESS_CPU)
+
+
+# ------------------------------------------- the listener times itself
+
+def test_a_diagnostics_request_adds_to_its_endpoint_and_to_no_other():
+    _, s, _ = served()
+    diag = DiagnosticsServer(s).start()
+
+    def get(path):
+        try:
+            with urllib.request.urlopen(diag.url + path, timeout=10) as resp:
+                return resp.read().decode()
+        except urllib.error.HTTPError:
+            return ""
+
+    endpoints = ("metrics", "trace", "health", "debug", "other")
+
+    def counts():
+        pm = parse_prometheus_text(diag.metrics_text())
+        return ({e: pm.value(DIAG_REQUESTS, endpoint=e) for e in endpoints},
+                {e: pm.value(DIAG_CPU, endpoint=e) for e in endpoints})
+    try:
+        # every endpoint is there before the first request, at zero
+        assert counts() == (dict.fromkeys(endpoints, 0),
+                            dict.fromkeys(endpoints, 0.0))
+        for path, n in (("/trace", 3), ("/metrics", 2), ("/readyz", 1),
+                        ("/healthz/ping", 1), ("/debug/queue?limit=1", 1),
+                        ("/nowhere", 1)):
+            for _ in range(n):
+                get(path)
+        deadline = time.perf_counter() + 10
+        while (sum(counts()[0].values()) < 9
+               and time.perf_counter() < deadline):
+            time.sleep(0.01)    # a request counts once it has been answered
+        requests, cpu = counts()
+    finally:
+        diag.close()
+        s.close()
+    assert requests == {"metrics": 2, "trace": 3, "health": 2, "debug": 1,
+                        "other": 1}
+    assert all(v > 0 for v in cpu.values())
+    # the scrape itself is answered over HTTP too, and sees the ones before
+    assert "scheduler_diagnostics_requests_total" in diag.metrics_text()
 
 
 # ------------------------------------------------------- the two rings
