@@ -12,11 +12,14 @@ Reference names and shapes (metrics.go):
 - ``apiserver_longrunning_requests{verb, resource}`` instead
 
 Beside them, kubetpu's own: ``apiserver_bulk_ops_total{resource, path}``,
-the ops of ``:bulk`` requests by the path the request took.
+the ops of ``:bulk`` requests by the path the request took, and
+``apiserver_pod_binds_total{result}``, the pods ``:bulk`` verb's bind ops
+by what they did.
 """
 
 from __future__ import annotations
 
+import collections
 import re
 import time
 from contextlib import contextmanager
@@ -31,6 +34,11 @@ REQUEST_DURATION_BUCKETS = [
 ]
 
 READ_VERBS = frozenset({"GET", "LIST", "WATCH"})
+
+#: a bind op's result by its status: the pod took its node, it was bound
+#: already (or recreated under another uid), it was not there
+BIND_RESULTS = ("bound", "conflict", "gone")
+_BIND_RESULT_OF = {200: "bound", 409: "conflict", 404: "gone"}
 
 #: distinct resource label values admitted before folding into "other" —
 #: the resource segment is CLIENT-supplied path text, and every unseen
@@ -123,6 +131,16 @@ class APIServerMetrics:
             labels=("resource", "path"),
             declared={"path": ("one_lock", "sequential")},
         )
+        # the binding subresource's outcomes, on both paths of the verb: Δ
+        # bound ÷ pods bound says every bind of a window took the op
+        self.pod_binds = r.counter(
+            "apiserver_pod_binds_total",
+            "Bind ops of the pods :bulk verb, by result: bound (the pod "
+            "took its node), conflict (already bound, or recreated under "
+            "another uid) or gone.",
+            labels=("result",),
+            declared={"result": BIND_RESULTS},
+        )
         # replication-feed egress by path — the chained-shipping
         # acceptance (leader egress ~= one follower's worth) reads the
         # leader's log-path delta
@@ -152,6 +170,15 @@ class APIServerMetrics:
             self.bulk_ops.labels(
                 self._resource_label(resource, succeeded=False), path
             ).inc(n)
+
+    def count_pod_binds(self, statuses) -> None:
+        """Record the results of one request's bind ops by their statuses
+        (a malformed op's 400 or 422 is none of the three)."""
+        for result, n in collections.Counter(
+            _BIND_RESULT_OF.get(s) for s in statuses
+        ).items():
+            if result is not None:
+                self.pod_binds.labels(result).inc(n)
 
     def count_replication(self, path: str, n: int) -> None:
         """Record ``n`` replication-feed payload bytes served."""

@@ -616,6 +616,9 @@ class RemoteStore:
                 w["object"] = op["object"]    # live; the codec encodes it
             if op.get("expect_rv") is not None:
                 w["resourceVersion"] = op["expect_rv"]
+            for field in ("uid", "node"):     # a bind op's
+                if field in op:
+                    w[field] = op[field]
             wire.append(w)
         res = self._request("POST", f"/apis/{kind}{BULK_SUFFIX}",
                             {"ops": wire})

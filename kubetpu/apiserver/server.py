@@ -41,7 +41,10 @@ envelope here:
                                         "object"?}, …]} positional, per-op
                                         conflict/admission semantics
                                         identical to the single-op verbs
-                                        (a mid-batch 409 fails only its op)
+                                        (a mid-batch 409 fails only its op);
+                                        on pods also {"op": "bind", "key",
+                                        "uid"?, "node"}: the binding
+                                        subresource, no object either way
     PUT    /apis/<kind>/<key…>[?resourceVersion=N]
                                         update; CAS conflict → 409
     DELETE /apis/<kind>/<key…>          delete (404 when absent)
@@ -81,6 +84,7 @@ from ..api import codec, scheme
 from ..metrics.health import HealthChecks
 from ..store.memstore import (
     CompactedError, ConflictError, FollowerWriteError, MemStore,
+    bind_refusal,
 )
 from .admission import AdmissionDenied, Registry, ValidationError
 from .metrics import APIServerMetrics
@@ -107,6 +111,13 @@ _OP_ERRORS = (
 )
 
 
+#: the ops of the bulk verb, and the answer to any other
+_BULK_VERBS = ("create", "update", "patch", "delete", "get", "bind")
+_BULK_VERBS_ERROR = (
+    "op must carry a key and one of create/update/patch/delete/get/bind"
+)
+
+
 def _op_error_result(e: Exception) -> dict:
     """Map one bulk-op exception to its per-op result dict."""
     for types, status in _OP_ERROR_STATUS:
@@ -116,6 +127,38 @@ def _op_error_result(e: Exception) -> dict:
             )
             return {"status": status, "resourceVersion": 0, "error": reason}
     raise e  # unmapped: let the request-level 500 handler see it
+
+
+def _bind_node(kind: str, key: str, op: dict) -> str:
+    """A bind op's node, validated as what the bind changes: the op is the
+    pods binding subresource (name, uid, node), and it names a node."""
+    if kind != "pods":
+        raise ValueError("bind is an op of the pods :bulk verb")
+    node = op.get("node")
+    if not isinstance(node, str) or not node:
+        raise ValidationError(kind, key, ["spec.nodeName: a bind names one"])
+    return node
+
+
+class _BatchObjects:
+    """What a bulk batch writes, as ``Registry.has_dynamic_admission``
+    sees it: the ops' own objects, then the stored pods its bind ops name,
+    read only if a predicate iterates them (under the store lock that
+    applies the batch, where the verb asks)."""
+
+    def __init__(self, store, kind: str, objs: list, bind_keys: list):
+        self._store, self._kind = store, kind
+        self._objs, self._bind_keys = objs, bind_keys
+
+    def __len__(self) -> int:
+        return len(self._objs) + len(self._bind_keys)
+
+    def __iter__(self):
+        yield from self._objs
+        for key in self._bind_keys:
+            obj, _rv = self._store.get(self._kind, key)
+            if obj is not None:
+                yield obj
 
 
 def _stamp_pod_ingest(kind: str, obj):
@@ -1145,7 +1188,14 @@ class _Handler(BaseHTTPRequestHandler):
         store lock acquisition that applies the batch: a ResourceQuota the
         store committed before the batch's first write sends all of it
         down the sequential chain. ``apiserver_bulk_ops_total{resource,
-        path}`` and the BULK span's ``path`` say which was taken."""
+        path}`` and the BULK span's ``path`` say which was taken.
+
+        A pods ``bind`` op (the binding subresource: key, uid, node; no
+        object) decodes nothing and validates the node it sets: on the
+        one-lock pass the store sets it on the pod it holds
+        (``MemStore.bulk``); on the sequential chain the stored pod with
+        its node set is the update the chain admits, CAS on the revision
+        read. ``apiserver_pod_binds_total{result}`` counts both."""
         body = self._read_body()
         ops = body.get("ops")
         if not isinstance(ops, list):
@@ -1165,6 +1215,10 @@ class _Handler(BaseHTTPRequestHandler):
             # verbs' proving responses)
             self.metrics.admit_resource(kind)
         self.metrics.count_bulk_ops(kind, path, len(ops))
+        self.metrics.count_pod_binds(
+            r["status"] for op, r in zip(ops, out)
+            if isinstance(op, dict) and op.get("op") == "bind"
+        )
         self._span_attrs["path"] = path
         self._reply({"results": out})
 
@@ -1178,13 +1232,8 @@ class _Handler(BaseHTTPRequestHandler):
             verb = op.get("op") if isinstance(op, dict) else None
             key = op.get("key") if isinstance(op, dict) else None
             try:
-                if not key or verb not in (
-                    "create", "update", "patch", "delete", "get"
-                ):
-                    raise ValueError(
-                        "op must carry a key and one of "
-                        "create/update/patch/delete/get"
-                    )
+                if not key or verb not in _BULK_VERBS:
+                    raise ValueError(_BULK_VERBS_ERROR)
                 if verb in ("create", "update", "patch"):
                     obj = codec.as_object(op.get("object") or {})
                     real = "create" if verb == "create" else "update"
@@ -1199,6 +1248,13 @@ class _Handler(BaseHTTPRequestHandler):
                         "op": real, "key": key, "object": obj,
                         "expect_rv": op.get("resourceVersion"),
                     })
+                elif verb == "bind":
+                    # nothing to decode: the store sets the node on the
+                    # pod it holds
+                    prepared.append({
+                        "op": "bind", "key": key, "uid": op.get("uid") or "",
+                        "node": _bind_node(kind, key, op),
+                    })
                 else:
                     prepared.append({"op": verb, "key": key})
                 results.append(None)     # filled from the storage pass
@@ -1206,13 +1262,25 @@ class _Handler(BaseHTTPRequestHandler):
                 results.append(_op_error_result(e))
                 prepared.append(None)
         store_ops = [p for p in prepared if p is not None]
-        objs = [p["object"] for p in store_ops if "object" in p]
+        objs = _BatchObjects(
+            self.store, kind,
+            [p["object"] for p in store_ops if "object" in p],
+            [p["key"] for p in store_ops if p["op"] == "bind"],
+        )
         stored = self.store.bulk(
             kind, store_ops,
             guard=lambda: not self.registry.has_dynamic_admission(kind, objs),
         )
         if stored is None:
             return None
+        for p, res in zip(store_ops, stored):
+            if (
+                p["op"] == "bind" and res["status"] == 200
+                and len(self._span_pod_traces) < 64
+            ):
+                # the bind-subresource span's link to the pod, as an
+                # update's: the pod as committed
+                self._note_pod_trace(kind, self.store.get(kind, p["key"])[0])
         store_res = iter(stored)
         out = []
         for res in results:
@@ -1231,13 +1299,8 @@ class _Handler(BaseHTTPRequestHandler):
         verb = op.get("op") if isinstance(op, dict) else None
         key = op.get("key") if isinstance(op, dict) else None
         try:
-            if not key or verb not in (
-                "create", "update", "patch", "delete", "get"
-            ):
-                raise ValueError(
-                    "op must carry a key and one of "
-                    "create/update/patch/delete/get"
-                )
+            if not key or verb not in _BULK_VERBS:
+                raise ValueError(_BULK_VERBS_ERROR)
             if verb == "create":
                 rv = self._apply_create(kind, key, op.get("object") or {})
                 return {"status": 201, "resourceVersion": rv}
@@ -1249,6 +1312,16 @@ class _Handler(BaseHTTPRequestHandler):
                 return {"status": 200, "resourceVersion": rv}
             if verb == "delete":
                 rv = self.store.delete(kind, key)
+                return {"status": 200, "resourceVersion": rv}
+            if verb == "bind":
+                # the update the admission chain sees today: the stored
+                # pod with its node set, CAS on the revision read
+                node = _bind_node(kind, key, op)
+                current, rv = self.store.get(kind, key)
+                refused = bind_refusal(key, current, op.get("uid") or "")
+                if refused is not None:
+                    return refused
+                rv = self._apply_update(kind, key, current.with_node(node), rv)
                 return {"status": 200, "resourceVersion": rv}
             obj, rv = self.store.get(kind, key)      # verb == "get"
             if obj is None:

@@ -52,55 +52,74 @@ class StoreClient:
     def __init__(self, store: MemStore) -> None:
         self.store = store
         self.status_patches: list[tuple[str, str]] = []
+        # False once the store answered a bind op 400: a server older than
+        # the op, which binds nothing by that answer; the binds go as a get
+        # and a CAS update from then on
+        self._bind_op = True
 
     def bind(self, pod: t.Pod, node_name: str) -> None:
+        """POST pods/<name>/binding: a one-op batch of the ``bind`` op
+        where the store has the bulk verb, so there is one bind semantics;
+        a get and a CAS update where it has not."""
+        from ..store.memstore import bind_refusal, bulk_result_error
+
+        if hasattr(self.store, "bulk"):
+            (err,) = self._bind_batch([(pod, node_name)])
+            if err is not None:
+                raise err
+            return
         key = pod_store_key(pod)
         current, rv = self.store.get(PODS, key)
-        if current is None:
-            raise RuntimeError(f"bind conflict: pod {key} is gone")
-        if current.node_name:
-            # ANY already-bound pod conflicts, same node included — the
-            # reference's binding subresource 409s regardless of target,
-            # and federation's race mode depends on it: a same-node
-            # "bind" from a losing replica must not read as a win
-            raise RuntimeError(
-                f"bind conflict: pod {key} already on {current.node_name}"
-            )
-        self.store.update(PODS, key, current.with_node(node_name), expect_rv=rv)
+        refused = bind_refusal(key, current, pod.uid)
+        if refused is not None:
+            raise bulk_result_error(refused)
+        self.store.update(
+            PODS, key, current.with_node(node_name), expect_rv=rv
+        )
 
     def bulk_bind(
         self, pairs: "list[tuple[t.Pod, str]]"
     ) -> "list[Exception | None]":
-        """One scheduling cycle's binds as TWO bulk round trips (one bulk
-        GET for current objects + CAS revisions, one bulk UPDATE) instead
-        of 2·N single-op requests — the dispatcher's micro-batch path.
-        Positional results: None for a landed bind, else the exception the
-        single-op ``bind`` would have raised for that pod (the dispatcher
-        falls back to per-call execution for those, so the bind-error →
-        forget-assumed → requeue path is unchanged pod for pod)."""
+        """One scheduling cycle's binds as ONE bulk round trip of ``bind``
+        ops (key, uid, node: no pod crosses the wire either way) — the
+        dispatcher's micro-batch path. Positional results: None for a
+        landed bind, else the exception the single-op ``bind`` raises for
+        that pod (the dispatcher falls back to per-call execution for
+        those, so the bind-error → forget-assumed → requeue path is
+        unchanged pod for pod)."""
+        if not hasattr(self.store, "bulk"):
+            raise NotImplementedError("store has no bulk verb")
+        return self._bind_batch(pairs)
+
+    def _bind_batch(self, pairs) -> "list[Exception | None]":
         from ..store.memstore import bulk_result_error
 
-        store = self.store
-        if not hasattr(store, "bulk"):
-            raise NotImplementedError("store has no bulk verb")
+        if self._bind_op:
+            res = self.store.bulk(PODS, [
+                {"op": "bind", "key": pod_store_key(pod), "uid": pod.uid,
+                 "node": node_name}
+                for pod, node_name in pairs
+            ])
+            if not res or any(r.get("status") != 400 for r in res):
+                return [bulk_result_error(r) for r in res]
+            self._bind_op = False
+        return self._bind_by_update(pairs)
+
+    def _bind_by_update(self, pairs) -> "list[Exception | None]":
+        """The binds as a bulk get and a bulk CAS update: two round trips,
+        only against a store that does not know the ``bind`` op."""
+        from ..store.memstore import bind_refusal, bulk_result_error
+
         keys = [pod_store_key(pod) for pod, _ in pairs]
-        gets = store.bulk(PODS, [{"op": "get", "key": k} for k in keys])
+        gets = self.store.bulk(PODS, [{"op": "get", "key": k} for k in keys])
         errs: "list[Exception | None]" = [None] * len(pairs)
         upd_idx: list[int] = []
         upd_ops: list[dict] = []
         for i, ((pod, node_name), res) in enumerate(zip(pairs, gets)):
             current = res.get("object")
-            if res.get("status", 500) >= 400 or current is None:
-                errs[i] = RuntimeError(
-                    f"bind conflict: pod {keys[i]} is gone"
-                )
-                continue
-            if current.node_name:
-                # same strictness as the single-op bind above
-                errs[i] = RuntimeError(
-                    f"bind conflict: pod {keys[i]} already on "
-                    f"{current.node_name}"
-                )
+            refused = bind_refusal(keys[i], current, pod.uid)
+            if refused is not None:
+                errs[i] = bulk_result_error(refused)
                 continue
             upd_idx.append(i)
             upd_ops.append({
@@ -109,7 +128,7 @@ class StoreClient:
                 "expect_rv": res["resourceVersion"],
             })
         if upd_ops:
-            for i, res in zip(upd_idx, store.bulk(PODS, upd_ops)):
+            for i, res in zip(upd_idx, self.store.bulk(PODS, upd_ops)):
                 errs[i] = bulk_result_error(res)
         return errs
 

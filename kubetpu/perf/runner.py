@@ -3031,12 +3031,8 @@ def run_wal_overhead(
                  "object": make_pod(k.split("/", 1)[1], namespace="ns")}
                 for k in keys
             ])
-            gets = store.bulk(PODS, [{"op": "get", "key": k} for k in keys])
             store.bulk(PODS, [
-                {"op": "update", "key": k,
-                 "object": g["object"].with_node("node-0"),
-                 "expect_rv": g["resourceVersion"]}
-                for k, g in zip(keys, gets)
+                {"op": "bind", "key": k, "node": "node-0"} for k in keys
             ])
         return time.perf_counter() - t0
 

@@ -204,7 +204,8 @@ class _BatchJob:
 
 #: call_type → (client bulk method name, call → bulk-op argument). A client
 #: exposing the named method gets the whole micro-batch in ONE invocation
-#: (e.g. StoreClient.bulk_bind turns a cycle's binds into two bulk RPCs);
+#: (e.g. StoreClient.bulk_bind turns a cycle's binds into ONE bulk RPC of
+#: bind ops);
 #: clients without it fall back to per-call execution unchanged.
 _BULK_ADAPTERS: dict[str, tuple] = {
     "bind": ("bulk_bind", lambda c: (c.pod, c.node_name)),

@@ -729,7 +729,7 @@ def _fenced_client(client: Any, leases: PartitionLeaseManager,
             cached per PARTITION within the batch — the answer is
             identical for every pod sharing one, and the uncached version
             would pay one lease read (an RPC in fullstack mode) per pod,
-            undoing the 2-RPCs-per-cycle bulk bind path."""
+            undoing the one-RPC-per-cycle bulk bind path."""
             errs: list = [None] * len(pairs)
             ok_idx: list[int] = []
             ok_pairs: list = []

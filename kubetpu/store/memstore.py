@@ -117,6 +117,27 @@ def bulk_result_error(res: dict) -> Exception | None:
     return RuntimeError(f"{status}: {reason}")
 
 
+def bind_refusal(key: str, current: Any, uid: str = "") -> dict | None:
+    """Why pod ``key`` may not be bound as it is stored (``current``), as
+    a bulk-op result, or None: the binding subresource's checks. Gone is
+    a 404; a different ``uid`` (a recreated pod of the same name) and ANY
+    node already set, the same node included, are 409s — federation's
+    race mode depends on a losing replica's same-node bind reading as a
+    conflict, not a win."""
+    if current is None:
+        status, why = 404, "is gone"
+    elif uid and current.uid != uid:
+        status, why = 409, f"was recreated (uid {current.uid!r}, not {uid!r})"
+    elif current.node_name:
+        status, why = 409, f"already on {current.node_name}"
+    else:
+        return None
+    return {
+        "status": status, "resourceVersion": 0,
+        "error": f"bind conflict: pod {key} {why}",
+    }
+
+
 @dataclass(frozen=True)
 class WatchEvent:
     type: str              # ADDED | MODIFIED | DELETED
@@ -608,7 +629,7 @@ class MemStore:
     # --------------------------------------------------------------- bulk
     def bulk(self, kind: str, ops: list[dict],
              guard: Callable[[], bool] | None = None) -> list[dict] | None:
-        """Apply a list of create/update/delete/get ops under ONE lock
+        """Apply a list of create/update/delete/get/bind ops under ONE lock
         acquisition (the bulk verb's storage half: N writes pay one lock
         round instead of N). Ops are dicts ``{"op": "create|update|delete|
         get", "key": …, "object": …, "expect_rv": …}``; the result list is
@@ -616,6 +637,12 @@ class MemStore:
         "object"?}`` per op with the SAME per-object conflict/absence
         semantics as the single-op verbs (a mid-batch conflict fails only
         its own op — later ops still apply).
+
+        ``{"op": "bind", "key", "uid"?, "node"}`` is the pods binding
+        subresource: no object crosses in or out. The stored pod, checked
+        by ``bind_refusal``, is committed with ``node_name`` set through
+        the update body, so the WAL record, the watch event and the
+        resourceVersion are what a get + CAS update of the same pod write.
 
         ``guard`` is asked once, under the SAME lock acquisition that
         applies the batch (the lock is reentrant, so it may read the
@@ -647,6 +674,18 @@ class MemStore:
                         out.append({"status": 200, "resourceVersion": rv})
                     elif verb == "delete":
                         rv = self._delete_locked(kind, key)
+                        out.append({"status": 200, "resourceVersion": rv})
+                    elif verb == "bind" and kind == "pods":
+                        current, rv = self._core.get(kind, key)
+                        refused = bind_refusal(
+                            key, current, op.get("uid") or ""
+                        )
+                        if refused is not None:
+                            out.append(refused)
+                            continue
+                        rv = self._update_locked(
+                            kind, key, current.with_node(op["node"]), rv
+                        )
                         out.append({"status": 200, "resourceVersion": rv})
                     elif verb == "get":
                         obj, rv = self._core.get(kind, key)

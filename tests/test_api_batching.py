@@ -523,10 +523,12 @@ def test_fullstack_bulk_on_off_identical_bindings():
 
 
 def test_fullstack_mid_batch_conflict_falls_back_and_still_binds():
-    """A mid-batch 409 (an interfering writer bumps one pod's rv between
-    the bulk GET and the bulk CAS UPDATE) must fail only that op; the
-    dispatcher's per-call fallback re-binds it against fresh state, so
-    the final bindings equal the single-op run's."""
+    """A mid-batch 409 must fail only that op; the dispatcher's per-call
+    fallback re-binds it against fresh state, so the final bindings equal
+    the single-op run's. The interposer answers one op of a cycle's bind
+    batch 409 in place of the server; and an interfering writer bumps
+    another pod's revision first, which a bind op (no revision in it) no
+    longer conflicts on."""
     class _InterposingStore(RemoteStore):
         def __init__(self, url, raw_store):
             super().__init__(url)
@@ -535,18 +537,23 @@ def test_fullstack_mid_batch_conflict_falls_back_and_still_binds():
 
         def bulk(self, kind, ops):
             if (
-                not self.injected and kind == PODS and ops
-                and ops[0]["op"] == "update" and len(ops) > 2
+                self.injected or kind != PODS or len(ops) <= 2
+                or ops[0]["op"] != "bind"
             ):
-                victim = ops[len(ops) // 2]["key"]
-                cur, _rv = MemStore.get(self._raw, PODS, victim)
-                if cur is not None and not cur.node_name:
-                    MemStore.update(
-                        self._raw, PODS, victim,
-                        dataclasses.replace(cur, priority=cur.priority + 1),
-                    )
-                    self.injected = True
-            return super().bulk(kind, ops)
+                return super().bulk(kind, ops)
+            cur, _rv = MemStore.get(self._raw, PODS, ops[0]["key"])
+            MemStore.update(
+                self._raw, PODS, ops[0]["key"],
+                dataclasses.replace(cur, priority=cur.priority + 1),
+            )
+            held = len(ops) // 2
+            res = super().bulk(kind, ops[:held] + ops[held + 1:])
+            res.insert(held, {
+                "status": 409, "resourceVersion": 0,
+                "error": "bind conflict: held back by the interposer",
+            })
+            self.injected = True
+            return res
 
     srv_a = APIServer().start()
     srv_b = APIServer().start()

@@ -5,7 +5,12 @@ listener's requests and CPU by endpoint, the process's CPU (its own counter
 and ``/proc``), the six per-layer shares that read them (computed in an
 untraced run too, and in the cell whose list of metrics is pinned), and the
 loop's spans by name with their ``cpu_s``, from one ``/trace`` read after the
-window has closed.
+window has closed. Beside them, the apiserver's bind ops by result
+(``apiserver_pod_binds_total``, ISSUE 39: ``null`` on a program without
+the op), its pods ``:bulk`` ops by path, and the pods the scheduler placed
+in the same window (``scheduler_schedule_attempts_total{result=
+"scheduled"}``): ``bound`` ÷ ``scheduled`` near 100 says every bind took
+the op.
 
     python3 tools/thread_clocks_run.py --workload basic-5k.saturate \\
         --seed <n> --seconds 51 --trace <0|1>
@@ -32,6 +37,9 @@ WORKER_WALL = "scheduler_api_dispatcher_worker_seconds_total"
 WORKER_CPU = "scheduler_api_dispatcher_worker_cpu_seconds_total"
 REQUESTS = "scheduler_diagnostics_requests_total"
 REQUEST_CPU = "scheduler_diagnostics_request_cpu_seconds_total"
+POD_BINDS = "apiserver_pod_binds_total"
+BULK_OPS = "apiserver_bulk_ops_total"
+ATTEMPTS = "scheduler_schedule_attempts_total"
 SHARES = ("loop_thread_cpu_share", "loop_stall_share", "loop_blocked_share",
           "loop_sleep_share", "dispatcher_cpu_share", "dispatcher_busy_share",
           "scheduler_unclocked_cpu_share", "scheduler_cpu_share")
@@ -92,7 +100,12 @@ def install() -> None:
                            else None),
             proc_stat_cpu_s=run.cpu_s.get("scheduler"),
             shares={name: share(name, run) for name in SHARES},
-            spans=spans_by_name(run.diag_url, opened.t0, t1))
+            spans=spans_by_name(run.diag_url, opened.t0, t1),
+            pod_binds=(run.apiserver.by_labels(POD_BINDS, "result")
+                       if POD_BINDS in run.apiserver.after.samples
+                       else None),
+            bulk_ops=run.apiserver.by_labels(BULK_OPS, "resource", "path"),
+            scheduled=d.total(ATTEMPTS, result="scheduled"))
         return t1, xspace
 
     phases._close_window = close_and_tell
