@@ -170,11 +170,16 @@ def spread_constraint(
     when: t.UnsatisfiableConstraintAction = t.UnsatisfiableConstraintAction.DO_NOT_SCHEDULE,
     match_labels: Mapping[str, str] | None = None,
     min_domains: int | None = None,
+    node_affinity_policy: str = "Honor",
+    node_taints_policy: str = "Ignore",
 ) -> t.TopologySpreadConstraint:
+    """The node inclusion policies default as the reference's do."""
     return t.TopologySpreadConstraint(
         max_skew=max_skew,
         topology_key=topology_key,
         when_unsatisfiable=when,
         selector=t.LabelSelector.of(match_labels),
         min_domains=min_domains,
+        node_affinity_policy=node_affinity_policy,
+        node_taints_policy=node_taints_policy,
     )

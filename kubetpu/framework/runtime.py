@@ -266,6 +266,14 @@ class SpreadEncodeStamp:
     #: of those, the pods with at least one ScheduleAnyway constraint: the
     #: ones the soft spread score runs for
     soft_pods: int
+    #: the most domains any signature counts (``domains`` also holds the
+    #: values of nodes left out of counting, interned after them)
+    counted_domains: int
+    #: by Honor policy ("taints", "affinity"): the pods with a signature
+    #: whose policy left at least one node out of counting, and the most
+    #: nodes it left out for any signature
+    policy_pods: dict
+    excluded_nodes: dict
 
 
 class StaleStaticEncode(Exception):
@@ -1214,6 +1222,9 @@ def finalize_batch(
                     (used & (sp.action[:P] == enc_spread.SOFT))
                     .any(axis=1).sum()
                 ),
+                counted_domains=int(sp.num_domains.max()),
+                policy_pods=sp.policy_pods,
+                excluded_nodes=sp.excluded_nodes,
             )
             spread_dev = SpreadDevice(
                 eligible=sp.eligible,

@@ -16,7 +16,8 @@ layouts (pkg/scheduler/metrics/metrics.go):
   extension_point="Filter+Score" at the framework level instead; the host
   spread encode is timed as plugin="PodTopologySpread",
   extension_point="PreFilter" (``Scheduler._launch_cycle``), beside
-  spread_constrained_pods_total and spread_soft_constrained_pods_total;
+  spread_constrained_pods_total, spread_soft_constrained_pods_total and
+  spread_policy_pods_total{policy};
   the host inter-pod affinity encode as plugin="InterPodAffinity",
   extension_point="PreFilter", beside podaffinity_pods_total{work}
 - schedule_attempts_total{result, profile}, preemption_attempts_total,
@@ -62,6 +63,10 @@ PIPELINE_RESULTS = ("applied", "replayed")
 #: which inter-pod affinity kernel had work for a pod: the ONLY legal values
 #: of {work} on scheduler_podaffinity_pods_total.
 PODAFFINITY_WORK = ("filter", "score")
+
+#: which node inclusion policy left nodes out of a spread count: the ONLY
+#: legal values of {policy} on scheduler_spread_policy_pods_total.
+SPREAD_POLICIES = ("taints", "affinity")
 
 
 def window_quantile_ms(
@@ -139,6 +144,20 @@ class SchedulerMetricsRegistry:
             "the soft spread score ran for them in every step of the assign "
             "scan and in the explain kernel.",
         )
+        self.spread_policy_pods = r.counter(
+            "scheduler_spread_policy_pods_total",
+            "Of the spread-constrained pods, those with a constraint whose "
+            "node inclusion policy, under Honor, left at least one node out "
+            "of the per-domain counts, by the policy: taints (nodeTaintsPolicy:"
+            " an untolerated NoSchedule or NoExecute taint) or affinity "
+            "(nodeAffinityPolicy: the pod's nodeSelector or required node "
+            "affinity). A pod may count under both.",
+            labels=("policy",),
+            declared={"policy": SPREAD_POLICIES},
+        )
+        for policy in SPREAD_POLICIES:
+            # both on the first scrape, at zero: a delta meets no gap
+            self.spread_policy_pods.labels(policy)
         self.podaffinity_pods = r.counter(
             "scheduler_podaffinity_pods_total",
             "Pods of the scheduling cycles for which an inter-pod affinity "

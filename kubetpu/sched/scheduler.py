@@ -1382,6 +1382,10 @@ class Scheduler:
                         domains=stamp.domains,
                         constrained_pods=stamp.constrained_pods,
                         soft_pods=stamp.soft_pods,
+                        counted_domains=stamp.counted_domains,
+                        taints_excluded_nodes=stamp.excluded_nodes["taints"],
+                        affinity_excluded_nodes=stamp.excluded_nodes[
+                            "affinity"],
                     )
                 stamp = batch.podaffinity_encode
                 if stamp is not None:
@@ -1657,6 +1661,11 @@ class Scheduler:
             )
             prom.spread_soft_constrained_pods.inc(
                 batch.spread_encode.soft_pods
+            )
+            policy_pods = batch.spread_encode.policy_pods
+            prom.spread_policy_pods.labels("taints").inc(policy_pods["taints"])
+            prom.spread_policy_pods.labels("affinity").inc(
+                policy_pods["affinity"]
             )
         if batch.podaffinity_encode is not None:
             prom.podaffinity_pods.labels("filter").inc(

@@ -116,6 +116,12 @@ class SpreadTensors:
     ignored: np.ndarray        # (P, N) bool — soft-scoring ignored nodes
     has_hard: bool
     has_soft: bool
+    # what each inclusion policy ("taints", "affinity") did under Honor
+    # (host-side, not shipped): the batch's real pods with a constraint
+    # whose signature it left at least one node out of counting for, and
+    # the most nodes it left out for one signature
+    policy_pods: dict
+    excluded_nodes: dict
 
     @property
     def num_sigs(self) -> int:
@@ -311,6 +317,8 @@ def encode_spread(
     node_count = np.zeros((S, NC), dtype=np.int32)
     has_key = np.zeros((S, NC), dtype=bool)
     is_hostname = np.zeros(S, dtype=bool)
+    taints_excluded = np.zeros(S, dtype=np.int32)
+    affinity_excluded = np.zeros(S, dtype=np.int32)
     domain_vocabs: list[Vocab] = []
 
     # Per-node matching-pod counts per (selector, namespace): dedupe across sigs.
@@ -335,6 +343,7 @@ def encode_spread(
                 m = _required_affinity_mask(nt, info["pod"])
                 aff_cache[aff_key] = m
             elig &= m
+            affinity_excluded[s_id] = N - np.count_nonzero(m)
         if info["taints_policy"] == "Honor":
             tol = info["tolerations"]
             tm = taint_cache.get(tol)
@@ -348,6 +357,7 @@ def encode_spread(
                 )
                 taint_cache[tol] = tm
             elig &= tm
+            taints_excluded[s_id] = N - np.count_nonzero(tm)
         eligible[s_id, :N] = elig
 
         # Counted domains (filtering.go's TpValueToMatchNum universe) are the
@@ -453,6 +463,10 @@ def encode_spread(
             has_hard = has_hard or act == HARD
             has_soft = has_soft or act == SOFT
 
+    used = sig_idx[:P] >= 0
+    sig_of = np.maximum(sig_idx[:P], 0)
+    excluded = {"taints": taints_excluded, "affinity": affinity_excluded}
+
     return SpreadTensors(
         eligible=eligible,
         node_domain=node_domain,
@@ -470,4 +484,11 @@ def encode_spread(
         ignored=ignored,
         has_hard=has_hard,
         has_soft=has_soft,
+        policy_pods={
+            name: int((used & (per_sig[sig_of] > 0)).any(axis=1).sum())
+            for name, per_sig in excluded.items()
+        },
+        excluded_nodes={
+            name: int(per_sig.max()) for name, per_sig in excluded.items()
+        },
     )

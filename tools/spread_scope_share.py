@@ -3,8 +3,10 @@ spread path's named scopes (``spread_filter``, ``spread_score``,
 ``spread_counts_update``), at the size of a spread cell's configuration
 (``topologyspread-5k.saturate`` unless ``--workload`` names another, e.g.
 ``preferredspread-5k.saturate``, whose soft score is the ``spread_score``
-scope): its nodes in its zones, ``existing`` bound pods of its measured
-template, ``real`` pending pods of the template padded to 1024.
+scope, or ``nodeinclusion-5k.saturate``, D = 5000 hostname domains): its
+nodes of its node template in its zones, ``existing`` bound pods of its
+measured template on the nodes it tolerates, ``real`` pending pods of the
+template padded to 1024.
 
     python3 tools/spread_scope_share.py [--workload CELL] \
         [nodes [existing [real]]]
@@ -28,6 +30,7 @@ import kubetpu  # noqa: E402
 import scope_share  # noqa: E402
 from benchmark.harness import templates  # noqa: E402
 from benchmark.harness.manifest import Cell, load_manifest  # noqa: E402
+from kubetpu.api.selectors import find_untolerated_taint  # noqa: E402
 from kubetpu.framework import config as C  # noqa: E402
 from kubetpu.framework import runtime as rt  # noqa: E402
 from kubetpu.state.snapshot import Cache  # noqa: E402
@@ -45,12 +48,19 @@ existing, real = args.existing, args.real
 zones = tuple(config["zones"])
 measured = config["measured_pods"]
 template = templates.resolve(templates.POD_TEMPLATES, measured["template"])
+node_of = templates.resolve(templates.NODE_TEMPLATES, config["node_template"])
 cache = Cache()
+# the existing pods go round-robin over the nodes the template tolerates
+tolerations = template("t", measured["namespace"]).tolerations
+hosts = []
 for i in range(nodes):
-    cache.add_node(templates.node_default(i, zones))
+    node = node_of(i, zones)
+    cache.add_node(node)
+    if find_untolerated_taint(node.taints, tolerations) is None:
+        hosts.append(node.name)
 for j in range(existing):
     cache.add_pod(template(f"e{j}", measured["namespace"]).with_node(
-        f"scheduler-perf-{j % nodes}"))
+        hosts[j % len(hosts)]))
 pending = [template(f"p{j}", measured["namespace"]) for j in range(real)]
 profile = C.Profile()
 snap = cache.update_snapshot()
