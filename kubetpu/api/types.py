@@ -301,7 +301,15 @@ class Pod:
         return out
 
     def with_node(self, node_name: str) -> "Pod":
-        return dataclasses.replace(self, node_name=node_name)
+        """This pod with ``node_name`` set: field for field what
+        ``dataclasses.replace`` gives, without re-running ``__init__`` over
+        every field (a tenth of its cost; a bind, its store commit, its
+        watch echo and the assume each make one, 1024 a cycle)."""
+        pod = object.__new__(type(self))
+        fields = pod.__dict__
+        fields.update(self.__dict__)
+        fields["node_name"] = node_name
+        return pod
 
 
 @dataclass(frozen=True)

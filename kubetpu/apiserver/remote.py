@@ -34,6 +34,8 @@ from ..api import codec
 from ..store.memstore import CompactedError, ConflictError, WatchEvent
 
 BULK_SUFFIX = ":bulk"
+#: the batched watch poll's query parameter that asks for bind deltas
+BIND_DELTAS_PARAM = "bindDeltas"
 
 
 class RemoteStoreError(Exception):
@@ -630,14 +632,21 @@ class RemoteStore:
         return out
 
     def watch_bulk(
-        self, cursors: dict[str, int], timeout_s: float = 0.0
+        self, cursors: dict[str, int], timeout_s: float = 0.0,
+        bind_deltas: bool = False,
     ) -> dict:
         """Batched watch poll: every kind's cursor drained in ONE request
         (GET /apis/?watch=1&buckets=…). Returns {kind: (events, cursor)}
         with a CompactedError VALUE for a compacted kind (the caller
         relists just that kind — the other buckets' deliveries still
-        land)."""
+        land). ``bind_deltas`` asks for a pods bind op's event as its
+        delta: a ``WatchEvent`` with ``obj`` None and ``bind`` (uid,
+        node), for a caller that holds the pods (``SharedInformer``
+        rebuilds them); a server that does not know the parameter sends
+        whole events, taken as ever."""
         qs = ",".join(f"{k}:{rv}" for k, rv in cursors.items())
+        if bind_deltas:
+            qs += f"&{BIND_DELTAS_PARAM}=1"
         cell = self._decode_cell()
         decode_s0 = cell[0]
         res = self._watch_request(
@@ -650,14 +659,7 @@ class RemoteStore:
                 out[kind] = CompactedError(bucket.get("error", "compacted"))
                 continue
             out[kind] = (
-                [
-                    WatchEvent(
-                        type=e["type"], kind=kind, key=e["key"],
-                        obj=codec.as_object(e["object"]),
-                        resource_version=e["resourceVersion"],
-                    )
-                    for e in bucket["events"]
-                ],
+                [_watch_event(kind, e) for e in bucket["events"]],
                 bucket["resourceVersion"],
             )
         return out
@@ -677,6 +679,16 @@ class RemoteStore:
             self, kind, since_rv,
             label_selector=label_selector, field_selector=field_selector,
         )
+
+
+def _watch_event(kind: str, e: dict) -> WatchEvent:
+    """One decoded event of a batched poll: whole, or a bind delta."""
+    bind = e.get("bind")
+    if bind is not None:
+        return WatchEvent(e["type"], kind, e["key"], None,
+                          e["resourceVersion"], (bind["uid"], bind["node"]))
+    return WatchEvent(e["type"], kind, e["key"], codec.as_object(e["object"]),
+                      e["resourceVersion"])
 
 
 def _sel_qs(prefix: str, label_selector: str, field_selector: str) -> str:

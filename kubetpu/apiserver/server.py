@@ -30,6 +30,32 @@ envelope here:
                                         (or {"code": 410} — only that kind
                                         relists). One request replaces the
                                         informer bundle's N per-kind polls.
+    GET    /apis/?watch=1&buckets=…&bindDeltas=1
+                                        the same poll, but each MODIFIED
+                                        event a pods ``bind`` op committed
+                                        comes as {"type", "key",
+                                        "resourceVersion", "bind": {"uid",
+                                        "node"}}, no object, on both wires.
+                                        GUARANTEE: a client that applies
+                                        events in order to the objects it
+                                        holds, and takes a delta's object
+                                        to be its pod with the node set,
+                                        sees event for event the (type,
+                                        key, object, resourceVersion) a
+                                        client without the parameter sees.
+                                        The watch is ordered and complete
+                                        per key, and a relist is at a
+                                        revision before any later event,
+                                        so the pod it holds is the store's
+                                        pre-bind pod, whichever client
+                                        made the bind. A client that holds
+                                        no such pod (missing, another uid,
+                                        a node already set) relists the
+                                        kind. The per-kind watch, the
+                                        stream, a scoped watch and a poll
+                                        without the parameter send whole
+                                        bodies as ever; nothing stored
+                                        changes.
     GET    /apis/<kind>/<key…>          get → {"object": …, "resourceVersion": N}
     POST   /apis/<kind>/<key…>          create (409 on exists)
     POST   /apis/<kind>:bulk            BULK verb: {"ops": [{"op": "create|
@@ -88,7 +114,9 @@ from ..store.memstore import (
 )
 from .admission import AdmissionDenied, Registry, ValidationError
 from .metrics import APIServerMetrics
-from .remote import BULK_SUFFIX   # ONE wire constant for both sides
+from .remote import (   # ONE wire constant for both sides
+    BIND_DELTAS_PARAM, BULK_SUFFIX,
+)
 
 PREFIX = "/apis/"
 
@@ -926,15 +954,17 @@ class _Handler(BaseHTTPRequestHandler):
             return
         self._reply_wire(body, wire)
 
-    def _drain_buckets(self, buckets: dict, wire: str):
+    def _drain_buckets(self, buckets: dict, wire: str,
+                       bind_deltas: bool = False):
         """One drain of every bucket's cursor → ({kind: (event bodies,
         cursor) | CompactedError}, drain revision). Uses the store's
         body-ring bulk drain when it has one (ONE lock round, cached
-        bodies, zero WatchEvent churn); otherwise materializes through
-        ``events_since_bulk`` + the serialize-once cache."""
+        bodies, zero WatchEvent churn; bind deltas where asked);
+        otherwise materializes through ``events_since_bulk`` + the
+        serialize-once cache, whole bodies only."""
         bulk_bodies = getattr(self.store, "events_body_since_bulk", None)
         if bulk_bodies is not None:
-            return bulk_bodies(buckets, wire)
+            return bulk_bodies(buckets, wire, bind_deltas)
         results, drain_rv = self.store.events_since_bulk(buckets)
         out: dict = {}
         for kind, res in results.items():
@@ -953,7 +983,9 @@ class _Handler(BaseHTTPRequestHandler):
         kind's cursor — ONE store lock acquisition, ONE HTTP round trip —
         with per-kind results (a compacted cursor 410s only its own
         bucket). Selectors are not supported on the batched poll (the
-        per-kind endpoint remains for scoped watchers)."""
+        per-kind endpoint remains for scoped watchers). With
+        ``bindDeltas=1`` a pods bind op's event is its delta (the module
+        docstring's guarantee)."""
         wire = self._reply_codec()
         buckets: dict[str, int] = {}
         for part in q["buckets"].split(","):
@@ -962,7 +994,8 @@ class _Handler(BaseHTTPRequestHandler):
                 raise ValueError(f"malformed bucket {part!r} (want kind:rv)")
             buckets[kind] = int(rv)
         timeout = min(float(q.get("timeoutSeconds", 0)), 60.0)
-        results, drain_rv = self._drain_buckets(buckets, wire)
+        bind_deltas = q.get(BIND_DELTAS_PARAM) == "1"
+        results, drain_rv = self._drain_buckets(buckets, wire, bind_deltas)
         if timeout > 0 and not any(
             isinstance(r, CompactedError) or r[0]
             for r in results.values()
@@ -970,7 +1003,7 @@ class _Handler(BaseHTTPRequestHandler):
             # wait on the revision captured AT the drain (same lock round):
             # a write landing after the drain wakes this immediately
             self.store.wait_for(drain_rv, timeout=timeout)
-            results, _ = self._drain_buckets(buckets, wire)
+            results, _ = self._drain_buckets(buckets, wire, bind_deltas)
         parts = []
         for kind in buckets:
             res = results[kind]

@@ -1014,9 +1014,11 @@ def cmd_scheduler(args) -> int:
                 # restart visibility: the client's watch-path reconnect
                 # counter rides the scheduler's /metrics page, and its
                 # decode clock (what the wire decode costs the loop thread
-                # and the dispatcher's worker)
+                # and the dispatcher's worker), and the informers' count of
+                # the bind deltas they rebuilt
                 metrics_sources=(store.reconnect_metrics_text,
-                                 store.decode_metrics_text),
+                                 store.decode_metrics_text,
+                                 informers.bind_delta_metrics_text),
             )
         except OSError as e:
             # a second scheduler on the host (HA standby) must not die on

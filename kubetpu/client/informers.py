@@ -213,8 +213,12 @@ class SchedulerInformers:
     ``watch_bulk`` — RemoteStore): ``pump()`` drains EVERY kind's watch
     cursor in one batched round trip instead of one poll per kind, each
     kind's frame delivered to its informer under a single lock acquisition.
-    Deliveries are event-for-event identical to per-kind polling — the
-    ``--bulk off`` escape hatch restores the per-kind path.
+    The poll asks for bind deltas: a pods bind op's event comes as (key,
+    uid, node) and the pods informer rebuilds the pod from the one it
+    holds (``SharedInformer._apply_batch``; a delta it cannot rebuild
+    relists the kind). Deliveries are event-for-event identical to
+    per-kind polling — the ``--bulk off`` escape hatch restores the
+    per-kind path.
 
     ``pod_filter`` (scheduler federation's per-replica filtered pump,
     sched.federation): a predicate consulted for PENDING pods only — a
@@ -333,7 +337,9 @@ class SchedulerInformers:
             cursors[r.informer.kind] = w.resource_version
         try:
             with self._polling():
-                buckets = self.store.watch_bulk(cursors)
+                # the informers hold every pod, so a bind comes as its
+                # delta and is rebuilt here, not decoded
+                buckets = self.store.watch_bulk(cursors, bind_deltas=True)
         except ConnectionError:
             # transient transport failure: same retry-next-pump shape as
             # Reflector.step's
@@ -343,17 +349,38 @@ class SchedulerInformers:
             res = buckets.get(r.informer.kind)
             if res is None:
                 continue
-            if isinstance(res, CompactedError):
-                # only this kind relists (reflector.go's too-old handling)
-                r.note_relist()
-                r.sync()
-                total += len(r.informer.store)
-                continue
-            events, cursor = res
-            r._watcher.advance(cursor)
-            r.informer._apply_batch(events)
-            total += len(events)
+            if not isinstance(res, CompactedError):
+                events, cursor = res
+                r._watcher.advance(cursor)
+                if r.informer._apply_batch(events):
+                    total += len(events)
+                    continue
+            # compacted (reflector.go's too-old handling), or a bind delta
+            # with no pod to rebuild: only this kind relists
+            r.note_relist()
+            r.sync()
+            total += len(r.informer.store)
         return total
+
+    def bind_delta_metrics_text(self) -> str:
+        """Prometheus text for the bind deltas the informers took, a
+        diagnostics metrics source: ``applied`` rebuilt from the pod held,
+        ``relisted`` refused for want of it."""
+        applied = relisted = 0
+        for r in self._reflectors:
+            a, rl = r.informer.bind_delta_counts()
+            applied += a
+            relisted += rl
+        return (
+            "# HELP scheduler_watch_bind_deltas_total Bind deltas of the "
+            "batched watch poll, by result: applied (the pod held, its "
+            "node set) or relisted (no such pod held: the kind relisted).\n"
+            "# TYPE scheduler_watch_bind_deltas_total counter\n"
+            f'scheduler_watch_bind_deltas_total{{result="applied"}} '
+            f"{applied}\n"
+            f'scheduler_watch_bind_deltas_total{{result="relisted"}} '
+            f"{relisted}\n"
+        )
 
     @property
     def synced(self) -> bool:

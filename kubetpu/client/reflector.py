@@ -76,6 +76,10 @@ class SharedInformer:
         self._handlers: list[Handler] = []
         self._lock = threading.Lock()
         self.synced = False
+        # bind deltas of the batched watch poll: rebuilt from the pod held,
+        # or refused for want of it (the kind is then relisted)
+        self.bind_deltas_applied = 0
+        self.bind_deltas_relisted = 0
 
     def add_handler(self, handler: Handler) -> None:
         with self._lock:
@@ -109,12 +113,31 @@ class SharedInformer:
         with self._lock:
             self._apply_locked(ev_type, key, obj)
 
-    def _apply_batch(self, events) -> None:
+    def _apply_batch(self, events) -> bool:
         """One watch frame's events dispatched under a SINGLE lock
-        acquisition."""
+        acquisition. A bind delta (``ev.bind`` = (uid, node), no object)
+        is rebuilt as the store built it: the pod held, its node set. No
+        such pod held (missing, another uid, a node already set): nothing
+        is guessed, that event and the rest of the frame are left
+        undelivered, and False tells the caller to relist the kind."""
         with self._lock:
             for ev in events:
-                self._apply_locked(ev.type, ev.key, ev.obj)
+                obj = ev.obj
+                if ev.bind is not None:
+                    uid, node = ev.bind
+                    held = self.store.get(ev.key)
+                    if held is None or held.uid != uid or held.node_name:
+                        self.bind_deltas_relisted += 1
+                        return False
+                    obj = held.with_node(node)
+                    self.bind_deltas_applied += 1
+                self._apply_locked(ev.type, ev.key, obj)
+        return True
+
+    def bind_delta_counts(self) -> tuple[int, int]:
+        """(applied, relisted) bind deltas so far."""
+        with self._lock:
+            return self.bind_deltas_applied, self.bind_deltas_relisted
 
     def _apply_locked(self, ev_type: str, key: str, obj: Any) -> None:
         if ev_type == DELETED:

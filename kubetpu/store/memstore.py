@@ -145,6 +145,18 @@ class WatchEvent:
     key: str
     obj: Any               # the object AFTER the change (before, for DELETED)
     resource_version: int
+    # a bind delta of the batched watch poll: (uid, node) of a pods ``bind``
+    # op, with ``obj`` None; the informer rebuilds the object it holds
+    bind: "tuple[str, str] | None" = None
+
+
+def _deletes_on_update(obj: Any) -> bool:
+    """An update that leaves the object terminating with no finalizers
+    completes its deletion (the finalizer gate of ``_update_locked``)."""
+    return (
+        getattr(obj, "deletion_timestamp", None) is not None
+        and not getattr(obj, "finalizers", ())
+    )
 
 
 class _PyCore:
@@ -450,6 +462,12 @@ class MemStore:
         # scheme-registry generation the cached wire bodies were encoded
         # under (None until the first body drain); a move flushes the ring
         self._body_gen: "int | None" = None
+        # the events that pods ``bind`` ops committed, by revision: rv →
+        # [key, uid, node, delta body per codec id]. Beside the ring and
+        # pruned as it compacts, so a record dies with its event; the ring,
+        # the WAL and the event's whole body are what any update writes
+        self._binds: dict[int, list] = {}
+        self._bind_rvs: collections.deque = collections.deque()
         self._wal = None
         self._wal_closed = False
         self._wal_lock = None
@@ -577,10 +595,7 @@ class MemStore:
     ) -> int:
         """The update body, caller holds the lock (shared by the single-op
         verb and ``bulk``; the caller notifies)."""
-        if (
-            getattr(obj, "deletion_timestamp", None) is not None
-            and not getattr(obj, "finalizers", ())
-        ):
+        if _deletes_on_update(obj):
             current, have_rv = self._core.get(kind, key)
             if current is None:
                 raise ConflictError(f"{kind}/{key}: gone")
@@ -643,6 +658,9 @@ class MemStore:
         by ``bind_refusal``, is committed with ``node_name`` set through
         the update body, so the WAL record, the watch event and the
         resourceVersion are what a get + CAS update of the same pod write.
+        The event's revision is recorded as a bind of (key, uid, node),
+        which the batched watch poll may send in place of the whole pod
+        (``events_body_since_bulk``).
 
         ``guard`` is asked once, under the SAME lock acquisition that
         applies the batch (the lock is reentrant, so it may read the
@@ -683,9 +701,12 @@ class MemStore:
                         if refused is not None:
                             out.append(refused)
                             continue
-                        rv = self._update_locked(
-                            kind, key, current.with_node(op["node"]), rv
-                        )
+                        bound = current.with_node(op["node"])
+                        rv = self._update_locked(kind, key, bound, rv)
+                        if not _deletes_on_update(bound):
+                            self._note_bind_locked(
+                                rv, key, current.uid, op["node"]
+                            )
                         out.append({"status": 200, "resourceVersion": rv})
                     elif verb == "get":
                         obj, rv = self._core.get(kind, key)
@@ -718,6 +739,44 @@ class MemStore:
             self._wal_commit_locked()
             self._lock.notify_all()
         return out
+
+    def _note_bind_locked(self, rv: int, key: str, uid: str,
+                          node: str) -> None:
+        """Record the event at ``rv`` as a bind of (key, uid, node), after
+        dropping the records whose events the ring has compacted: there
+        are never more records than events in the ring."""
+        compacted = self._core.compacted_through()
+        rvs, binds = self._bind_rvs, self._binds
+        while rvs and rvs[0] <= compacted:
+            del binds[rvs.popleft()]
+        rvs.append(rv)
+        binds[rv] = [key, uid, node, None, None]
+
+    def _splice_bind_deltas_locked(self, raw: dict, cursors: dict,
+                                   codec: str, cid: int) -> None:
+        """In the pods bucket of a body drain (``raw``, the core's answer
+        under this same lock round), put each bind op's delta body in the
+        place of its whole-pod body. The delta is encoded once per codec
+        and kept in the record."""
+        got = raw.get("pods")
+        rvs = self._bind_rvs
+        if not got or not rvs or rvs[-1] <= cursors["pods"]:
+            return
+        from ..api.codec import bind_delta_wire_bytes
+
+        bodies = got[0]
+        meta, _cursor = self._core.events_since("pods", cursors["pods"])
+        binds = self._binds
+        for i, ev in enumerate(meta):
+            rec = binds.get(ev[4])
+            if rec is None:
+                continue
+            body = rec[3 + cid]
+            if body is None:
+                body = rec[3 + cid] = bind_delta_wire_bytes(
+                    rec[0], rec[1], rec[2], ev[4], codec
+                )
+            bodies[i] = body
 
     def events_since_bulk(
         self, cursors: dict[str, int]
@@ -786,10 +845,15 @@ class MemStore:
                 raise CompactedError(str(e)) from None
 
     def events_body_since_bulk(
-        self, cursors: dict[str, int], codec: str = "json"
+        self, cursors: dict[str, int], codec: str = "json",
+        bind_deltas: bool = False,
     ) -> tuple[dict, int]:
         """Bulk form: ({kind: (bodies, cursor) | CompactedError}, drain
-        revision) — the batched watch poll's one-lock-round body drain."""
+        revision) — the batched watch poll's one-lock-round body drain.
+        ``bind_deltas``: each event a pods ``bind`` op committed comes as
+        its delta (``codec.bind_delta_wire_bytes``: key, uid, node, no
+        object) in place of the whole pod, for a client that holds the
+        pods and rebuilds them; every other event is its cached body."""
         enc, cid = _wire_encoder(codec)
         with self._lock:
             self._check_body_gen_locked()
@@ -797,6 +861,8 @@ class MemStore:
                 cursors, cid, enc
             )
             compacted = self._core.compacted_through()
+            if bind_deltas:
+                self._splice_bind_deltas_locked(raw, cursors, codec, cid)
         out: dict[str, Any] = {}
         for kind, res in raw.items():
             out[kind] = (
@@ -1067,6 +1133,8 @@ class MemStore:
                     "load_replica_snapshot on a non-follower store"
                 )
             self._core.load_snapshot(list(items), rv)
+            self._binds.clear()         # their events left with the ring
+            self._bind_rvs.clear()
             # the load renumbered seqs — invalidate every outstanding
             # continue token (they 410 into a fresh walk)
             self._list_gen = int.from_bytes(os.urandom(4), "big") or 1
