@@ -251,6 +251,13 @@ class PodAffinityEncodeStamp:
     filter_pods: int
     #: the batch's real pods with at least one weighted score slot
     score_pods: int
+    #: of ``filter_pods``, by the kind of slot (``affinity``,
+    #: ``anti_affinity``, ``existing_anti_affinity``): the pods with at
+    #: least one slot of that kind; a pod may count under several
+    filter_terms: dict
+    #: summed over the real pods, the nodes their existing pods'
+    #: anti-affinity refuses at the batch's start counts
+    existing_anti_nodes: int
 
 
 @dataclass(frozen=True)
@@ -1166,16 +1173,22 @@ def finalize_batch(
             groups=groups_of(),
         )
         if pa is not None:
+            t_end = time.perf_counter()
+            by_term = {
+                "affinity": (pa.fa_rows[:P] >= 0).any(axis=1),
+                "anti_affinity": (pa.ra_rows[:P] >= 0).any(axis=1),
+                "existing_anti_affinity": (pa.ea_rows[:P] >= 0).any(axis=1),
+            }
             pa_stamp = PodAffinityEncodeStamp(
-                start=t_pa, end=time.perf_counter(),
+                start=t_pa, end=t_end,
                 rows=pa.num_rows, domains=pa.max_domains,
                 slots=PA.kernel_slots(pa),
-                filter_pods=int((
-                    (pa.fa_rows[:P] >= 0).any(axis=1)
-                    | (pa.ra_rows[:P] >= 0).any(axis=1)
-                    | (pa.ea_rows[:P] >= 0).any(axis=1)
-                ).sum()),
+                filter_pods=int(np.logical_or.reduce(
+                    list(by_term.values())).sum()),
                 score_pods=int((pa.score_rows[:P] >= 0).any(axis=1).sum()),
+                filter_terms={term: int(used.sum())
+                              for term, used in by_term.items()},
+                existing_anti_nodes=pa.existing_anti_nodes(P),
             )
             # host numpy leaves — the single batched device_put below ships
             # the whole pytree in one dispatch instead of ~30

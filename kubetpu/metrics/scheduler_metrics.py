@@ -19,7 +19,9 @@ layouts (pkg/scheduler/metrics/metrics.go):
   spread_constrained_pods_total, spread_soft_constrained_pods_total and
   spread_policy_pods_total{policy};
   the host inter-pod affinity encode as plugin="InterPodAffinity",
-  extension_point="PreFilter", beside podaffinity_pods_total{work}
+  extension_point="PreFilter", beside podaffinity_pods_total{work},
+  podaffinity_filter_pods_total{term} and
+  podaffinity_existing_anti_nodes_total
 - schedule_attempts_total{result, profile}, preemption_attempts_total,
   preemption_victims (:267 ExponentialBuckets(1, 2, 7)), pending_pods{queue}
 """
@@ -63,6 +65,11 @@ PIPELINE_RESULTS = ("applied", "replayed")
 #: which inter-pod affinity kernel had work for a pod: the ONLY legal values
 #: of {work} on scheduler_podaffinity_pods_total.
 PODAFFINITY_WORK = ("filter", "score")
+
+#: which kind of inter-pod affinity filter slot a pod had: the ONLY legal
+#: values of {term} on scheduler_podaffinity_filter_pods_total.
+PODAFFINITY_FILTER_TERMS = ("affinity", "anti_affinity",
+                            "existing_anti_affinity")
 
 #: which node inclusion policy left nodes out of a spread count: the ONLY
 #: legal values of {policy} on scheduler_spread_policy_pods_total.
@@ -173,6 +180,27 @@ class SchedulerMetricsRegistry:
         for work in PODAFFINITY_WORK:
             # both on the first scrape, at zero: a delta meets no gap
             self.podaffinity_pods.labels(work)
+        self.podaffinity_filter_pods = r.counter(
+            "scheduler_podaffinity_filter_pods_total",
+            "Of the pods counted under work=\"filter\", those with at least "
+            "one filter slot of a kind, by the kind: affinity (an incoming "
+            "required affinity term), anti_affinity (an incoming required "
+            "anti-affinity term) or existing_anti_affinity (an existing "
+            "pod's required anti-affinity term that matches the pod). A pod "
+            "may count under several.",
+            labels=("term",),
+            declared={"term": PODAFFINITY_FILTER_TERMS},
+        )
+        for term in PODAFFINITY_FILTER_TERMS:
+            # all three on the first scrape, at zero: a delta meets no gap
+            self.podaffinity_filter_pods.labels(term)
+        self.podaffinity_existing_anti_nodes = r.counter(
+            "scheduler_podaffinity_existing_anti_nodes_total",
+            "Summed over the pods of the scheduling cycles, the nodes that "
+            "existing pods' required anti-affinity terms matching the pod "
+            "refuse it, at the counts the cycle started from: pods the same "
+            "batch places earlier are not in it.",
+        )
         self.pipeline_cycles = r.counter(
             "scheduler_pipeline_cycles_total",
             "Scheduling cycles whose device program was dispatched ahead "

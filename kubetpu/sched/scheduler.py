@@ -1401,6 +1401,9 @@ class Scheduler:
                         domains=stamp.domains, slots=stamp.slots,
                         filter_pods=stamp.filter_pods,
                         score_pods=stamp.score_pods,
+                        existing_anti_pods=stamp.filter_terms[
+                            "existing_anti_affinity"],
+                        existing_anti_nodes=stamp.existing_anti_nodes,
                     )
             # the host encode builds per-pod state ahead of filtering —
             # the PreFilter role in the reference's extension-point map
@@ -1673,6 +1676,11 @@ class Scheduler:
             )
             prom.podaffinity_pods.labels("score").inc(
                 batch.podaffinity_encode.score_pods
+            )
+            for term, pods in batch.podaffinity_encode.filter_terms.items():
+                prom.podaffinity_filter_pods.labels(term).inc(pods)
+            prom.podaffinity_existing_anti_nodes.inc(
+                batch.podaffinity_encode.existing_anti_nodes
             )
 
         try:

@@ -194,6 +194,26 @@ class PodAffinityTensors:
     def max_domains(self) -> int:
         return self.base_sums.shape[1]
 
+    def existing_anti_nodes(self, pods: int) -> int:
+        """Summed over the first ``pods`` pods, the nodes their EA slots
+        refuse at the START counts: some EA row r of the pod has
+        ``base_sums[r, node_domain[r, n]] > 0``. Counted once per distinct
+        EA slot tuple; in-batch increments are not in it."""
+        ea = self.ea_rows[:pods]
+        used = (ea >= 0).any(axis=1)
+        if not used.any():
+            return 0
+        slot_tuples, pods_with = np.unique(ea[used], axis=0,
+                                           return_counts=True)
+        total = 0
+        for slots, n in zip(slot_tuples, pods_with):
+            rows = slots[slots >= 0]
+            dom = self.node_domain[rows]
+            held = np.take_along_axis(self.base_sums[rows],
+                                      np.maximum(dom, 0), axis=1) > 0
+            total += int(n) * int(((dom >= 0) & held).any(axis=0).sum())
+        return total
+
 
 def encode_pod_affinity(
     nt: NodeTensors,
