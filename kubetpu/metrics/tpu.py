@@ -28,6 +28,10 @@ Metric set (labels ``engine`` = greedy | batched):
   ``scheduler_encode_cache_entries`` gauge — the template-keyed encode
   cache (state.encode_cache): a high steady-state hit rate is what keeps
   host encode off the cycle critical path
+- ``scheduler_encode_template_index_pods_total{result}`` counter
+  (``result`` = kept | keyed) — the bound pods the template-count index
+  (``EncodeCache.pod_groups``) walked and kept, or had to key: keyed
+  follows the pods that changed, not the pods on the nodes they touched
 - ``tpu_shard_host_to_device_transfer_bytes_total{engine,shard}`` counter
   and ``tpu_shard_device_resident_bytes{engine,shard}`` gauge — the
   per-shard view of the SHARDED resident node block (delta uploads are
@@ -44,6 +48,9 @@ import collections
 from dataclasses import asdict, dataclass
 
 from .registry import Registry, exponential_buckets
+
+#: what the template-count index did with a bound pod it walked
+TEMPLATE_INDEX_RESULTS = ("kept", "keyed")
 
 
 @dataclass(frozen=True)
@@ -180,6 +187,18 @@ class TPUBackendMetrics:
             "scheduler_encode_cache_entries",
             "Entries resident in the encode cache (LRU-bounded).",
         )
+        self.template_index_pods = r.counter(
+            "scheduler_encode_template_index_pods_total",
+            "Bound pods the encode cache's template-count index walked on "
+            "nodes whose generation moved, by what it did: kept (the pod it "
+            "had counted, or a copy with the same template fields) or keyed "
+            "(a pod new to the node, or one whose template fields changed).",
+            labels=("result",),
+            declared={"result": TEMPLATE_INDEX_RESULTS},
+        )
+        for result in TEMPLATE_INDEX_RESULTS:
+            # both on the first scrape, at zero: a delta meets no gap
+            self.template_index_pods.labels(result)
         # --- mesh-sharded assignment (parallel.mesh) ---------------------
         self.shard_transfer_bytes = r.counter(
             "tpu_shard_host_to_device_transfer_bytes_total",

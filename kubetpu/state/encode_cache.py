@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import collections
 from dataclasses import dataclass
+from operator import is_
 from typing import Callable
 
 import numpy as np
@@ -99,6 +100,13 @@ def template_key(pod) -> tuple:
         pod.labels, pod.namespace, pod.affinity,
         pod.topology_spread_constraints, pod.tolerations, pod.node_selector,
     )
+
+
+def _same_template(a, b) -> bool:
+    """Do two objects of one pod carry the very same ``template_key``
+    fields? A copy that changed only other fields (``Pod.with_node``)
+    does, so its template needs no deep hash."""
+    return all(map(is_, template_key(a), template_key(b)))
 
 
 class _BoundedMemo(dict):
@@ -208,21 +216,27 @@ class EncodeCache:
         # previous object's signatures
         self._pod_sigs = _BoundedMemo(max_entries * 8)
         # --- incremental template-group index ---------------------------
-        # per-node {group_key: count} + the node generation folded in, and
-        # the aggregated (N,) count vectors — pod_groups() refreshes only
-        # nodes whose generation moved (the snapshot's O(Δ) discipline
-        # extended to the template grouping pass)
+        # per node, the pods it counted, their gids and the node's
+        # {gid: count}; the node generation folded in; the aggregated (N,)
+        # count vectors — pod_groups() diffs only nodes whose generation
+        # moved, and keys only the pods it has not counted
         self._groups_nt: object | None = None
         self._groups_epoch = -1
         self._group_vecs: dict = {}    # gid -> (N,) int64
-        self._group_node: dict = {}    # node name -> {gid: count}
+        # node name -> ({uid: pod}, {uid: gid}, {gid: count})
+        self._group_node: dict = {}
         self._group_gens: dict = {}
-        # template keys interned to small ints: the deep (labels, ns,
-        # affinity) hash is paid once per pod OBJECT (uid-memoized,
-        # identity-checked), not once per pod per cycle
-        self._group_ids: dict = {}     # (labels, ns, affinity) -> gid
+        # template keys interned to small ints, shared by the bound-pod
+        # index and the pending pods' ids
+        self._group_ids: dict = {}     # template_key -> gid
         self._group_keys: list = []    # gid -> key
+        # pending pods' ids (pod_gids_for): uid-memoized, identity-checked
         self._pod_group_ids = _BoundedMemo(max_entries * 8)
+        # bound pods the index walks kept (same object, or a copy with the
+        # same template fields) and had to key: plain ints, mirrored into
+        # the prom registry by flush_metrics
+        self.index_pods = {"kept": 0, "keyed": 0}
+        self._flushed_index_pods = {"kept": 0, "keyed": 0}
         # --- persistent affinity / spread term caches -------------------
         self._ns_gen: int | None = None
         # (affinity, ns) -> tuple of source-row specs (state.podaffinity)
@@ -642,47 +656,66 @@ class EncodeCache:
         return True
 
     # ------------------------------------------------ template-group index
-    def group_id_of(self, pod) -> int:
-        """Small-int id of the pod's TEMPLATE ``(labels, namespace,
-        affinity)`` — the deep key hash is paid once per pod OBJECT
-        (uid-memoized, identity-checked), after which template membership
-        is an int."""
-        got = self._pod_group_ids.get(pod.uid)
-        if got is not None and got[0] is pod:
-            return got[1]
-        key = template_key(pod)
+    def _gid_of_key(self, key) -> int:
         gid = self._group_ids.get(key)
         if gid is None:
             gid = len(self._group_keys)
             self._group_ids[key] = gid
             self._group_keys.append(key)
+        return gid
+
+    def group_id_of(self, pod) -> int:
+        """Small-int id of a PENDING pod's template — the deep key hash is
+        paid once per pod OBJECT (uid-memoized, identity-checked), after
+        which template membership is an int. Bound pods are keyed by
+        ``pod_groups``' own per-node record instead."""
+        got = self._pod_group_ids.get(pod.uid)
+        if got is not None and got[0] is pod:
+            return got[1]
+        gid = self._gid_of_key(template_key(pod))
         self._pod_group_ids.put(pod.uid, (pod, gid))
         return gid
 
     def pod_groups(self, nt) -> dict:
-        """``collect_pod_groups(nt)``, maintained incrementally: only nodes
-        whose generation moved since the last call re-derive their
-        per-template counts (O(Δ nodes × pods-per-node) per cycle instead
-        of O(all assigned pods)). Rebuilt wholesale when the tensors were
-        replaced or a FULL-epoch flush landed (update/delete); scoped node
-        ADDS just grow the count vectors in place. Returned vectors are
-        LIVE index state — callers must not mutate them."""
+        """``collect_pod_groups(nt)``, maintained by pod deltas: each node
+        whose generation moved diffs its pods against the ones it counted,
+        by uid. The same object keeps its gid; a new object with the same
+        template fields (the assumed copy, the informer's ``with_node``
+        rebuild) keeps it too; only a pod not seen before, or one whose
+        template fields changed, is keyed; a uid that left is subtracted —
+        O(Δ pods) keys a cycle, whatever the pods per node. The count
+        vectors are rebuilt from the per-node counts when the tensors were
+        replaced or a FULL-epoch flush landed (update/delete), without
+        re-keying a pod; scoped node ADDS just grow them in place.
+        Returned vectors are LIVE index state — callers must not mutate
+        them."""
         if len(self._group_keys) > (1 << 16):
             # template-id interning ran away (per-pod-unique labels): reset
             # the whole index — gids are invalidated with it
             self._group_ids = {}
             self._group_keys = []
             self._pod_group_ids.clear()
-            self._groups_nt = None
-        if self._groups_nt is not nt or self._groups_epoch != self._full_epoch:
-            self._group_vecs = {}
             self._group_node = {}
+            self._groups_nt = None
+        N = nt.num_nodes
+        rebuild = (
+            self._groups_nt is not nt or self._groups_epoch != self._full_epoch
+        )
+        if rebuild:
+            # the node axis may have moved: zero the vectors and revisit
+            # every node, but keep what each node NAME counted — a pod's
+            # gid does not depend on the node axis
+            self._group_vecs = {}
             self._group_gens = {}
+            names = set(nt.node_names)
+            for name in [n for n in self._group_node if n not in names]:
+                del self._group_node[name]
             self._groups_nt = nt
             self._groups_epoch = self._full_epoch
-        N = nt.num_nodes
         gens = nt.node_gens
         vecs = self._group_vecs
+        seen_gens = self._group_gens
+        group_node = self._group_node
         # scoped node ADDS grow the node axis in place: extend the count
         # vectors with zeros (appended nodes' pods fold in via the gens
         # loop below — their generations are unseen)
@@ -691,29 +724,66 @@ class EncodeCache:
                 vecs[gid] = np.concatenate(
                     [vec, np.zeros(N - len(vec), dtype=np.int64)]
                 )
+        walked = keyed = 0
         for i, info in enumerate(nt.infos):
             name = nt.node_names[i]
             g = gens.get(name)
-            if self._group_gens.get(name) == g:
+            if seen_gens.get(name) == g:
                 continue
-            old = self._group_node.get(name)
-            if old:
-                for gid, c in old.items():
+            seen_gens[name] = g
+            pods = info.pods
+            entry = group_node.get(name)
+            if entry is None:
+                if not pods:
+                    continue
+                entry = group_node[name] = ({}, {}, {})
+            seen, gids, counts = entry
+            delta: dict = {}
+            # a node the cycle touched holds its other pods as the very
+            # objects it counted: only the rest are looked at
+            get = seen.get
+            for uid, q in [
+                (u, q) for u, q in pods.items() if get(u) is not q
+            ]:
+                p = get(uid)
+                seen[uid] = q
+                if p is not None and _same_template(p, q):
+                    continue
+                gid = self._gid_of_key(template_key(q))
+                keyed += 1
+                old = gids.get(uid)
+                gids[uid] = gid
+                if gid != old:
+                    if old is not None:
+                        delta[old] = delta.get(old, 0) - 1
+                    delta[gid] = delta.get(gid, 0) + 1
+            walked += len(pods)
+            if len(seen) > len(pods):
+                # some counted uid left the node: subtract it
+                for uid in [u for u in seen if u not in pods]:
+                    del seen[uid]
+                    gid = gids.pop(uid)
+                    delta[gid] = delta.get(gid, 0) - 1
+            for gid, d in delta.items():
+                if d:
+                    c = counts.get(gid, 0) + d
+                    if c:
+                        counts[gid] = c
+                    else:
+                        del counts[gid]
+            # a rebuild's vectors start at zero: fold the node's whole
+            # counts in, else only what changed
+            for gid, c in (counts if rebuild else delta).items():
+                if c:
                     vec = vecs.get(gid)
-                    if vec is not None:
-                        vec[i] -= c
-            new: dict = {}
-            for q in info.pods.values():
-                gid = self.group_id_of(q)
-                new[gid] = new.get(gid, 0) + 1
-            for gid, c in new.items():
-                vec = vecs.get(gid)
-                if vec is None:
-                    vec = np.zeros(N, dtype=np.int64)
-                    vecs[gid] = vec
-                vec[i] += c
-            self._group_node[name] = new
-            self._group_gens[name] = g
+                    if vec is None:
+                        vec = np.zeros(N, dtype=np.int64)
+                        vecs[gid] = vec
+                    vec[i] += c
+            if not seen:
+                del group_node[name]
+        self.index_pods["kept"] += walked - keyed
+        self.index_pods["keyed"] += keyed
         return {
             self._group_keys[gid]: v for gid, v in vecs.items() if v.any()
         }
@@ -762,6 +832,12 @@ class EncodeCache:
                 self._flushed_misses[kind] = self.misses[kind]
                 if self.metrics is not None:
                     self.metrics.encode_cache_misses.labels(kind).inc(d)
+        for result, n in self.index_pods.items():
+            d = n - self._flushed_index_pods[result]
+            if d:
+                self._flushed_index_pods[result] = n
+                if self.metrics is not None:
+                    self.metrics.template_index_pods.labels(result).inc(d)
         inv = self.invalidations - self._flushed_invalidations
         if inv:
             delta["invalidations"] = inv
