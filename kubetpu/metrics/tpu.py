@@ -32,6 +32,13 @@ Metric set (labels ``engine`` = greedy | batched):
   (``result`` = kept | keyed) — the bound pods the template-count index
   (``EncodeCache.pod_groups``) walked and kept, or had to key: keyed
   follows the pods that changed, not the pods on the nodes they touched
+- ``scheduler_cache_bind_confirmations_total{result}`` counter
+  (``result`` = kept | reapplied) — the confirmations of assumed pods the
+  scheduler cache took (``Cache.add_pod``): kept ends the assume and moves
+  no node's generation, reapplied removes and adds the pod again
+- ``scheduler_encode_node_rows_total`` counter — node rows the host encode
+  wrote into the node tensors: a refresh's rows whose generation moved,
+  every row of a build
 - ``tpu_shard_host_to_device_transfer_bytes_total{engine,shard}`` counter
   and ``tpu_shard_device_resident_bytes{engine,shard}`` gauge — the
   per-shard view of the SHARDED resident node block (delta uploads are
@@ -51,6 +58,8 @@ from .registry import Registry, exponential_buckets
 
 #: what the template-count index did with a bound pod it walked
 TEMPLATE_INDEX_RESULTS = ("kept", "keyed")
+#: what the scheduler cache did with the confirmation of an assumed pod
+BIND_CONFIRMATION_RESULTS = ("kept", "reapplied")
 
 
 @dataclass(frozen=True)
@@ -199,6 +208,23 @@ class TPUBackendMetrics:
         for result in TEMPLATE_INDEX_RESULTS:
             # both on the first scrape, at zero: a delta meets no gap
             self.template_index_pods.labels(result)
+        self.bind_confirmations = r.counter(
+            "scheduler_cache_bind_confirmations_total",
+            "Confirmations of assumed pods the scheduler cache took, by "
+            "what it did: kept (the bound pod equals the assumed one field "
+            "for field: the assume ends, the node's generation stays) or "
+            "reapplied (any difference: the pod is removed and added again "
+            "and its node is re-encoded).",
+            labels=("result",),
+            declared={"result": BIND_CONFIRMATION_RESULTS},
+        )
+        for result in BIND_CONFIRMATION_RESULTS:
+            self.bind_confirmations.labels(result)
+        self.encode_node_rows = r.counter(
+            "scheduler_encode_node_rows_total",
+            "Node rows the host encode wrote into the node tensors: in a "
+            "refresh the rows whose generation moved, in a build every row.",
+        )
         # --- mesh-sharded assignment (parallel.mesh) ---------------------
         self.shard_transfer_bytes = r.counter(
             "tpu_shard_host_to_device_transfer_bytes_total",
@@ -220,6 +246,15 @@ class TPUBackendMetrics:
         self.records: collections.deque[CycleRecord] = collections.deque(
             maxlen=max_records
         )
+
+    def set_host_encode_totals(self, node_rows: int,
+                               confirmations: dict) -> None:
+        """Hand over the totals the loop thread keeps without a lock (the
+        encoded node rows, ``Cache.bind_confirmations``): at scrape time,
+        as the loop's phase clock is."""
+        self.encode_node_rows.set_total(node_rows)
+        for result, n in confirmations.items():
+            self.bind_confirmations.labels(result).set_total(n)
 
     def record_cycle(
         self,

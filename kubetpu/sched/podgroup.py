@@ -411,7 +411,7 @@ def _coalesced_group_cycle(
         nominated=sched.nominator.entries(), prev_nt=sched._prev_nt,
         topology=sched.topology,
     )
-    sched._prev_nt = batch.node_tensors
+    sched._adopt_node_tensors(batch.node_tensors)
     params = rt.score_params(profile, batch.resource_names)
     device_batch = sched._apply_extenders(batch, pods)
     assignments, _ = sched._assign_device(device_batch, params)
@@ -477,7 +477,7 @@ def _placement_group_cycle(sched: "Scheduler", e: GroupEntry) -> tuple[int, int]
         nominated=sched.nominator.entries(), prev_nt=sched._prev_nt,
         topology=sched.topology,
     )
-    sched._prev_nt = batch.node_tensors
+    sched._adopt_node_tensors(batch.node_tensors)
     gen = generate_placements(
         sched, e, batch.node_names, batch.num_nodes,
         batch.device.alloc.shape[0],

@@ -105,6 +105,8 @@ class SchedulerMetrics:
     # in bind_errors (a conflict IS a failed bind)
     bind_conflicts: int = 0
     cycles: int = 0
+    # node rows the host encode wrote (scheduler_encode_node_rows_total)
+    encode_node_rows: int = 0
     # pipelined cycles whose dispatched device result had to be discarded
     # and recomputed because cluster state changed under them (node update /
     # foreign pod event between dispatch and sync) — replay preserves exact
@@ -157,6 +159,9 @@ class SchedulerMetrics:
 
     def note_bind_conflict(self) -> None:
         self.bind_conflicts += 1
+
+    def note_encode_node_rows(self, n: int) -> None:
+        self.encode_node_rows += n
 
 
 class Scheduler:
@@ -960,7 +965,7 @@ class Scheduler:
                 mesh=self.mesh,
                 topology=self.topology,
             )
-            self._prev_nt = batch.node_tensors
+            self._adopt_node_tensors(batch.node_tensors)
             params = rt.score_params(self.profile, batch.resource_names)
             a, _ = self._assign_device(batch.device, params)
             jax.device_get(a)  # block until compiled + executed
@@ -1215,7 +1220,7 @@ class Scheduler:
             # stage 1 is an optimization: any failure falls back to the
             # launch-time full encode (which surfaces real bugs loudly)
             return None
-        self._prev_nt = sb.nt
+        self._adopt_node_tensors(sb.nt)
         if sb.assume_coupled:
             return None
         return sb
@@ -1228,7 +1233,8 @@ class Scheduler:
         spread/port tensors without moving the rows), a replaced node
         object (labels/taints/images may differ), or a node set/order
         change (tensor rebuild). Bind confirmations of our own assumed
-        pods re-encode to identical rows/content and do NOT flag."""
+        pods move no node (``Cache.add_pod`` keeps an equal one), and one
+        that differs only where no row or content reads it does NOT flag."""
         from ..state.encoder import encode_snapshot
 
         self._snapshot = self.cache.update_snapshot(self._snapshot)
@@ -1246,7 +1252,13 @@ class Scheduler:
             or new_nt.last_pods_mutated
         ):
             self._inflight_stale = True
-        self._prev_nt = new_nt
+        self._adopt_node_tensors(new_nt)
+
+    def _adopt_node_tensors(self, nt) -> None:
+        """Hold ``nt``, just encoded, as the next encode's ``prev`` and
+        count the node rows that encode wrote."""
+        self.metrics.note_encode_node_rows(len(nt.last_dirty_rows))
+        self._prev_nt = nt
 
     def _complete_inflight(self) -> dict[str, int]:
         """Sync the in-flight cycle and apply its results — or, when host
@@ -1411,7 +1423,7 @@ class Scheduler:
             prom.framework_extension_point_duration.labels(
                 "PreFilter", "Success", profile.name
             ).observe(encode_s)
-            self._prev_nt = batch.node_tensors
+            self._adopt_node_tensors(batch.node_tensors)
             with self.tracer.span("extenders", cycle=cycle_id):
                 device_batch = self._apply_extenders(batch, pods)
             params = rt.score_params(profile, batch.resource_names)
@@ -2082,6 +2094,8 @@ class Scheduler:
             self.dispatcher.stats(), self.dispatcher.worker_clock())
         self.metrics.prom.set_loop_clock(
             self.loop_clock.snapshot(), self.loop_clock.cpu_snapshot())
+        self.metrics.tpu.set_host_encode_totals(
+            self.metrics.encode_node_rows, self.cache.bind_confirmations)
         text = self.metrics.prom.expose()
         if self.recorder is not None and hasattr(
             self.recorder, "metrics_text"

@@ -195,7 +195,7 @@ class NodeTensors:
     # "freshly (re)built — everything needs a full upload"
     pending_device_rows: set | None = field(repr=False, default=None)
     # outcome of the LAST encode_snapshot call on this object: which rows it
-    # re-encoded, whether any re-encoded row's VALUES actually differ from
+    # re-encoded (every row, where that call built it), whether any re-encoded row's VALUES actually differ from
     # what was there before (a bind confirmation replaces a pod with
     # identical accounting → rows re-encode to the same values), and whether
     # any node OBJECT was replaced (labels/taints/images may differ — facts
@@ -463,6 +463,7 @@ def encode_snapshot(
         src_token=snapshot.cache_token,
         src_order_epoch=snapshot.order_epoch,
         gens_watermark=snapshot.cache_watermark,
+        last_dirty_rows=tuple(range(N)),
     )
     for i, info in enumerate(infos):
         _encode_node_row(nt, i, info, ridx)

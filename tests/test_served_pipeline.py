@@ -291,7 +291,13 @@ def test_the_second_refresh_of_a_call_re_encodes_no_row():
     try:
         post(st, "pod-default", 0, 3 * BATCH)
         once()
-        once()      # the pump brought confirmations: the first refresh works
+        once()
+        # a pod another actor bound: the pump brings it and the first
+        # refresh re-encodes its node (the loop's own confirmations move
+        # no node)
+        st.create(PODS, "sched-0/foreign", make_pod(
+            "foreign", namespace="sched-0", cpu_milli=100,
+            node_name="scheduler-perf-1"))
         dirty = []
         refresh = s._refresh_host_state
 
@@ -306,6 +312,75 @@ def test_the_second_refresh_of_a_call_re_encodes_no_row():
         assert dirty[0] > 0 and dirty[1] == 0, dirty
     finally:
         s.close()
+
+
+# ------------------------------------------- bind confirmations, served
+
+#: the oracle's view of ``C.Profile()`` for these templates (the parity
+#: rules of basic-5k and topologyspread-5k)
+ORACLE = {"pod-default": dict(w_fit=1, w_balanced=1),
+          "pod-with-topology-spreading": dict(w_fit=1, w_balanced=1,
+                                              w_spread=2, check_spread=True)}
+CONFIRMED = "scheduler_cache_bind_confirmations_total"
+
+
+@pytest.mark.parametrize("template", list(ORACLE))
+def test_every_bind_confirmation_of_a_served_run_is_kept(template):
+    """320 pods bound through an apiserver, each bind echoed to the
+    informer as its delta and rebuilt there: the pod the cache gets back
+    equals the pod the loop assumed in every case, the placements are the
+    oracle's, and no cycle dispatched ahead is replayed."""
+    from benchmark.harness import check
+    from kubetpu.apiserver import RemoteStore
+    from kubetpu.state import Cache
+
+    from . import oracle
+
+    pods, batch = 320, 32
+    st = cluster(nodes=24)
+    srv = APIServer(st).start()
+    s = None
+    try:
+        remote = RemoteStore(srv.url)
+        s = Scheduler(
+            StoreClient(remote), profile=C.Profile(), dispatcher_workers=0,
+            clock=FakeClock(), max_batch=batch, pipeline=True,
+            recorder=EventRecorder(remote, "kubetpu-scheduler"),
+        )
+        informers = SchedulerInformers(remote, s)
+        informers.start()
+        once = cli._scheduler_iteration(s, informers, lambda: True)
+        pm = parse_prometheus_text(s.metrics_text())
+        assert pm.value(CONFIRMED, result="kept") == 0
+        assert pm.value(CONFIRMED, result="reapplied") == 0
+        post(st, template, 0, pods)
+        for _ in range(pods // batch + 6):
+            once()
+        once()      # the last binds' echoes
+        assert len(bound(st)) == pods
+        assert not s.cache._assumed and s._inflight is None
+        (r,) = [r for r in informers._reflectors if r.informer.kind == PODS]
+        assert r.informer.bind_delta_counts() == (pods, 0)
+        pm = parse_prometheus_text(s.metrics_text())
+        assert pm.value(CONFIRMED, result="kept") == pods
+        assert pm.value(CONFIRMED, result="reapplied") == 0
+        assert cycles(s, "replayed") == 0 and cycles(s, "applied") > 0
+    finally:
+        if s is not None:
+            s.close()
+        srv.close()
+    nodes = [n for _, n in st.list(NODES)[0]]
+    stored = st.list(PODS)[0]
+    assert check.validity_problems(nodes, stored) == []
+    cache = Cache()
+    for n in nodes:
+        cache.add_node(n)
+    cache.add_pod(st.get(PODS, "sched-0/seed")[0])
+    infos = [i.clone() for i in cache.update_snapshot().node_infos()]
+    order = [TEMPLATES[template](f"p{j}", "sched-1") for j in range(pods)]
+    want = oracle.greedy(infos, order, **ORACLE[template])
+    got = bound(st)
+    assert [got[f"sched-1/p{j}"] for j in range(pods)] == want
 
 
 # ------------------------------------------------------- the entry point

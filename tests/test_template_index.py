@@ -91,6 +91,21 @@ class Cluster:
         for p, q in zip(pending, assumed):
             self.cache.add_pod(p.with_node(q.node_name))
 
+    def run(self, pods):
+        """The node agent's status update: each pod's cached object replaced
+        by a copy in phase Running, its template fields the very same."""
+        for p in pods:
+            old = self.cache._pods[p.uid]
+            self.cache.update_pod(old, dataclasses.replace(old,
+                                                           phase="Running"))
+
+    def relist(self, pods):
+        """A relist's Add of each bound pod, not assumed: a new object of
+        the same pod on the same node."""
+        for p in pods:
+            old = self.cache._pods[p.uid]
+            self.cache.add_pod(old.with_node(old.node_name))
+
 
 def renamed(pod, name):
     """Another pod of ``pod``'s template, as ``Pod.with_node`` copies one."""
@@ -117,7 +132,14 @@ def test_every_step_of_a_cluster_history_matches_the_full_pass():
 
     c.confirm(pending, assumed)                 # the with_node copies
     assert c.step() == 0
-    assert c.ec.index_pods["kept"] > 0
+    # equal to the assumed pods: the cache moved no node, nothing walked
+    assert c.ec.index_pods["kept"] == 0
+
+    # new objects with the same templates, on every node: kept unkeyed
+    c.run(assumed[:20])
+    c.relist(assumed[20:])
+    assert c.step() == 0
+    assert c.ec.index_pods["kept"] == len(pending)
 
     # a bound pod replaced under its uid with new labels moves template
     for j in range(3):
@@ -125,7 +147,9 @@ def test_every_step_of_a_cluster_history_matches_the_full_pass():
         new = dataclasses.replace(
             old, labels=t.freeze_map({"color": "green"}))
         c.cache.update_pod(old, new)
+    kept = c.ec.index_pods["kept"]
     assert c.step() == 3
+    assert c.ec.index_pods["kept"] > kept       # their nodes' other pods
     green = [k for k in c.ec.pod_groups(c.nt) if dict(k[0]) == {"color":
                                                                 "green"}]
     assert len(green) == 1
@@ -166,6 +190,7 @@ def test_the_keys_follow_the_pods_bound_past_the_old_memo():
     bound, step, pending, assumed = 0, 0, [], []
     while bound < OLD_MEMO + 5000:
         c.confirm(pending, assumed)             # last step's, confirmed
+        c.run(assumed)                          # and Running: new objects
         proto = protos[step % 2]
         pending = [renamed(proto, f"s{step}-{j}") for j in range(1024)]
         on = [names[(step * 8 + k) % nodes] for k in range(8)]
@@ -249,6 +274,7 @@ def test_the_counter_reaches_the_metrics_page():
     assumed = c.assume(pending, ["n0"])
     c.step()
     c.confirm(pending, assumed)
+    c.run(assumed)                             # new objects, same template
     c.step()
     c.ec.flush_metrics()
     page = metrics.registry.expose()

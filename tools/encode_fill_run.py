@@ -15,9 +15,14 @@ The cell's init pods and ``prefill`` more pods of the measured template
 per fill band (the cycles split into ``bands`` runs of equal length, the
 first cycle, which builds the template index from nothing, apart), the bound
 pods at the band's first and last cycle and the mean milliseconds a cycle of
-the whole encode, of ``encode_spread`` and of ``encode_pod_affinity`` (the
-``finalize_batch`` stamps; 0 where the encoder did not run) and of
-``EncodeCache.pod_groups``; and the seconds ``pod_groups`` took in all.
+the snapshot refresh (``Cache.update_snapshot``), of the whole encode, of
+``encode_spread`` and of ``encode_pod_affinity`` (the ``finalize_batch``
+stamps; 0 where the encoder did not run), of ``EncodeCache.pod_groups`` and
+of the confirmations (the ``Cache.add_pod`` loop), and the node rows the
+encode wrote a cycle (``NodeTensors.last_dirty_rows``); the seconds
+``pod_groups`` took in all; the node rows written per pod assumed after the
+first cycle; and what ``Cache.add_pod`` did with the confirmations, where
+the cache counts it.
 Run from the root of a checkout. It runs no device program: the times are
 the host's (the batch's transfer to the device is in the whole encode, as it
 is in the loop's ``encode`` span), so the chip's host gives the chip's.
@@ -102,18 +107,23 @@ def main() -> None:
     rows = []
     for c in range(args.cycles):
         pending = [template(f"m{c}-{j}", ns) for j in range(args.batch)]
+        t0 = time.perf_counter()
         snap = cache.update_snapshot(snap)
+        snap_ms = 1e3 * (time.perf_counter() - t0)
         before = index_s[0]
         t0 = time.perf_counter()
         batch = rt.encode_batch(snap, pending, profile, prev_nt=prev_nt,
                                 cache=ec, pad_pods=args.batch)
-        rows.append((bound, 1e3 * (time.perf_counter() - t0),
-                     stamp_ms(batch.spread_encode),
-                     stamp_ms(batch.podaffinity_encode),
-                     1e3 * (index_s[0] - before)))
+        encode_ms = 1e3 * (time.perf_counter() - t0)
         prev_nt = batch.node_tensors
+        written = len(prev_nt.last_dirty_rows)
+        t0 = time.perf_counter()
         for p, node in zip(held, placed):      # the informer's confirmation
             cache.add_pod(p.with_node(node))
+        rows.append((bound, encode_ms, stamp_ms(batch.spread_encode),
+                     stamp_ms(batch.podaffinity_encode),
+                     1e3 * (index_s[0] - before), snap_ms,
+                     1e3 * (time.perf_counter() - t0), written))
         placed = [hosts[(cursor + j) % len(hosts)]
                   for j in range(len(pending))]
         cursor += len(pending)
@@ -133,8 +143,10 @@ def main() -> None:
         bands.append({
             "bound_from": band[0][0], "bound_to": band[-1][0],
             "cycles": len(band),
+            "snapshot_ms": mean(band, 5),
             "encode_ms": mean(band, 1), "spread_ms": mean(band, 2),
             "affinity_ms": mean(band, 3), "pod_groups_ms": mean(band, 4),
+            "confirm_ms": mean(band, 6), "node_rows": mean(band, 7),
         })
     index_pods = getattr(ec, "index_pods", None)
     print(json.dumps({
@@ -144,6 +156,11 @@ def main() -> None:
                   "pod_groups_ms": rows[0][4]} if rows else None,
         "bands": bands,
         "pod_groups_s": index_s[0],
+        # cycle c's encode holds the assumes of cycle c - 1
+        "node_rows_per_pod": (sum(r[7] for r in steady)
+                              / (len(steady) * args.batch)
+                              if steady else None),
+        "confirmations": getattr(cache, "bind_confirmations", None),
         "index_pods": dict(index_pods) if index_pods is not None else None,
         "device": kubetpu.device_stamp(),
     }), flush=True)
