@@ -23,10 +23,6 @@ Stages, each at the size upstream publishes (5000 nodes):
            5000 nodes + 3000 pods over REST, every pod read back bound
            exactly once, SIGTERM cascade, clean exit; only the scheduler
            child may hold the chip
-  served   ``run_workload_full_stack`` in one process: SchedulingBasic
-           5000Nodes_10000Pods (greedy) and SchedulingPodAffinity
-           5000Nodes_5000Pods (batched) — all bound, the store agrees pod
-           for pod, no compile in the measured window, no explain failure
   parity   device greedy engine vs ``tests/oracle.py``, pod for pod, on a
            seeded 5000-node cluster: fit+balanced, spread, inter-pod
            affinity, and a nominated-fit batch with GiB-scale memory — where
@@ -34,9 +30,15 @@ Stages, each at the size upstream publishes (5000 nodes):
   compile  every jitted device program lowered, compiled and executed once
            at 5000-node width; then the SAME stage again in a new process,
            showing the warm set-up time against the cold one
-  mesh     >1 device only: SchedulingBasic 15000Nodes served with mesh on —
-           bound map equal to the single-device run's, resident block
+  mesh     >1 device only: SchedulingBasic 15000Nodes through the
+           scheduler loop in one process (``perf.run_workload``) with mesh
+           on — bound map equal to the single-device run's, resident block
            spanning every chip ("not run (1 device)" otherwise)
+
+The served path at 5000 nodes (``kubetpu scheduler`` against its
+apiserver, every pod bound once, the store agreeing pod for pod, parity
+with the oracle) is what every benchmark cell's ``correct`` holds, so no
+stage here repeats it.
 """
 
 from __future__ import annotations
@@ -55,12 +57,6 @@ TOTAL_BUDGET_S = 1150.0
 NODES = 5000
 UP_PODS = 3000
 SEED = 20260926
-#: (case, workload, engine) the served stage drives, at upstream's scale:
-#: misc/performance-config.yaml:59 and affinity/performance-config.yaml:135
-SERVED = (
-    ("SchedulingBasic", "5000Nodes_10000Pods", "greedy"),
-    ("SchedulingPodAffinity", "5000Nodes_5000Pods", "batched"),
-)
 
 #: set by each child before its first line (the parent hands the probe's
 #: answer to the one child that must not ask JAX itself)
@@ -139,9 +135,8 @@ def main() -> int:
     cold_warm: list = []
     # (stage, its arguments, the share of what is left of the budget it may
     # take at most)
-    plan = [("up", (json.dumps(DEVICE),), 0.35), ("served", (), 0.6),
-            ("parity", (), 0.6), ("compile", (), 0.8),
-            ("compile", ("warm",), 1.0)]
+    plan = [("up", (json.dumps(DEVICE),), 0.35), ("parity", (), 0.6),
+            ("compile", (), 0.8), ("compile", ("warm",), 1.0)]
     for stage, args, share in plan:
         left = TOTAL_BUDGET_S - (time.monotonic() - t_start)
         rc, recs = _run_child(stage, max(left * share, 30.0), *args)
@@ -261,6 +256,32 @@ def stage_probe() -> bool:
 
 # ------------------------------------------------------------------ stage up
 
+def _scrape_metrics(url: str):
+    """One component's /metrics, parsed (None if it cannot be read)."""
+    import urllib.request
+
+    from kubetpu.metrics.textparse import parse_prometheus_text
+
+    try:
+        with urllib.request.urlopen(url.rstrip("/") + "/metrics",
+                                    timeout=10) as resp:
+            return parse_prometheus_text(resp.read().decode())
+    except Exception:
+        return None
+
+
+def _sum_samples(parsed, name: str, **labels) -> float:
+    """Sum of every sample of family ``name`` whose label set contains
+    ``labels`` (a sum() over a PromQL instant selector)."""
+    if parsed is None:
+        return 0.0
+    want = {(k, str(v)) for k, v in labels.items()}
+    return sum(
+        s.value for s in parsed.samples(name)
+        if s.name == name and want <= set(s.labels)
+    )
+
+
 def _maps_libtpu(pid: int) -> bool:
     """Whether process ``pid`` has libtpu mapped — it is loaded when, and
     only when, a process initialises the TPU backend."""
@@ -281,11 +302,7 @@ def stage_up(device_json: str) -> bool:
     from kubetpu.client.informers import NODES as NODES_KIND, PODS
     from kubetpu.launch.banner import parse_banner
     from kubetpu.perf import workloads as W
-    from kubetpu.perf.runner import (
-        _bulk_create,
-        _scrape_metrics,
-        _sum_samples,
-    )
+    from kubetpu.perf.runner import _bulk_create
 
     DEVICE.update(json.loads(device_json))
     problems: list[str] = []
@@ -405,59 +422,6 @@ def stage_up(device_json: str) -> bool:
               "post to last bind and includes the 5000-node compiles",
          jax=jax.__version__)
     return not problems
-
-
-# -------------------------------------------------------------- stage served
-
-def stage_served() -> bool:
-    _own_the_chip()
-    meter = CompileMeter()
-    from kubetpu.native import build_status
-    from kubetpu.perf.runner import run_workload_full_stack
-
-    ok_all = True
-    for case, workload, engine in SERVED:
-        mark = meter.mark()
-        t0 = time.perf_counter()
-        # warmup=False: the runner's own warm-up compiles all eight rungs of
-        # the bucket ladder (minutes, cold, per engine). The workload's init
-        # phase compiles the one rung these batches use; where the measured
-        # phase still meets a program variant of its own (PodAffinity does,
-        # on the chip), the workload runs once more in this process and
-        # THAT pass is the one held to zero compiles
-        passes = 0
-        while True:
-            passes += 1
-            r = run_workload_full_stack(case, workload, engine=engine,
-                                        timeout_s=600.0, warmup=False)
-            if not r.compile_misses or passes == 2:
-                break
-            emit("served", ok=r.scheduled == r.measure_pods, case=case,
-                 workload=workload, engine=engine, scheduled=r.scheduled,
-                 compile_misses_in_window=r.compile_misses,
-                 note="compiling pass; the next one is the one judged",
-                 setup_s=round(time.perf_counter() - t0, 1), run_s=0.0)
-        wall = time.perf_counter() - t0
-        explain_failures = r.metrics_snapshot["explain_kernel_failures"]
-        ok = (
-            r.scheduled == r.measure_pods
-            and r.binding_parity == r.measure_pods
-            and r.compile_misses == 0
-            and explain_failures == 0
-            and not build_status().startswith("failed")
-        )
-        ok_all = ok_all and ok
-        emit("served", summary=True, ok=ok, case=case, workload=workload,
-             engine=engine, scheduled=r.scheduled,
-             measure_pods=r.measure_pods, binding_parity=r.binding_parity,
-             compile_misses_in_window=r.compile_misses,
-             explain_kernel_failures=explain_failures,
-             native_build=build_status(), cycles=r.cycles, passes=passes,
-             setup_s=round(wall - r.duration_s, 1),
-             run_s=round(r.duration_s, 2),
-             smoke_pods_per_s=round(r.throughput, 1),
-             note="smoke number, not a benchmark", **meter.since(mark))
-    return ok_all
 
 
 # -------------------------------------------------------------- stage parity
@@ -884,20 +848,18 @@ class _Uncompiled:
 def stage_mesh() -> bool:
     _own_the_chip()
     meter = CompileMeter()
-    from kubetpu.perf.runner import run_workload_full_stack
+    from kubetpu.perf import run_workload
 
     runs = {}
     for mesh in (None, "on"):
         mark = meter.mark()
         t0 = time.perf_counter()
-        r = run_workload_full_stack("SchedulingBasic", "15000Nodes",
-                                    engine="greedy", mesh=mesh,
-                                    timeout_s=600.0, warmup=False)
+        r = run_workload("SchedulingBasic", "15000Nodes", engine="greedy",
+                         mesh=mesh, timeout_s=600.0, warmup=False)
         runs[mesh] = r
-        emit("mesh", ok=r.scheduled == r.measure_pods
-             and r.binding_parity == r.measure_pods,
+        emit("mesh", ok=r.scheduled == r.measure_pods,
              mesh=mesh or "off", scheduled=r.scheduled,
-             measure_pods=r.measure_pods, binding_parity=r.binding_parity,
+             measure_pods=r.measure_pods,
              bindings_digest=r.bindings_digest,
              mesh_shape=list(r.mesh_shape),
              mesh_placement=r.mesh_placement,
@@ -929,8 +891,8 @@ def stage_mesh() -> bool:
 
 
 STAGES = {
-    "probe": stage_probe, "up": stage_up, "served": stage_served,
-    "parity": stage_parity, "compile": stage_compile, "mesh": stage_mesh,
+    "probe": stage_probe, "up": stage_up, "parity": stage_parity,
+    "compile": stage_compile, "mesh": stage_mesh,
 }
 
 if __name__ == "__main__":

@@ -24,7 +24,7 @@ Subpackages
 - ``sched``:     scheduling queue + batch scheduling/binding cycles.
 - ``bridge``:    extender-webhook wire protocol server (the integration seam
                  with a real kube-scheduler, ``pkg/scheduler/extender.go``).
-- ``perf``:      scheduler_perf-style workload harness.
+- ``perf``:      scheduler_perf-style workloads, driven in one process (tests).
 - ``utils``:     metrics, feature gates, logging.
 
 Integer-exact score parity with the reference requires 64-bit resource
@@ -43,10 +43,10 @@ if not os.environ.get("KUBETPU_NO_X64"):
     jax.config.update("jax_enable_x64", True)
 
 # ONE placement rule for JAX's persistent compilation cache, for every entry
-# point (chip_smoke.py, ``python -m kubetpu ...`` and
-# ``python -m kubetpu.perf`` all import this package first): whoever launches
-# the process places the cache through JAX_COMPILATION_CACHE_DIR, which jax
-# reads into its config at import, and then nothing is set here. Otherwise
+# point (chip_smoke.py and ``python -m kubetpu ...`` both import this
+# package first): whoever launches the process places the cache through
+# JAX_COMPILATION_CACHE_DIR, which jax reads into its config at import, and
+# then nothing is set here. Otherwise
 # the cache lives at the FIXED path <checkout>/.jax_cache — a later process
 # only finds the entries at the same path, so never a temporary or
 # pid/time-derived one. Assigning the environment variable here would come

@@ -107,8 +107,7 @@ class RemoteStore:
         self._reconnect_lock = threading.Lock()
         self.reconnect_counts: dict[str, int] = {}
         # paged-relist evidence: cumulative totals plus the last walk's
-        # shape (pages, wire bytes, largest page) — run_list_scaling's
-        # pages/relist and bytes/relist read from here
+        # shape (pages, wire bytes, largest page)
         self.relist_stats: dict[str, int] = {
             "relists": 0, "pages": 0, "bytes": 0, "max_page_bytes": 0,
         }

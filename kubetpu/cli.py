@@ -16,7 +16,6 @@ Commands (the control-plane binaries + tooling):
 - ``serve``               the extender webhook bridge from a config file
 - ``get`` / ``apply`` / ``delete``   kubectl-style object access
 - ``check-config``        decode + validate a config file, loudly
-- ``perf``                the scheduler_perf harness (kubetpu.perf)
 - ``explain``             render a pod's scheduling flight-recorder record
                           (timeline + why-node-won / why-filtered) from a
                           scheduler's /debug/flightrecorder or a JSON dump
@@ -360,14 +359,14 @@ def cmd_collector(args) -> int:
 
 def cmd_watch_driver(args) -> int:
     """``kubetpu watch-driver``: N concurrent pod watchers against an
-    apiserver, as ONE dedicated process — the unit the mp wire ladder
-    spreads its 200-watcher fan-out load over (M driver processes instead
-    of 200 threads sharing the measuring process's GIL)."""
+    apiserver, as ONE dedicated process — the unit ``kubetpu up
+    --watch-fanout N --fanout-procs M`` spreads its fan-out load over (M
+    driver processes instead of N threads sharing one process's GIL)."""
+    from .launch import WatchFanout
     from .launch.banner import emit_banner
-    from .perf.runner import _WatchFanout
 
     stop = _install_stop_event()
-    fanout = _WatchFanout(args.server, args.wire, args.watchers)
+    fanout = WatchFanout(args.server, args.wire, args.watchers)
     emit_banner(
         "watch-driver", server=args.server, watchers=args.watchers,
         wire=args.wire,
@@ -2011,7 +2010,8 @@ def build_parser() -> argparse.ArgumentParser:
     wd = sub.add_parser(
         "watch-driver",
         help="run N concurrent pod watchers against an apiserver as one "
-             "dedicated process (the mp wire ladder's fan-out unit)",
+             "dedicated process (the unit kubetpu up --fanout-procs "
+             "spreads its watchers over)",
     )
     wd.add_argument("--server", required=True, help="API server base URL")
     wd.add_argument("--watchers", type=int, default=50)
@@ -2086,12 +2086,6 @@ def build_parser() -> argparse.ArgumentParser:
     ver = sub.add_parser("version", help="print version")
     ver.set_defaults(fn=cmd_version)
 
-    perf = sub.add_parser(
-        "perf", help="scheduler_perf harness (see python -m kubetpu.perf)"
-    )
-    perf.add_argument("rest", nargs=argparse.REMAINDER)
-    perf.set_defaults(fn=None)
-
     analyze = sub.add_parser(
         "analyze",
         help="graftcheck static-analysis suite "
@@ -2111,10 +2105,6 @@ def main(argv: Sequence[str] | None = None) -> int:
 
         return analyze_main(raw[1:]) or 0
     args = build_parser().parse_args(argv)
-    if args.command == "perf":
-        from .perf.__main__ import main as perf_main
-
-        return perf_main(args.rest) or 0
     return args.fn(args)
 
 

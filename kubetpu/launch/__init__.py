@@ -6,9 +6,9 @@ processes instead: a readiness-banner contract (``banner``), a process
 supervisor owning the full child lifecycle (``supervisor`` — THE
 ``subprocess.Popen`` seam, pinned by graftcheck PS001), and the standard
 topology builder (``cluster`` — apiserver + N scheduler replicas +
-optional collector + watch-fanout drivers), shared verbatim by the tier-1
-multi-process smoke, ``kubetpu up``, and the perf runner's multi-process
-drivers.
+optional collector + watch-fanout drivers, each driver carrying a
+``fanout.WatchFanout``), shared verbatim by the tier-1 multi-process smoke
+and ``kubetpu up``.
 """
 
 from .banner import (  # noqa: F401
@@ -32,3 +32,4 @@ from .cluster import (  # noqa: F401
     scheduler_spec,
     watch_driver_spec,
 )
+from .fanout import WatchFanout  # noqa: F401

@@ -7,11 +7,9 @@ together through readiness banners (nobody pre-picks a port):
 
     collector?  ──►  apiserver  ──►  scheduler r0..r{N-1}  ──►  drivers
 
-``kubetpu up`` serves this topology interactively; the perf runner's
-``run_workload_multiprocess`` drives a workload against it and joins on
-the store-verified binding parity. Both go through the same ChildSpec
-builders, so the tier-1 smoke, the CLI, and the perf runner exercise ONE
-spawn/readiness/shutdown path (the PR-13 dedup contract).
+``kubetpu up`` serves this topology interactively and the tier-1
+multi-process smoke drives it; both go through the same ChildSpec
+builders, so they exercise ONE spawn/readiness/shutdown path.
 """
 
 from __future__ import annotations

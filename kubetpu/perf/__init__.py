@@ -1,12 +1,10 @@
 """scheduler_perf analog: op-list workloads driving the real scheduler loop
-(test/integration/scheduler_perf)."""
+in one process (test/integration/scheduler_perf) — the tests' harness. A
+speed is stated by the benchmark (``BENCHMARK.json``), not from here."""
 
 from .runner import (
     WorkloadResult,
-    run_label,
     run_workload,
-    run_workload_full_stack,
-    run_workload_multiprocess,
     run_workload_trace,
 )
 from .workloads import (
@@ -24,9 +22,6 @@ __all__ = [
     "TraceProfile",
     "Workload",
     "WorkloadResult",
-    "run_label",
     "run_workload",
-    "run_workload_full_stack",
-    "run_workload_multiprocess",
     "run_workload_trace",
 ]

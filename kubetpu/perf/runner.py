@@ -85,10 +85,8 @@ class WorkloadResult:
     encode_ms_per_cycle: float | None = None
     encode_wall_frac: float | None = None
     encode_cache_hit_rate: float | None = None
-    # API-plane view of the measured phase (fullstack only for rpcs): HTTP
-    # round trips per scheduled pod — the tentpole's acceptance metric —
-    # plus the dispatcher's mean bulk micro-batch size and error count
-    rpcs_per_scheduled_pod: float | None = None
+    # the dispatcher's mean bulk micro-batch size and error count over the
+    # measured phase
     dispatcher_batch_mean: float | None = None
     dispatcher_errors: int = 0
     # mesh-sharded assignment (parallel.mesh): device count + mesh shape the
@@ -112,70 +110,19 @@ class WorkloadResult:
     # dispatch/bind_rtt/e2e (+ api_ingest/informer through the full stack)
     staged_latency_ms: dict | None = None
     # SustainedChurn soak gate: p99 e2e of the measured window's first vs
-    # second half + the flatness verdict (ROADMAP item 2's "p99 flat for
-    # minutes" evidence)
+    # second half + the flatness verdict ("p99 flat for minutes")
     soak: dict | None = None
     # flight recorder + per-pod tracing state for this run (the <5%
     # overhead budget's on/off comparison key)
     flight_recorder: bool = True
-    # wire-protocol view of the measured phase (fullstack only): the codec
-    # request bodies actually NEGOTIATED to ("binary" means the server
-    # confirmed the dialect — a fallback shows up as "json" here, not as a
-    # silently slow run), apiserver payload bytes per scheduled pod, and
-    # how many extra concurrent watchers hammered the fan-out path
-    wire_codec: str = ""
-    wire_bytes_per_pod: float | None = None
-    watch_fanout: int = 0
-    # active-active federation (sched.federation, the multi-process
-    # drivers' replicas= / partition=): replica count, partition mode, total
-    # CAS-bind conflicts + conflict rate (conflicted attempts / all bind
-    # attempts), binding_parity (store-verified pods bound exactly once —
-    # must equal measure_pods for a lossless run), lease transitions, and
-    # the replica-kill recovery time (kill → survivors re-absorbed the
-    # dead replica's partition and every pod bound)
-    replicas: int = 1
-    partition: str = ""
-    conflicts: int = 0
-    conflict_rate: float | None = None
-    binding_parity: int | None = None
-    # sha256 over the sorted (pod, node) pairs this run bound (fullstack):
-    # two runs bound the same map, pod for pod, iff their digests are equal
+    # sha256 over the sorted (pod, node) pairs this run bound: two runs
+    # bound the same map, pod for pod, iff their digests are equal
     bindings_digest: str = ""
-    lease_transitions: int = 0
-    recovery_s: float | None = None
-    # telemetry-plane view when a run exported to a collector
-    # (--telemetry): ingested span totals and the drop counter
-    telemetry: dict | None = None
     # anomaly-sentinel view when a run rode the sentinel (--sentinel):
     # lifecycle stats (evaluations/fired/bundles), the per-alert final
     # states, clean (nothing fired — the false-positive gate), and in
     # spike mode the injected-stall fire→bundle→resolve verdict
     sentinel: dict | None = None
-    # multi-process deployment view (run_workload_multiprocess): how many
-    # REAL OS processes carried the run (apiserver + schedulers +
-    # collector + watch drivers), each child's peak RSS / CPU seconds /
-    # restart count from the supervisor's /proc sampling, and how many
-    # supervisor respawns fired mid-run — 0 processes = in-process mode
-    n_processes: int = 0
-    child_stats: dict | None = None
-    restarts: int = 0
-    # replicated read plane (run_workload_multiprocess with
-    # ``apiservers`` > 1): how many apiservers carried the run (1 leader
-    # + N-1 followers; the watch fan-out load round-robins over the
-    # followers) and the PEAK follower replication lag sampled over the
-    # measured window — the read plane's honesty counter: a follower may
-    # serve a slightly old rv, never a wrong one, and this is how old
-    # "slightly" got under load
-    apiservers: int = 1
-    follower_lag_ms: float | None = None
-    follower_lag_records: int | None = None
-    # chained replication shipping (``--replication-chain``): follower i
-    # tails follower i-1 instead of the leader, so the leader's egress is
-    # ONE follower's worth regardless of fan-out — the result records the
-    # topology it ran and the leader's apiserver_replication_bytes_total
-    # over the run (the egress claim's evidence)
-    replication_chain: bool = False
-    leader_replication_bytes: float | None = None
     # --- trace-shaped workloads (run_workload_trace) ---------------------
     # admission-latency SLO: p50/p99 of enqueue→bind over every pod the
     # trace created, judged against the profile's declared budget
@@ -221,11 +168,6 @@ class WorkloadResult:
     # artifact paths written next to the result JSON when tracing is on:
     # chrome trace, /metrics text, device-side cycle records
     artifacts: dict = field(default_factory=dict)
-    # platform / device_kind / devices of the process that SCHEDULED — a
-    # record from a machine without a chip says so. None = this process
-    # (the in-process runners); the multi-process runner, whose measuring
-    # parent holds no device, copies its scheduler children's banner stamp
-    device: dict | None = None
 
     def to_json(self) -> dict:
         from .. import device_stamp
@@ -236,7 +178,7 @@ class WorkloadResult:
             "metric": "SchedulingThroughput/Average",
             "value": round(self.throughput, 1),
             "unit": "pods/s",
-            **(self.device or device_stamp()),
+            **device_stamp(),
             "scheduled": self.scheduled,
             "measure_pods": self.measure_pods,
             "duration_s": round(self.duration_s, 3),
@@ -268,8 +210,6 @@ class WorkloadResult:
             out["encode_wall_frac"] = round(self.encode_wall_frac, 3)
         if self.encode_cache_hit_rate is not None:
             out["encode_cache_hit_rate"] = round(self.encode_cache_hit_rate, 4)
-        if self.rpcs_per_scheduled_pod is not None:
-            out["rpcs_per_scheduled_pod"] = round(self.rpcs_per_scheduled_pod, 4)
         if self.dispatcher_batch_mean is not None:
             out["dispatcher_batch_mean"] = round(self.dispatcher_batch_mean, 1)
         if self.dispatcher_errors:
@@ -286,24 +226,6 @@ class WorkloadResult:
             out["soak"] = self.soak
         if not self.flight_recorder:
             out["flight_recorder"] = False
-        if self.wire_codec:
-            out["wire_codec"] = self.wire_codec
-        if self.wire_bytes_per_pod is not None:
-            out["wire_bytes_per_pod"] = round(self.wire_bytes_per_pod, 1)
-        if self.watch_fanout:
-            out["watch_fanout"] = self.watch_fanout
-        if self.replicas > 1 or self.partition:
-            out["replicas"] = self.replicas
-            out["partition"] = self.partition
-            out["conflicts"] = self.conflicts
-            if self.conflict_rate is not None:
-                out["conflict_rate"] = round(self.conflict_rate, 4)
-            if self.lease_transitions:
-                out["lease_transitions"] = self.lease_transitions
-            if self.recovery_s is not None:
-                out["recovery_s"] = round(self.recovery_s, 3)
-        if self.binding_parity is not None:
-            out["binding_parity"] = self.binding_parity
         if self.bindings_digest:
             out["bindings_digest"] = self.bindings_digest
         if self.compile_misses:
@@ -323,15 +245,8 @@ class WorkloadResult:
             out["truncated"] = True
         if self.trace_stats is not None:
             out["trace"] = self.trace_stats
-        if self.telemetry is not None:
-            out["telemetry"] = self.telemetry
         if self.sentinel is not None:
             out["sentinel"] = self.sentinel
-        if self.n_processes:
-            out["n_processes"] = self.n_processes
-            out["restarts"] = self.restarts
-            if self.child_stats is not None:
-                out["child_stats"] = self.child_stats
         if self.nodes_used_at_steady_state is not None:
             out["nodes_used_at_steady_state"] = self.nodes_used_at_steady_state
         if self.priority_slo_hit_rate is not None:
@@ -359,6 +274,12 @@ class WorkloadResult:
         if self.artifacts:
             out["artifacts"] = self.artifacts
         return out
+
+
+def bindings_digest(bound) -> str:
+    """Digest of the (pod name, node name) pairs a run bound, order-free:
+    two runs bound the same map, pod for pod, iff the digests are equal."""
+    return hashlib.sha256(repr(sorted(bound)).encode()).hexdigest()[:16]
 
 
 def dump_diagnosis_artifacts(
@@ -410,7 +331,7 @@ class _Client:
     def bulk_bind(self, pairs) -> list:
         # direct mode has no RPC to amortize; accepting the micro-batch
         # keeps the dispatch shape (and its batch-size stats) identical to
-        # fullstack
+        # the served path's
         for pod, node_name in pairs:
             self.bind(pod, node_name)
         return [None] * len(pairs)
@@ -686,101 +607,22 @@ class _Churn:
                 self.live.append(pod)
 
 
-@dataclass
-class _FsChurn:
-    """churnOp through the REST stack: interfering pods are created (and
-    in recreate mode deleted) via the remote store, so the scheduler sees
-    them through the informer seam — the informer→invalidate→re-encode
-    path end to end, exactly the reference's churn goroutine shape."""
-
-    op: W.ChurnOp
-    namespace: str
-    remote: object
-    bulk: bool = True
-    next_at: float = 0.0
-    seq: int = 0
-    live: list = field(default_factory=list)   # recreate-mode pool (keys)
-
-    def maybe_fire(self, now: float) -> None:
-        from ..client.informers import PODS
-
-        creates: list[tuple[str, t.Pod]] = []
-        while now >= self.next_at:
-            self.next_at = (self.next_at or now) + self.op.interval_ms / 1000.0
-            if self.op.mode == "recreate" and self.op.number and (
-                len(self.live) >= self.op.number
-            ):
-                # a catch-up burst can wrap past ``number``: the victim may
-                # still be sitting in the unflushed create queue — flush
-                # first so every popped key exists before its delete
-                if creates:
-                    _bulk_create(self.remote, PODS, creates, bulk=self.bulk)
-                    creates = []
-                victim = self.live.pop(0)
-                try:
-                    self.remote.delete(PODS, victim)
-                except Exception:
-                    pass   # already bound+mutated or gone — churn goes on
-            pod = self.op.template(f"churn-{self.seq}", self.namespace)
-            self.seq += 1
-            key = f"{self.namespace}/{pod.name}"
-            creates.append((key, pod))
-            if self.op.mode == "recreate":
-                self.live.append(key)
-        # everything due this fire rides one bulk create (a stalled loop
-        # catching up pays one RPC, not one per missed interval)
-        _bulk_create(self.remote, PODS, creates, bulk=self.bulk)
-
-
 def _bulk_create(
-    remote, kind: str, items: "list[tuple[str, object]]",
-    bulk: bool = True, chunk: int = 256,
+    remote, kind: str, items: "list[tuple[str, object]]", chunk: int = 256,
 ) -> None:
-    """Create ``items`` through the REST store — one bulk request per
-    ``chunk`` when the store has the bulk verb (the perf runner's
-    create-path RPC amortization), falling back to per-object creates
-    (and always for ``bulk=False``, the escape hatch's single-op path)."""
-    if bulk and len(items) > 1 and hasattr(remote, "bulk"):
-        from ..store.memstore import bulk_result_error
+    """Create ``items`` through the REST store, one bulk request per
+    ``chunk``; the first op an apiserver refuses raises."""
+    from ..store.memstore import bulk_result_error
 
-        for i in range(0, len(items), chunk):
-            ops = [
-                {"op": "create", "key": k, "object": o}
-                for k, o in items[i:i + chunk]
-            ]
-            for res in remote.bulk(kind, ops):
-                err = bulk_result_error(res)
-                if err is not None:
-                    raise err
-        return
-    for k, o in items:
-        remote.create(kind, k, o)
-
-
-@dataclass
-class _FsDeleter:
-    """deletePodsOp through the REST stack: drain a namespace's created
-    pods at ``per_second`` via remote deletes (each one becomes an
-    AssignedPodDelete informer event for the scheduler)."""
-
-    keys: list
-    per_second: int
-    remote: object
-    started_at: float = -1.0
-    deleted: int = 0
-
-    def maybe_fire(self, now: float) -> None:
-        from ..client.informers import PODS
-
-        if self.started_at < 0:
-            self.started_at = now
-        due = int((now - self.started_at) * self.per_second)
-        while self.deleted < min(due, len(self.keys)):
-            try:
-                self.remote.delete(PODS, self.keys[self.deleted])
-            except Exception:
-                pass
-            self.deleted += 1
+    for i in range(0, len(items), chunk):
+        ops = [
+            {"op": "create", "key": k, "object": o}
+            for k, o in items[i:i + chunk]
+        ]
+        for res in remote.bulk(kind, ops):
+            err = bulk_result_error(res)
+            if err is not None:
+                raise err
 
 
 def run_workload(
@@ -1165,6 +1007,7 @@ def run_workload(
         attempts=sched.metrics.schedule_attempts - attempts0,
         cycles=sched.metrics.cycles - cycles0,
         p99_attempt_latency_ms=lat,
+        bindings_digest=bindings_digest(client.bound),
         metrics_snapshot=sched.metrics.prom.snapshot(baseline=prom_base),
         artifacts=artifacts,
     )
@@ -1751,63 +1594,6 @@ class _TraceClient(_Client):
         )
 
 
-class _WatchFanout:
-    """N extra concurrent pod watchers against the apiserver — the heavy
-    fan-out load of a big cluster (hundreds of kubelets/controllers each
-    holding a watch). Each watcher is its own RemoteStore connection on
-    its own thread, long-polling the pods bucket; a compaction relists
-    and resumes. The load is the POINT (every store write wakes every
-    watcher, each draining the same events — the serialize-once body ring
-    pays one encode for all of them), so the threads run for the whole
-    workload and stop at teardown."""
-
-    def __init__(self, url: str, wire: str, n: int) -> None:
-        import threading
-
-        from ..apiserver import RemoteStore
-        from ..client.informers import PODS
-
-        self._stop = threading.Event()
-        self._threads: list[threading.Thread] = []
-
-        def loop() -> None:
-            try:
-                rs = RemoteStore(url, wire=wire)
-                w = rs.watch(PODS, 0)
-                # 2s long-poll: a write still wakes the watcher instantly
-                # through the store's condition variable — the timeout
-                # only bounds IDLE churn (hundreds of watchers at 0.5s
-                # would burn the host on empty polls, starving the very
-                # scheduler the fan-out is supposed to load)
-                w.poll_timeout_s = 2.0
-                while not self._stop.is_set():
-                    try:
-                        w.poll()
-                    except Exception:
-                        if self._stop.is_set():
-                            return
-                        # compacted cursor or transient transport error:
-                        # re-anchor at the current head and keep watching
-                        try:
-                            _items, rv = rs.list(PODS)
-                            w = rs.watch(PODS, rv)
-                            w.poll_timeout_s = 2.0
-                        except Exception:
-                            time.sleep(0.05)
-            except Exception:
-                pass    # a dead extra watcher must not kill the run
-
-        for _ in range(n):
-            t = threading.Thread(target=loop, daemon=True)
-            t.start()
-            self._threads.append(t)
-
-    def stop(self) -> None:
-        self._stop.set()
-        for t in self._threads:
-            t.join(timeout=5)
-
-
 def _sentinel_settle(sentinel, spike: "dict | None",
                      resolve_timeout_s: float = 30.0) -> dict:
     """Post-run sentinel settle: keep evaluating on the real clock until
@@ -1875,1209 +1661,4 @@ def _sentinel_settle(sentinel, spike: "dict | None",
                 (bundle.get("sections") or {}).keys()
             )
         out["spike"] = verdict
-    return out
-
-
-def run_workload_full_stack(
-    case: W.TestCase | str,
-    workload: W.Workload | str,
-    profile: C.Profile | None = None,
-    max_batch: int = 1024,
-    timeout_s: float = 1800.0,
-    engine: str = "greedy",
-    stall_s: float = 15.0,
-    warmup: bool = True,
-    artifacts_dir: str | None = None,
-    pipeline: bool = False,
-    encode_cache: bool = True,
-    bulk: bool = True,
-    mesh=None,
-    flight_recorder: bool = True,
-    wire: str = "binary",
-    watch_fanout: int = 0,
-    telemetry: bool = False,
-    sentinel: bool = False,
-    sentinel_spike: bool = False,
-) -> WorkloadResult:
-    """The same measurement through the FULL STACK: an in-process REST
-    apiserver + RemoteStore + informers + dispatcher binds over HTTP —
-    the reference harness's shape (scheduler_perf boots a real apiserver
-    and measures through it, test/integration/scheduler_perf/util.go:96).
-    Supports createNodes/createNamespaces/createPods/barrier PLUS churn
-    and pod-delete recycling (churnOp / deletePodsOp create and delete
-    through the REST store, so the informer→invalidate→re-encode path is
-    exercised end to end) — SchedulingBasic, the quadratic affinity/
-    spreading cases, and the churn workloads; richer ops (PV/DRA/gang)
-    still raise.
-
-    The direct-vs-full-stack delta is the apiserver tax: run both modes on
-    one workload to measure what the REST hop costs.
-
-    ``wire`` selects the negotiated wire codec ("binary" default, "json"
-    the escape hatch — bindings are pod-for-pod identical); the record
-    embeds the codec actually negotiated plus wire_bytes_per_pod.
-    ``watch_fanout`` adds N extra concurrent pod watchers (the big-
-    cluster fan-out load the serialize-once body ring exists for).
-    ``telemetry`` runs the FULL telemetry plane alongside the workload —
-    a real HTTP collector, traceparent stamped on every RPC, both
-    processes' exporters on their 1 s cadence — the whole tax, not a
-    cut-down one; the result carries the collector's span totals and
-    drop counter.
-    ``sentinel`` rides the anomaly sentinel (telemetry.sentinel) on the
-    scheduler's cycle boundary with run-scaled rule windows
-    (rules.fast_rules); the result carries its lifecycle stats
-    (``clean`` = nothing fired).
-    ``sentinel_spike`` additionally injects a one-shot scheduling stall
-    mid-measured-phase and reports the fire→bundle→resolve verdict
-    (the acceptance scenario — NOT a judged throughput row)."""
-    import collections
-
-    from ..apiserver import APIServer, RemoteStore
-    from ..client import SchedulerInformers, StoreClient
-    from ..client.informers import NAMESPACES, NODES, PODS
-
-    if isinstance(case, str):
-        case = W.TEST_CASES[case]
-    if isinstance(workload, str):
-        workload = next(w for w in case.workloads if w.name == workload)
-    params = dict(workload.params)
-    supported = (
-        W.CreateNodesOp, W.CreateNamespacesOp, W.CreatePodsOp, W.BarrierOp,
-        W.ChurnOp, W.DeletePodsOp,
-    )
-    for op in case.ops:
-        if not isinstance(op, supported):
-            raise NotImplementedError(
-                f"full-stack mode does not drive {type(op).__name__}"
-            )
-
-    srv = APIServer().start()
-    remote = RemoteStore(srv.url, wire=wire, traceparent=telemetry)
-    fanout = (
-        _WatchFanout(srv.url, wire, watch_fanout) if watch_fanout else None
-    )
-    coll_srv = None
-    exporters: list = []
-    if telemetry:
-        from ..telemetry.collector import CollectorServer
-        from ..telemetry.exporter import TelemetryExporter
-
-        coll_srv = CollectorServer().start()
-        exporters.append(TelemetryExporter(
-            coll_srv.url, process="apiserver-bench",
-            component="apiserver", tracer=srv.tracer,
-            metrics_fn=srv.metrics_text,
-        ).start())
-
-    class _CountingClient(StoreClient):
-        def __init__(self, store) -> None:
-            import threading
-
-            super().__init__(store)
-            self.bound_by_ns: collections.Counter = collections.Counter()
-            self.bound_pairs: list[tuple[str, str]] = []
-            self._count_lock = threading.Lock()   # dispatcher workers bind
-            #                                       concurrently
-
-        def bind(self, pod, node_name) -> None:
-            super().bind(pod, node_name)
-            with self._count_lock:
-                self.bound_by_ns[pod.namespace] += 1
-                self.bound_pairs.append((pod.name, node_name))
-
-        def bulk_bind(self, pairs) -> list:
-            errs = super().bulk_bind(pairs)
-            with self._count_lock:
-                for (pod, node), err in zip(pairs, errs):
-                    # failed ops fall back through bind(), which counts
-                    if err is None:
-                        self.bound_by_ns[pod.namespace] += 1
-                        self.bound_pairs.append((pod.name, node))
-            return errs
-
-    client = _CountingClient(remote)
-    sentinel_obj = None
-    if sentinel or sentinel_spike:
-        from ..telemetry.rules import fast_rules
-        from ..telemetry.sentinel import Sentinel as _Sentinel
-
-        # run-scaled windows (seconds, not minutes) so the lifecycle
-        # completes inside one run; the declared budget only
-        # exists in spike mode — a clean run keeps the admission burn
-        # rule dormant and judges the budget-less rules (outlier,
-        # cache-collapse) for false positives instead
-        sentinel_obj = _Sentinel(
-            rules=fast_rules(),
-            slo_budget_ms=250.0 if sentinel_spike else None,
-            interval_s=0.25,
-        )
-    sched = Scheduler(
-        client, profile=profile or C.Profile(), max_batch=max_batch,
-        engine=engine, pipeline=pipeline, encode_cache=encode_cache,
-        bulk=bulk, mesh=mesh, flight_recorder=flight_recorder,
-        feature_gates=dict(case.feature_gates) if case.feature_gates else None,
-        sentinel=sentinel_obj if sentinel_obj is not None else False,
-    )
-    if telemetry:
-        from ..telemetry.exporter import TelemetryExporter
-
-        remote.set_tracer(sched.tracer)
-        fr = sched.flight_recorder
-        exporters.append(TelemetryExporter(
-            coll_srv.url, process="scheduler-bench",
-            component="scheduler", tracer=sched.tracer,
-            metrics_fn=sched.metrics_text,
-            flight_fn=(
-                (lambda: fr.records_json(limit=512))
-                if fr is not None else None
-            ),
-        ).start())
-    informers = SchedulerInformers(remote, sched, bulk=bulk)
-    informers.start()
-
-    measured = 0
-    duration = 0.0
-    attempts0 = cycles0 = 0
-    prom_base = None
-    op_ns_counter = 0
-    requests0 = 0
-    rpcs_total = 0        # measured-phase apiserver round trips
-    wire0 = 0
-    wire_total = 0        # measured-phase apiserver payload bytes
-    churns: list[_FsChurn] = []
-    deleters: list[_FsDeleter] = []
-    created_keys_by_ns: dict[str, list[str]] = {}
-    created_pods: list[t.Pod] = []
-    # one-shot injected stall (sentinel_spike): armed when the MEASURED
-    # phase starts, fired once a third of its pods have bound — the
-    # backlogged pods then bind with e2e latencies past the declared
-    # budget, which is exactly the bad-event burst the admission
-    # burn-rate rule exists to catch
-    spike = {"armed": False, "stall_s": 0.75,
-             "start_wall": None, "end_wall": None}
-
-    def settle(target: int, namespaces: tuple[str, ...]) -> tuple[int, float]:
-        def bound_now() -> int:
-            return sum(client.bound_by_ns[ns] for ns in namespaces)
-
-        start = bound_now()
-        done = 0
-        t0 = time.perf_counter()
-        deadline = t0 + timeout_s
-        last_progress = t0
-        while done < target:
-            now = time.perf_counter()
-            if now > deadline:
-                break
-            if spike["armed"] and done >= target // 3:
-                spike["armed"] = False
-                spike["start_wall"] = time.time()
-                time.sleep(spike["stall_s"])
-                spike["end_wall"] = time.time()
-            for ch in churns:
-                ch.maybe_fire(now)
-            for d in deleters:
-                d.maybe_fire(now)
-            moved = informers.pump()
-            res = sched.schedule_batch()
-            sched.dispatcher.sync()
-            sched._drain_bind_completions()
-            before = done
-            done = bound_now() - start
-            if done == before and res["scheduled"] == 0 and not moved:
-                if now - last_progress > stall_s:
-                    break
-                time.sleep(0.005)
-            else:
-                last_progress = now
-        return done, time.perf_counter() - t0
-
-    try:
-        for op_i, op in enumerate(case.ops):
-            if isinstance(op, W.CreateNodesOp):
-                n = op.count or params[op.count_param]
-                factory = op.template or W.node_default
-                nodes = [factory(i, op.zones) for i in range(n)]
-                _bulk_create(
-                    remote, NODES, [(nd.name, nd) for nd in nodes], bulk=bulk,
-                )
-            elif isinstance(op, W.CreateNamespacesOp):
-                n = params[op.count_param] if op.count_param else op.count
-                _bulk_create(remote, NAMESPACES, [
-                    (f"{op.prefix}-{i}", t.Namespace(
-                        name=f"{op.prefix}-{i}", labels=op.labels,
-                    ))
-                    for i in range(n)
-                ], bulk=bulk)
-            elif isinstance(op, W.BarrierOp):
-                informers.pump()
-                sched.run_until_idle()
-            elif isinstance(op, W.ChurnOp):
-                churns.append(_FsChurn(
-                    op=op, namespace=f"churn-{len(churns)}", remote=remote,
-                    bulk=bulk,
-                ))
-            elif isinstance(op, W.DeletePodsOp):
-                deleters.append(_FsDeleter(
-                    keys=list(created_keys_by_ns.get(op.namespace, ())),
-                    per_second=op.per_second, remote=remote,
-                ))
-            elif isinstance(op, W.CreatePodsOp):
-                count = params[op.count_param]
-                template = op.template or case.default_pod_template
-                ns = op.namespace or f"namespace-{op_ns_counter}"
-                op_ns_counter += 1
-                prefix = (
-                    f"{'measure' if op.collect_metrics else 'init'}-{op_i}"
-                )
-                informers.pump()
-                if op.collect_metrics:
-                    attempts0, cycles0, prom_base = _begin_measured_phase(
-                        sched, warmup,
-                        [
-                            template(f"warmup-{op_i}-{j}", ns)
-                            for j in range(min(count, sched.max_batch))
-                        ],
-                    )
-                    requests0 = srv.metrics.total_requests()
-                    wire0 = srv.metrics.wire_bytes_total()
-                    if sentinel_spike:
-                        spike["armed"] = True
-                items = []
-                for j in range(count):
-                    pod = template(f"{prefix}-{ns}-{j}", ns)
-                    key = f"{ns}/{pod.name}"
-                    created_keys_by_ns.setdefault(ns, []).append(key)
-                    created_pods.append(pod)
-                    items.append((key, pod))
-                _bulk_create(remote, PODS, items, bulk=bulk)
-                if op.skip_wait:
-                    continue
-                done, secs = settle(count, (ns,))
-                if op.collect_metrics:
-                    measured += done
-                    duration += secs
-                    # everything the measured phase cost the API plane:
-                    # pod creates, informer polls, binds, status patches
-                    rpcs_total += srv.metrics.total_requests() - requests0
-                    wire_total += srv.metrics.wire_bytes_total() - wire0
-        informers.pump()
-        sched.dispatcher.sync()
-        sched._drain_bind_completions()
-        # the store's own view, read back over REST while the apiserver
-        # still serves: measured pods this run bound exactly once whose
-        # stored node is the node the client was acked for, pod for pod
-        stored = {
-            pod.name: pod.node_name for _key, pod in remote.list(PODS)[0]
-        }
-        binds = collections.Counter(name for name, _ in client.bound_pairs)
-        parity = sum(
-            1 for name, node in client.bound_pairs
-            if name.startswith("measure-") and binds[name] == 1
-            and stored.get(name) == node
-        )
-        sentinel_report = None
-        if sentinel_obj is not None:
-            sentinel_report = _sentinel_settle(
-                sentinel_obj,
-                spike if spike["end_wall"] is not None else None,
-            )
-    finally:
-        if fanout is not None:
-            fanout.stop()
-        telemetry_stats = None
-        for exp in exporters:
-            exp.close()         # final flush so span totals are complete
-        if coll_srv is not None:
-            col = coll_srv.collector
-            telemetry_stats = {
-                "spans": col.spans_total,
-                "spans_dropped": col.spans_dropped,
-                "processes": len(col.summary()["processes"]),
-            }
-            coll_srv.close()
-        sched.close()
-        srv.close()
-
-    lat = measured_p99_ms(sched, prom_base)
-    artifacts: dict[str, str] = {}
-    if artifacts_dir is not None:
-        artifacts = dump_diagnosis_artifacts(
-            sched, artifacts_dir,
-            f"{case.name}_{workload.name}_{engine}_fullstack",
-        )
-    throughput = measured / duration if duration > 0 else 0.0
-    traffic = _device_traffic_stats(sched, cycles0, duration)
-    return WorkloadResult(
-        case_name=case.name,
-        workload_name=workload.name + "_fullstack",
-        threshold=workload.threshold,
-        threshold_note=workload.threshold_note,
-        **traffic,
-        **_encode_stats(sched, cycles0),
-        **_dispatcher_stats(sched),
-        **_mesh_stats(sched),
-        **_staged_and_soak(sched, prom_base),
-        **_packing_stats(sched, cycles0, client.bound_pairs, created_pods),
-        rpcs_per_scheduled_pod=(
-            rpcs_total / measured if measured else None
-        ),
-        wire_codec=remote.wire_codec,
-        wire_bytes_per_pod=(
-            wire_total / measured if measured else None
-        ),
-        watch_fanout=watch_fanout,
-        measure_pods=sum(
-            params[op.count_param]
-            for op in case.ops
-            if isinstance(op, W.CreatePodsOp) and op.collect_metrics
-        ),
-        scheduled=measured,
-        duration_s=duration,
-        throughput=throughput,
-        vs_threshold=(
-            throughput / workload.threshold if workload.threshold else None
-        ),
-        attempts=sched.metrics.schedule_attempts - attempts0,
-        cycles=sched.metrics.cycles - cycles0,
-        p99_attempt_latency_ms=lat,
-        binding_parity=parity,
-        bindings_digest=hashlib.sha256(
-            repr(sorted(client.bound_pairs)).encode()
-        ).hexdigest()[:16],
-        telemetry=telemetry_stats,
-        sentinel=sentinel_report,
-        metrics_snapshot=sched.metrics.prom.snapshot(baseline=prom_base),
-        artifacts=artifacts,
-    )
-
-
-def _children_device(cluster) -> dict:
-    """The device stamp the scheduler children published on their
-    readiness banners: the measuring parent of a multi-process run holds
-    no device, so its records carry what the schedulers held."""
-    banner = cluster.schedulers[0].banner or {}
-    return {k: banner.get(k) for k in ("platform", "device_kind", "devices")}
-
-
-def _scrape_metrics(url: str):
-    """Parse one component's /metrics scrape (None on any failure — a
-    restarting replica mid-scrape must not kill the run; the caller
-    reports what it could read)."""
-    import urllib.request
-
-    from ..metrics.textparse import parse_prometheus_text
-
-    try:
-        with urllib.request.urlopen(url.rstrip("/") + "/metrics",
-                                    timeout=10) as resp:
-            return parse_prometheus_text(resp.read().decode())
-    except Exception:
-        return None
-
-
-def _replication_status(url: str, timeout: float = 2.0) -> dict | None:
-    """One apiserver's /replication/status page (None on any failure —
-    a follower mid-crash or mid-election must not kill the sampler)."""
-    import json as _json
-    import urllib.request
-
-    try:
-        with urllib.request.urlopen(
-            url.rstrip("/") + "/replication/status", timeout=timeout,
-        ) as resp:
-            return _json.loads(resp.read().decode())
-    except Exception:
-        return None
-
-
-def _sum_samples(parsed, name: str, **labels) -> float:
-    """Sum of every sample of family ``name`` whose label set contains
-    ``labels`` (a sum() over a PromQL instant selector)."""
-    if parsed is None:
-        return 0.0
-    want = {(k, str(v)) for k, v in labels.items()}
-    return sum(
-        s.value for s in parsed.samples(name)
-        if s.name == name and want <= set(s.labels)
-    )
-
-
-class ParityError(AssertionError):
-    """The store-verified exactly-once binding check failed: a measured
-    pod is unbound (lost to a dead replica / conflict loop) after the run
-    claimed completion. Raised — never just a field — so a lossy mp run
-    FAILS instead of reporting a number."""
-
-
-def run_workload_multiprocess(
-    case: W.TestCase | str,
-    workload: W.Workload | str,
-    replicas: int = 2,
-    apiservers: int = 1,
-    partition: str = "race",
-    wire: str = "binary",
-    engine: str = "greedy",
-    max_batch: int = 1024,
-    timeout_s: float = 1800.0,
-    stall_s: float = 30.0,
-    bulk: bool = True,
-    persistence: str | None = None,
-    telemetry: bool = False,
-    watch_fanout: int = 0,
-    fanout_procs: int = 0,
-    kill_replica_at: float | None = None,
-    restart: str = "on-failure:2",
-    replication_chain: bool = False,
-    child_env: dict | None = None,
-) -> WorkloadResult:
-    """THE honest deployment shape: apiserver + N scheduler replicas
-    (+ optional collector and watch-fanout drivers) as REAL OS processes
-    under the launch supervisor (``kubetpu.launch.Cluster``) — no shared
-    GIL, components talk ONLY through the apiserver, exactly the
-    reference's independent-binaries layer map. The measuring parent
-    drives the op list through an admin RemoteStore and observes binding
-    progress from the STORE (not from in-process counters it cannot
-    have), then joins through ``Supervisor.join`` with the store-verified
-    exactly-once parity check — a parity miss raises ``ParityError`` and
-    fails the stage, never just a field.
-
-    ``kill_replica_at`` (0..1): at that fraction of the measured pods
-    bound, the last replica is SIGKILLed; the supervisor's ``restart``
-    policy respawns it (the respawned process re-federates — hash
-    re-adopts its rank's backlog via the informer relist, lease
-    re-acquires through the shared store) and ``recovery_s`` measures
-    kill → every measured pod bound.
-
-    ``apiservers`` > 1 stands up the replicated read plane (1 leader +
-    N-1 follower apiservers; the Cluster round-robins the watch fan-out
-    drivers over the followers, leaving the leader to its writers) and
-    samples each follower's peak replication lag over the measured
-    window into ``follower_lag_ms`` / ``follower_lag_records``.
-    ``replication_chain`` wires follower i to tail follower i-1 instead
-    of the leader; the run records the leader's replication egress bytes
-    either way (``leader_replication_bytes``) so the chained-vs-star
-    delta is a stage-to-stage comparison, not an inference.
-
-    Evidence scraped over HTTP before shutdown: apiserver request/wire
-    deltas for the measured window, per-replica federation conflicts +
-    schedule attempts from the diagnostics pages (counters of the
-    CURRENTLY live processes — a restarted replica restarts its
-    counters; ``restarts`` says when that happened), and per-child peak
-    RSS / CPU seconds from the supervisor's /proc sampling.
-
-    Supports the createNodes/createNamespaces/createPods/barrier op set
-    (the fullstack SchedulingBasic shape); richer ops raise."""
-    import os as _os
-
-    from ..apiserver import RemoteStore
-    from ..client.informers import NAMESPACES, NODES, PODS
-    from ..launch import Cluster
-
-    if isinstance(case, str):
-        case = W.TEST_CASES[case]
-    if isinstance(workload, str):
-        workload = next(w for w in case.workloads if w.name == workload)
-    params = dict(workload.params)
-    supported = (
-        W.CreateNodesOp, W.CreateNamespacesOp, W.CreatePodsOp, W.BarrierOp,
-    )
-    for op in case.ops:
-        if not isinstance(op, supported):
-            raise NotImplementedError(
-                f"multi-process mode does not drive {type(op).__name__}"
-            )
-    if kill_replica_at is not None and replicas < 2:
-        raise ValueError("--kill-replica-at requires --replicas >= 2")
-
-    import kubetpu as _pkg
-
-    repo_root = _os.path.dirname(_os.path.dirname(_os.path.abspath(
-        _pkg.__file__
-    )))
-    cluster = Cluster(
-        replicas=replicas, apiservers=apiservers, partition=partition,
-        wire=wire, engine=engine,
-        max_batch=max_batch, persistence=persistence,
-        telemetry=("collector" if telemetry else "off"),
-        fanout_procs=fanout_procs, fanout_watchers=watch_fanout,
-        restart=restart, replication_chain=replication_chain,
-        env=child_env, cwd=repo_root,
-    )
-    measured = 0
-    duration = 0.0
-    measure_target = 0
-    recovery_s: float | None = None
-    killed = False
-    requests0 = wire0 = 0.0
-    rpcs_total = wire_total = 0.0
-    measure_namespaces: tuple[str, ...] = ()
-    op_ns_counter = 0
-    # peak follower replication lag over the measured window (the read
-    # plane's honesty counter) — sampled from /replication/status at most
-    # every ``_LAG_SAMPLE_S`` inside the settle loop
-    _LAG_SAMPLE_S = 0.4
-    lag_peak: dict[str, float] = {}
-    lag_last_sample = [0.0]
-
-    def sample_follower_lag() -> None:
-        if apiservers < 2:
-            return
-        now = time.perf_counter()
-        if now - lag_last_sample[0] < _LAG_SAMPLE_S:
-            return
-        lag_last_sample[0] = now
-        for url in cluster.api_urls[1:]:
-            st = _replication_status(url)
-            if not st:
-                continue
-            lag_peak["ms"] = max(
-                lag_peak.get("ms", 0.0), float(st.get("lagMs") or 0.0)
-            )
-            lag_peak["records"] = max(
-                lag_peak.get("records", 0.0),
-                float(st.get("lagRecords") or 0.0),
-            )
-
-    cluster.start()
-    try:
-        admin = RemoteStore(cluster.api_url, wire=wire)
-
-        def bound_now(namespaces: tuple[str, ...]) -> int:
-            items, _rv = admin.list(PODS)
-            return sum(
-                1 for key, pod in items
-                if pod.node_name and key.split("/", 1)[0] in namespaces
-            )
-
-        def settle(
-            target: int, namespaces: tuple[str, ...], start: int,
-            allow_kill: bool = False,
-        ) -> tuple[int, float]:
-            """``start`` is the namespaces' bound count captured BEFORE
-            the creating bulk RPCs: the scheduler processes run
-            concurrently with the chunked create, so pods from chunk 1
-            can already be bound when settle begins — a baseline taken
-            here would make ``target`` unreachable and every mp
-            throughput row would silently absorb a full stall wait."""
-            nonlocal recovery_s, killed
-            t0 = time.perf_counter()
-            deadline = t0 + timeout_s
-            last_progress = t0
-            done = 0
-            t_kill = None
-            kill_at = (
-                int(kill_replica_at * target)
-                if (kill_replica_at is not None and allow_kill) else None
-            )
-            while done < target:
-                now = time.perf_counter()
-                if now > deadline:
-                    break
-                before = done
-                done = bound_now(namespaces) - start
-                if kill_at is not None and not killed and done >= kill_at:
-                    cluster.kill_replica(len(cluster.schedulers) - 1)
-                    killed = True
-                    t_kill = time.perf_counter()
-                sample_follower_lag()
-                if done > before:
-                    last_progress = now
-                elif now - last_progress > stall_s:
-                    break
-                else:
-                    time.sleep(0.1)
-            t_end = time.perf_counter()
-            if t_kill is not None and done >= target:
-                recovery_s = t_end - t_kill
-            return done, t_end - t0
-
-        for op_i, op in enumerate(case.ops):
-            if isinstance(op, W.CreateNodesOp):
-                n = op.count or params[op.count_param]
-                factory = op.template or W.node_default
-                nodes = [factory(i, op.zones) for i in range(n)]
-                _bulk_create(
-                    admin, NODES, [(nd.name, nd) for nd in nodes], bulk=bulk,
-                )
-            elif isinstance(op, W.CreateNamespacesOp):
-                n = params[op.count_param] if op.count_param else op.count
-                _bulk_create(admin, NAMESPACES, [
-                    (f"{op.prefix}-{i}", t.Namespace(
-                        name=f"{op.prefix}-{i}", labels=op.labels,
-                    ))
-                    for i in range(n)
-                ], bulk=bulk)
-            elif isinstance(op, W.BarrierOp):
-                continue   # phases settle to completion below
-            elif isinstance(op, W.CreatePodsOp):
-                count = params[op.count_param]
-                template = op.template or case.default_pod_template
-                ns = op.namespace or f"namespace-{op_ns_counter}"
-                op_ns_counter += 1
-                prefix = (
-                    f"{'measure' if op.collect_metrics else 'init'}-{op_i}"
-                )
-                if op.collect_metrics:
-                    measure_namespaces = measure_namespaces + (ns,)
-                    measure_target += count
-                    api_metrics = _scrape_metrics(cluster.api_url)
-                    requests0 = _sum_samples(
-                        api_metrics, "apiserver_request_total"
-                    )
-                    wire0 = _sum_samples(
-                        api_metrics, "apiserver_wire_bytes_total"
-                    )
-                start = bound_now((ns,))   # BEFORE the creates — see settle
-                items = []
-                for j in range(count):
-                    pod = template(f"{prefix}-{ns}-{j}", ns)
-                    items.append((f"{ns}/{pod.name}", pod))
-                _bulk_create(admin, PODS, items, bulk=bulk)
-                if op.skip_wait:
-                    continue
-                done, secs = settle(
-                    count, (ns,), start, allow_kill=op.collect_metrics,
-                )
-                if op.collect_metrics:
-                    measured += done
-                    duration += secs
-                    api_metrics = _scrape_metrics(cluster.api_url)
-                    rpcs_total += _sum_samples(
-                        api_metrics, "apiserver_request_total"
-                    ) - requests0
-                    wire_total += _sum_samples(
-                        api_metrics, "apiserver_wire_bytes_total"
-                    ) - wire0
-
-        # federation evidence off the live replicas' diagnostics pages
-        # (scraped BEFORE the join stops them)
-        conflicts = 0.0
-        attempts = 0.0
-        lease_transitions = 0.0
-        for diag_url in cluster.scheduler_diag_urls():
-            parsed = _scrape_metrics(diag_url)
-            conflicts += _sum_samples(
-                parsed, "scheduler_federation_conflicts_total"
-            )
-            attempts += _sum_samples(
-                parsed, "scheduler_schedule_attempts_total",
-                result="scheduled",
-            )
-            lease_transitions += _sum_samples(
-                parsed, "scheduler_federation_lease_transitions_total"
-            )
-        wire_codec = admin.wire_codec
-        n_processes = cluster.n_processes()
-        restarts = cluster.supervisor.restarts_total()
-        leader_rep_bytes: float | None = None
-        if apiservers > 1:
-            leader_rep_bytes = _sum_samples(
-                _scrape_metrics(cluster.api_url),
-                "apiserver_replication_bytes_total",
-            )
-
-        parity_read: dict[str, int] = {}
-
-        def verify_parity() -> None:
-            """The join contract: store-verified exactly-once binding of
-            EVERY measured pod, checked while the apiserver still serves.
-            (The CAS bind makes bound-twice impossible, so parity ==
-            target means none were lost to a dead replica or a conflict
-            loop either.) The count READ from the store is what the
-            record carries — never a value derived from the target."""
-            parity = bound_now(measure_namespaces)
-            parity_read["bound"] = parity
-            if parity != measure_target:
-                raise ParityError(
-                    f"binding parity miss: {parity}/{measure_target} "
-                    f"measured pods bound "
-                    f"(replicas={replicas}, partition={partition}, "
-                    f"killed={killed}, restarts={restarts})"
-                )
-
-        cluster.join(verify=verify_parity if measure_namespaces else None)
-        child_stats = cluster.supervisor.child_stats()
-    finally:
-        cluster.shutdown()
-
-    throughput = measured / duration if duration > 0 else 0.0
-    return WorkloadResult(
-        case_name=case.name,
-        workload_name=(
-            f"{workload.name}_mp_{replicas}sched_{partition}"
-            + (f"_{apiservers}api" if apiservers > 1 else "")
-        ),
-        threshold=workload.threshold,
-        threshold_note=workload.threshold_note,
-        measure_pods=measure_target,
-        scheduled=measured,
-        duration_s=duration,
-        throughput=throughput,
-        vs_threshold=(
-            throughput / workload.threshold if workload.threshold else None
-        ),
-        attempts=int(attempts),
-        cycles=0,
-        rpcs_per_scheduled_pod=(
-            rpcs_total / measured if measured else None
-        ),
-        wire_codec=wire_codec,
-        wire_bytes_per_pod=(
-            wire_total / measured if measured else None
-        ),
-        watch_fanout=watch_fanout,
-        replicas=replicas,
-        partition=partition,
-        conflicts=int(conflicts),
-        conflict_rate=(conflicts / attempts) if attempts else 0.0,
-        lease_transitions=int(lease_transitions),
-        binding_parity=parity_read.get("bound"),   # the store-READ count
-        #                   (join raised ParityError on any miss, so a
-        #                    record only exists when it equals the target)
-        recovery_s=recovery_s,
-        n_processes=n_processes,
-        child_stats=child_stats,
-        restarts=restarts,
-        apiservers=apiservers,
-        follower_lag_ms=lag_peak.get("ms"),
-        follower_lag_records=(
-            int(lag_peak["records"]) if "records" in lag_peak else None
-        ),
-        replication_chain=replication_chain,
-        leader_replication_bytes=leader_rep_bytes,
-        device=_children_device(cluster),
-    )
-
-
-def run_list_scaling(
-    n_nodes: int = 5000,
-    relists: int = 8,
-    page_limit: int | None = None,
-    wire: str = "binary",
-    wall_budget_s: float = 120.0,
-) -> dict:
-    """The read plane's LIST-at-scale evidence: one apiserver over a store
-    pre-loaded with ``n_nodes`` nodes, then ``relists`` full paged walks
-    through a RemoteStore — the exact informer-relist path (limit/continue
-    pages pinned to one snapshot rv, per-page retry budget, serialize-once
-    item bytes).
-
-    Reports the per-relist wall p50/p99, the wire bytes and page count
-    per relist off the client's relist accounting, the max single page ever
-    shipped, and one unpaged-GET wall for the before/after context. Every walk is
-    parity-checked against the node count — a paged walk that dropped or
-    duplicated a key raises (a correctness failure must fail the stage,
-    never land as a slow-but-green number). ``wall_budget_s`` caps the
-    run: one that can't finish its relists returns a TRUNCATED but
-    parseable record carrying the walks it did complete."""
-    from ..apiserver import APIServer, RemoteStore
-    from ..client.informers import NODES
-    from ..store.memstore import MemStore
-
-    store = MemStore()
-    srv = APIServer(store).start()
-    try:
-        rs = RemoteStore(srv.url, wire=wire)
-        limit = rs.LIST_PAGE_LIMIT if page_limit is None else page_limit
-        nodes = [W.node_default(i) for i in range(n_nodes)]
-        _bulk_create(rs, NODES, [(nd.name, nd) for nd in nodes])
-
-        walls_ms: list[float] = []
-        stats0 = dict(rs.relist_stats)
-        t0 = time.perf_counter()
-        truncated = False
-        for _ in range(relists):
-            if time.perf_counter() - t0 > wall_budget_s:
-                truncated = True
-                break
-            t_walk = time.perf_counter()
-            items, rv = rs.list(NODES, limit=limit)
-            walls_ms.append((time.perf_counter() - t_walk) * 1000.0)
-            keys = {k for k, _ in items}
-            if len(items) != n_nodes or len(keys) != n_nodes:
-                raise AssertionError(
-                    f"paged walk parity miss: {len(items)} items / "
-                    f"{len(keys)} distinct keys over {n_nodes} nodes "
-                    f"(rv={rv})"
-                )
-        done = len(walls_ms)
-        pages = rs.relist_stats["pages"] - stats0["pages"]
-        total_bytes = rs.relist_stats["bytes"] - stats0["bytes"]
-        # snapshot BEFORE the unpaged baseline below — limit=0 rides the
-        # same walk accounting as one giant page and would clobber the max
-        max_page_bytes = rs.relist_stats["max_page_bytes"]
-        unpaged_ms = None
-        if not truncated and time.perf_counter() - t0 <= wall_budget_s:
-            t_walk = time.perf_counter()
-            rs.list(NODES, limit=0)     # the legacy single-GET baseline
-            unpaged_ms = (time.perf_counter() - t_walk) * 1000.0
-        return {
-            "nodes": n_nodes,
-            "page_limit": limit,
-            "relists": done,
-            "list_p50_ms": round_latency_ms(
-                float(np.percentile(walls_ms, 50)) if walls_ms else None
-            ),
-            "list_p99_ms": round_latency_ms(
-                float(np.percentile(walls_ms, 99)) if walls_ms else None
-            ),
-            "pages_per_relist": round(pages / done, 2) if done else None,
-            "bytes_per_relist": round(total_bytes / done) if done else None,
-            "max_page_bytes": max_page_bytes,
-            "unpaged_ms": round_latency_ms(unpaged_ms),
-            "wire_codec": rs.wire_codec,
-            "parity_ok": True,
-            "truncated": truncated,
-        }
-    finally:
-        srv.close()
-
-
-def run_trace_multiprocess(
-    profile,
-    replicas: int = 2,
-    partition: str = "lease",
-    wire: str = "binary",
-    engine: str = "greedy",
-    max_batch: int = 128,
-    timeout_s: float = 600.0,
-    stall_s: float = 30.0,
-    speed: float = 1.0,
-    wall_budget_s: float | None = None,
-    handover_at: float | None = 0.5,
-    restart: str = "on-failure:2",
-    child_env: dict | None = None,
-) -> WorkloadResult:
-    """Replay a trace profile against the REAL multi-process federation
-    (ROADMAP 5b): apiserver + ``replicas`` scheduler processes under the
-    launch supervisor, pod arrivals paced by the trace clock through an
-    admin RemoteStore, admission latency measured enqueue→bind from the
-    STORE's observed bindings (polled over the paged list walk — bind
-    timestamps carry up to one poll interval of quantization, well under
-    the seconds-scale SLO budgets these records are judged against).
-
-    ``handover_at`` (0..1 of the trace clock, lease/hash modes): at that
-    point the LAST scheduler replica is SIGKILLed mid-trace — the
-    supervisor's restart policy respawns it and its keyspace rides a
-    lease handover — so ``admission_p99_ms`` spans a forced handover,
-    which is the record's whole point: the SLO price of losing a
-    federated scheduler under live trace load. ``recovery_s`` is
-    kill → every live trace pod bound.
-
-    Supports create_pod/delete_pod/add_node/drain_node events (gang
-    create_group has no REST kind and needs the in-process seam —
-    those profiles raise)."""
-    import os as _os
-
-    from ..apiserver import RemoteStore
-    from ..client.informers import NODES, PODS
-    from ..launch import Cluster
-
-    if isinstance(profile, str):
-        profile = W.TRACE_PROFILES[profile]
-    events = profile.events()
-    unsupported = {e.kind for e in events} - {
-        "create_pod", "delete_pod", "add_node", "drain_node",
-    }
-    if unsupported:
-        raise NotImplementedError(
-            f"multi-process trace replay does not drive {unsupported}"
-        )
-    if handover_at is not None and replicas < 2:
-        raise ValueError("handover_at requires replicas >= 2")
-    trace_len_s = events[-1].at_s if events else 0.0
-
-    import kubetpu as _pkg
-
-    repo_root = _os.path.dirname(_os.path.dirname(_os.path.abspath(
-        _pkg.__file__
-    )))
-    cluster = Cluster(
-        replicas=replicas, partition=partition, wire=wire, engine=engine,
-        max_batch=max_batch, restart=restart, env=child_env,
-        cwd=repo_root,
-    )
-    cluster.start()
-    truncated = False
-    killed = False
-    t_kill: float | None = None
-    recovery_s: float | None = None
-    created_at: dict[str, float] = {}
-    deleted: set[str] = set()
-    bind_time: dict[str, float] = {}
-    try:
-        admin = RemoteStore(cluster.api_url, wire=wire)
-        nodes = [W.node_default(i, profile.zones,
-                                getattr(profile, "slices", 0))
-                 for i in range(profile.nodes)]
-        _bulk_create(admin, NODES, [(nd.name, nd) for nd in nodes])
-
-        _POLL_S = 0.05
-        poll_last = [0.0]
-
-        def poll_bound(now: float, force: bool = False) -> int:
-            """Stamp bind times for newly-bound trace pods off a store
-            list (rides the paged walk). Throttled — the poll is the
-            measurement's read load, not a busy loop."""
-            if not force and now - poll_last[0] < _POLL_S:
-                return 0
-            poll_last[0] = now
-            items, _rv = admin.list(PODS)
-            stamp = time.perf_counter()
-            fresh = 0
-            for key, pod in items:
-                if pod.node_name and key in created_at \
-                        and key not in bind_time:
-                    bind_time[key] = stamp
-                    fresh += 1
-            return fresh
-
-        def live_unbound() -> int:
-            return sum(
-                1 for k in created_at
-                if k not in deleted and k not in bind_time
-            )
-
-        t0 = time.perf_counter()
-        deadline = t0 + timeout_s
-        wall_deadline = (
-            t0 + wall_budget_s if wall_budget_s is not None else None
-        )
-        i = 0
-        last_progress = t0
-        while True:
-            now = time.perf_counter()
-            if (wall_deadline is not None and now > wall_deadline) \
-                    or now > deadline:
-                truncated = True
-                break
-            trace_now = (now - t0) * speed
-            fired = 0
-            while i < len(events) and events[i].at_s <= trace_now:
-                ev = events[i]
-                i += 1
-                fired += 1
-                if ev.kind == "create_pod":
-                    key = f"{ev.namespace}/{ev.name}"
-                    admin.create(PODS, key, W.build_trace_pod(ev))
-                    created_at[key] = time.perf_counter()
-                elif ev.kind == "delete_pod":
-                    key = f"{ev.namespace}/{ev.name}"
-                    deleted.add(key)
-                    try:
-                        admin.delete(PODS, key)
-                    except Exception:
-                        pass    # already gone / rebound — the trace goes on
-                elif ev.kind == "add_node":
-                    admin.create(NODES, ev.name,
-                                 make_trace_node(
-                                     ev.name, profile.zones,
-                                     getattr(profile, "slices", 0)))
-                elif ev.kind == "drain_node":
-                    try:
-                        admin.delete(NODES, ev.name)
-                    except Exception:
-                        pass
-            if (
-                handover_at is not None and not killed
-                and trace_now >= handover_at * trace_len_s
-            ):
-                cluster.kill_replica(len(cluster.schedulers) - 1)
-                killed = True
-                t_kill = time.perf_counter()
-            fresh = poll_bound(now)
-            progressed = bool(fired or fresh)
-            if i >= len(events):
-                if live_unbound() == 0:
-                    break
-                if progressed:
-                    last_progress = now
-                elif now - last_progress > stall_s:
-                    break
-                else:
-                    time.sleep(0.02)
-            elif progressed:
-                last_progress = now
-            else:
-                time.sleep(min(0.02, max(0.0, (
-                    events[i].at_s / speed + t0 - now
-                ))))
-        poll_bound(time.perf_counter(), force=True)
-        t_end = time.perf_counter()
-        duration = t_end - t0
-        unbound = live_unbound()
-        if t_kill is not None and unbound == 0:
-            recovery_s = t_end - t_kill
-
-        conflicts = 0.0
-        attempts = 0.0
-        lease_transitions = 0.0
-        for diag_url in cluster.scheduler_diag_urls():
-            parsed = _scrape_metrics(diag_url)
-            conflicts += _sum_samples(
-                parsed, "scheduler_federation_conflicts_total"
-            )
-            attempts += _sum_samples(
-                parsed, "scheduler_schedule_attempts_total",
-                result="scheduled",
-            )
-            lease_transitions += _sum_samples(
-                parsed, "scheduler_federation_lease_transitions_total"
-            )
-        wire_codec = admin.wire_codec
-        n_processes = cluster.n_processes()
-        restarts = cluster.supervisor.restarts_total()
-
-        def verify_parity() -> None:
-            if live_unbound():
-                raise ParityError(
-                    f"binding parity miss: {live_unbound()} live trace "
-                    f"pods unbound (replicas={replicas}, "
-                    f"partition={partition}, killed={killed}, "
-                    f"restarts={restarts})"
-                )
-
-        # a clean full replay joins on the strict store-verified parity;
-        # a truncated/stalled one records its unbound count honestly via
-        # slo_ok=False instead of turning an SLO record into a crash
-        cluster.join(
-            verify=verify_parity if (not truncated and unbound == 0)
-            else None
-        )
-        child_stats = cluster.supervisor.child_stats()
-    finally:
-        cluster.shutdown()
-
-    lats = [
-        (bind_time[k] - created_at[k]) * 1000.0
-        for k in created_at if k in bind_time
-    ]
-    p50 = float(np.percentile(lats, 50)) if lats else None
-    p99 = float(np.percentile(lats, 99)) if lats else None
-    throughput = len(lats) / duration if duration > 0 else 0.0
-    return WorkloadResult(
-        case_name=f"TraceFederation_{profile.name}",
-        workload_name=(
-            f"{profile.nodes}Nodes_mp_{replicas}sched_{partition}"
-        ),
-        threshold=None,
-        measure_pods=len(created_at),
-        scheduled=len(lats),
-        duration_s=duration,
-        throughput=throughput,
-        vs_threshold=None,
-        attempts=int(attempts),
-        cycles=0,
-        wire_codec=wire_codec,
-        replicas=replicas,
-        partition=partition,
-        conflicts=int(conflicts),
-        conflict_rate=(conflicts / attempts) if attempts else 0.0,
-        lease_transitions=int(lease_transitions),
-        binding_parity=len(bind_time),
-        recovery_s=recovery_s,
-        n_processes=n_processes,
-        child_stats=child_stats,
-        restarts=restarts,
-        device=_children_device(cluster),
-        admission_p50_ms=p50,
-        admission_p99_ms=p99,
-        slo_budget_ms=profile.slo_budget_ms,
-        slo_ok=(
-            p99 is not None and p99 <= profile.slo_budget_ms
-            and unbound == 0 and not truncated
-        ),
-        truncated=truncated,
-        trace_stats={
-            "profile": profile.name,
-            "seed": profile.seed,
-            "events": len(events),
-            "fired": i,
-            "created": len(created_at),
-            "deleted": len(deleted),
-            "unbound": unbound,
-            "samples": len(lats),
-            "handover": killed,
-            "handover_at_s": (
-                round(t_kill - t0, 3) if t_kill is not None else None
-            ),
-        },
-    )
-
-
-def run_wal_overhead(
-    n_writes: int = 20000,
-    chunk: int = 256,
-    wal_fsync: bool = True,
-    wal_wire: str = "binary",
-) -> dict:
-    """Steady-state WAL cost: the SAME bulk create+bind write sequence
-    against a persistent store and a memory-only one; the throughput
-    ratio (and ``wal_overhead_frac``) is the price of durability."""
-    import shutil
-    import tempfile
-
-    from ..api.wrappers import make_pod
-    from ..client.informers import PODS
-    from ..store.memstore import MemStore
-
-    def drive(store) -> float:
-        t0 = time.perf_counter()
-        for i in range(0, n_writes, chunk):
-            keys = [f"ns/p-{j}" for j in range(i, min(i + chunk, n_writes))]
-            store.bulk(PODS, [
-                {"op": "create", "key": k,
-                 "object": make_pod(k.split("/", 1)[1], namespace="ns")}
-                for k in keys
-            ])
-            store.bulk(PODS, [
-                {"op": "bind", "key": k, "node": "node-0"} for k in keys
-            ])
-        return time.perf_counter() - t0
-
-    dirpath = tempfile.mkdtemp(prefix="kubetpu-wal-bench-")
-    try:
-        st_on = MemStore(persistence=dirpath, wal_fsync=wal_fsync,
-                         wal_wire=wal_wire)
-        on_s = drive(st_on)
-        stats = st_on.wal_stats()
-        st_on.close()
-    finally:
-        shutil.rmtree(dirpath, ignore_errors=True)
-    st_off = MemStore()
-    off_s = drive(st_off)
-    writes = 2 * n_writes           # one create + one bind per pod
-    on_rate = writes / on_s if on_s > 0 else 0.0
-    off_rate = writes / off_s if off_s > 0 else 0.0
-    return {
-        "writes": writes,
-        "chunk": chunk,
-        "wal_fsync": wal_fsync,
-        "wal_wire": wal_wire,
-        "on_writes_per_s": round(on_rate, 1),
-        "off_writes_per_s": round(off_rate, 1),
-        "throughput_ratio": round(on_rate / off_rate, 4) if off_rate else None,
-        "wal_overhead_frac": (
-            round(max(0.0, 1.0 - on_rate / off_rate), 4) if off_rate else None
-        ),
-        "wal_bytes_per_write": (
-            round(stats["bytes_appended"] / writes, 1) if stats else None
-        ),
-        "wal_fsyncs": stats["fsyncs"] if stats else None,
-        # the durability tax's latency shape, not just its throughput
-        # cost: p99 of the group-commit fsync (store_wal_fsync_duration_
-        # seconds — the same histogram the apiserver's /metrics exposes)
-        "fsync_p99_ms": stats["fsync_p99_ms"] if stats else None,
-    }
-
-
-def run_label(label: str = "performance", **kwargs) -> list[WorkloadResult]:
-    """Run every workload carrying ``label`` (the reference's label selector,
-    e.g. -perf-scheduling-label-filter=performance)."""
-    out = []
-    for case in W.TEST_CASES.values():
-        for wl in case.workloads:
-            if label in wl.labels:
-                out.append(run_workload(case, wl, **kwargs))
     return out

@@ -1201,7 +1201,7 @@ class MemStore:
                 self._wal_lock = None
 
     def wal_stats(self) -> "dict | None":
-        """Append-side counters for metrics and run_wal_overhead (None when
+        """Append-side counters for metrics and the debug bundle (None when
         off)."""
         import math
 

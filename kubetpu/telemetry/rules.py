@@ -1,8 +1,8 @@
 """Declarative alert-rule table for the anomaly sentinel.
 
 Every threshold the sentinel compares against lives HERE (or arrives as
-a declared budget — ``TRACE_PROFILES[*].slo_budget_ms`` from
-kubetpu.perf.workloads), never as a literal at an evaluation site: the
+a declared budget — a trace profile's ``slo_budget_ms`` in
+``perf/workloads.py``), never as a literal at an evaluation site: the
 AL001 checker (kubetpu.analysis.alertcheck) machine-enforces that split,
 the same way EC001 pins encode-cache flush scope. A rule is a frozen
 record naming WHAT series to watch and WHEN it is anomalous; the

@@ -267,29 +267,64 @@ def test_explain_renders_from_dump_file(tmp_path, capsys):
 def test_fullstack_carries_ingest_stamp_through_watch_to_stages():
     """The apiserver stamps trace id + ingest time at REST create; the
     watch frame carries it; the staged vector then includes api_ingest and
-    the e2e base is the CREATE, not the informer delivery."""
-    from kubetpu.perf.runner import run_workload_full_stack
-    from kubetpu.perf.workloads import Workload
+    the e2e base is the CREATE, not the informer delivery. Driven over a
+    real apiserver: RemoteStore, the scheduler's informers, a Scheduler
+    binding through the store."""
+    import time
 
-    r = run_workload_full_stack(
-        "SchedulingBasic",
-        Workload("tiny", {"initNodes": 10, "initPods": 5, "measurePods": 20}),
-        timeout_s=120,
-    )
-    assert r.scheduled == 20
-    staged = r.staged_latency_ms
-    assert staged is not None
-    assert {"api_ingest", "informer", "queue_wait", "encode", "kernel",
-            "dispatch", "bind_rtt", "e2e"} <= set(staged)
-    # e2e covers at least the non-overlapping pipeline stages it contains
-    assert staged["e2e"]["p99"] >= staged["bind_rtt"]["p50"]
-    # the soak split is present (both halves saw binds) and carries the
-    # flatness verdict fields
-    if r.soak is not None:
-        assert {"p99_first_half_ms", "p99_second_half_ms", "ratio",
-                "p99_flat"} <= set(r.soak)
-    out = r.to_json()
-    assert out["staged_latency_ms"] is staged
+    from kubetpu.apiserver import APIServer, RemoteStore
+    from kubetpu.client import SchedulerInformers, StoreClient
+    from kubetpu.client.informers import NODES, PODS
+    from kubetpu.sched import Scheduler
+
+    srv = APIServer().start()
+    s = None
+    try:
+        remote = RemoteStore(srv.url)
+        for i in range(10):
+            remote.create(NODES, f"n{i}", make_node(
+                f"n{i}", cpu_milli=4000, memory=8 * 1024 ** 3))
+        s = Scheduler(StoreClient(remote), max_batch=8)
+        informers = SchedulerInformers(remote, s)
+        informers.start()
+        base = s.metrics.prom.snapshot_baseline()
+        for j in range(20):
+            remote.create(PODS, f"default/p{j}", make_pod(
+                f"p{j}", cpu_milli=100, memory=100 * 1024 ** 2))
+        stored = {}
+        deadline = time.monotonic() + 120
+        while time.monotonic() < deadline:
+            informers.pump()
+            s.schedule_batch()
+            s.dispatcher.sync()
+            s._drain_bind_completions()
+            stored = {k: p for k, p in remote.list(PODS)[0]}
+            if all(p.node_name for p in stored.values()):
+                break
+        assert len(stored) == 20
+        assert all(p.node_name for p in stored.values())
+        informers.pump()
+        staged = s.metrics.prom.staged_percentiles(base)
+        assert staged is not None
+        assert {"api_ingest", "informer", "queue_wait", "encode", "kernel",
+                "dispatch", "bind_rtt", "e2e"} <= set(staged)
+        # e2e covers at least the non-overlapping pipeline stages it
+        # contains
+        assert staged["e2e"]["p99"] >= staged["bind_rtt"]["p50"]
+        for key, pod in stored.items():
+            rec = s.flight_recorder.lookup(key)
+            # the trace id the apiserver stamped rode the watch frame
+            assert pod.trace_id and rec["trace_id"] == pod.trace_id
+            stages = rec["stages_ms"]
+            # e2e is based at the CREATE: it spans the REST-create →
+            # delivery leg and everything after it
+            assert stages["api_ingest"] >= 0.0
+            assert stages["e2e"] >= stages["api_ingest"]
+            assert stages["e2e"] >= stages["bind_rtt"]
+    finally:
+        if s is not None:
+            s.close()
+        srv.close()
 
 
 def test_apiserver_stamps_pod_ingest_once():

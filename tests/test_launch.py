@@ -8,8 +8,9 @@ and — the integration spine — a real 2-replica hash cluster over a
 persistent apiserver survives a replica SIGKILL mid-run (the respawned
 process re-federates and every pod binds), the SIGTERM cascade leaves no
 orphan processes, ``store fsck`` passes on the WAL dir afterwards, and
-``run_workload_multiprocess`` joins on store-verified binding parity with
-per-child resource stats in the record.
+per-child resource stats are sampled while the children live. The watch
+fan-out load starts too: ``WatchFanout`` in process, and ``kubetpu up
+--watch-fanout N --fanout-procs M`` as ``watch-driver`` processes.
 """
 
 from __future__ import annotations
@@ -310,110 +311,72 @@ def test_mp_cluster_replica_kill_respawn_cascade_and_fsck(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the mp perf runner: parity-joined measurement on a tiny workload
+# the watch fan-out load: in process, and as `kubetpu up` driver processes
 # ---------------------------------------------------------------------------
 
-def _tiny_case():
-    from kubetpu.perf import workloads as W
+def test_watch_fanout_watchers_share_each_event_and_stop():
+    """Every watcher drains the same events: with more than one, the
+    store's serialize-once body ring answers all but the first from its
+    cache. ``stop`` ends every watcher thread."""
+    from kubetpu.api.wrappers import make_pod
+    from kubetpu.apiserver import APIServer, RemoteStore
+    from kubetpu.launch import WatchFanout
 
-    return W.TestCase(
-        name="MpSmoke",
-        ops=(
-            W.CreateNodesOp(count=4),
-            W.CreatePodsOp("initPods"),
-            W.CreatePodsOp("measurePods", collect_metrics=True),
-        ),
-        workloads=(
-            W.Workload("tiny", {"initPods": 8, "measurePods": 24}),
-        ),
+    srv = APIServer().start()
+    fanout = WatchFanout(srv.url, "binary", 3)
+    try:
+        remote = RemoteStore(srv.url)
+        deadline = time.monotonic() + 30
+        hits = 0
+        j = 0
+        while time.monotonic() < deadline:
+            remote.create("pods", f"ns/p{j}", make_pod(f"p{j}",
+                                                       namespace="ns"))
+            j += 1
+            time.sleep(0.1)
+            hits = srv.store.body_cache_stats()["binary"][0]
+            if hits >= 2:
+                break
+        assert hits >= 2, srv.store.body_cache_stats()
+    finally:
+        fanout.stop()
+        srv.close()
+    assert not any(t.is_alive() for t in fanout._threads)
+
+
+def test_up_watch_fanout_starts_watch_driver_processes():
+    """``kubetpu up --watch-fanout 3 --fanout-procs 2`` spreads three
+    watchers over two ``kubetpu watch-driver`` children, each one
+    bannered ready beside the apiserver and the scheduler, and a SIGTERM
+    takes the whole topology down cleanly."""
+    import signal
+    import subprocess
+
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "kubetpu", "up", "--replicas", "1",
+         "--watch-fanout", "3", "--fanout-procs", "2"],
+        cwd=REPO, env={**os.environ, **CPU_ENV}, stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT, text=True,
     )
-
-
-def test_run_workload_multiprocess_joins_on_parity():
-    from kubetpu.perf.runner import run_workload_multiprocess
-
-    case = _tiny_case()
-    r = run_workload_multiprocess(
-        case, case.workloads[0], replicas=2, partition="race",
-        max_batch=32, timeout_s=120.0, child_env=CPU_ENV,
-    )
-    assert r.scheduled == 24 and r.measure_pods == 24
-    assert r.binding_parity == 24        # join-verified exactly-once
-    assert r.replicas == 2 and r.partition == "race"
-    assert r.n_processes == 3            # apiserver + 2 schedulers
-    assert r.restarts == 0
-    assert r.throughput > 0
-    # CI/bench hygiene: per-child peak RSS + cpu_seconds in the record
-    doc = r.to_json()
-    assert doc["n_processes"] == 3
-    # the device stamp is the scheduler CHILDREN's (the parent holds none)
-    assert doc["platform"] == "cpu" and doc["devices"] >= 1
-    stats = doc["child_stats"]
-    assert set(stats) == {"apiserver", "scheduler-r0", "scheduler-r1"}
-    for child in stats.values():
-        assert child.get("peak_rss_bytes", 0) > 0
-        assert child.get("cpu_seconds", 0) > 0
-    # the API-plane evidence was scraped over HTTP, not read in-process
-    assert r.rpcs_per_scheduled_pod is not None
-    assert r.wire_codec == "binary"
-
-
-def test_run_workload_multiprocess_rejects_unknown_ops():
-    from kubetpu.perf import workloads as W
-    from kubetpu.perf.runner import run_workload_multiprocess
-
-    case = W.TestCase(
-        name="MpUnsupported",
-        ops=(W.ChurnOp(interval_ms=100, template=W.pod_default),),
-        workloads=(W.Workload("w", {}),),
-    )
-    with pytest.raises(NotImplementedError):
-        run_workload_multiprocess(case, case.workloads[0])
-
-
-# ---------------------------------------------------------------------------
-# trace replay against the mp federation (ROADMAP 5b): paced arrivals,
-# forced lease handover, store-observed admission latency
-# ---------------------------------------------------------------------------
-
-def test_run_trace_multiprocess_lease_handover():
-    from kubetpu.perf.runner import run_trace_multiprocess
-    from kubetpu.perf.workloads import TRACE_PROFILES
-
-    prof = TRACE_PROFILES["diurnal-burst"].scaled(
-        "mp-smoke", nodes=6, duration_s=4.0, base_rate=3.0,
-        peak_rate=6.0, bursts=1, burst_pods=4, slo_budget_ms=60000.0,
-    )
-    r = run_trace_multiprocess(
-        prof, replicas=2, partition="lease", max_batch=32,
-        timeout_s=180.0, handover_at=0.5, child_env=CPU_ENV,
-    )
-    created = r.trace_stats["created"]
-    assert created > 0
-    # every live trace pod bound, parity read off the store
-    assert r.trace_stats["unbound"] == 0
-    assert r.binding_parity == created
-    assert r.scheduled == created
-    # the forced handover actually happened: kill recorded mid-trace,
-    # the supervisor respawned the victim, recovery wall measured
-    assert r.trace_stats["handover"] is True
-    assert r.trace_stats["handover_at_s"] is not None
-    assert r.restarts >= 1
-    assert r.recovery_s is not None and r.recovery_s > 0
-    # the SLO record shape: p99 spans the handover, judged vs budget
-    assert r.admission_p99_ms is not None and r.admission_p99_ms > 0
-    assert r.slo_budget_ms == 60000.0
-    assert r.slo_ok is True and not r.truncated
-    assert r.partition == "lease" and r.replicas == 2
-
-
-def test_run_trace_multiprocess_rejects_gang_profiles():
-    from kubetpu.perf.runner import run_trace_multiprocess
-    from kubetpu.perf.workloads import TRACE_PROFILES
-
-    # multitenant emits create_group events — no REST kind, mp replay
-    # must refuse loudly before spawning anything
-    with pytest.raises(NotImplementedError):
-        run_trace_multiprocess(
-            TRACE_PROFILES["multitenant"], replicas=2, handover_at=None,
-        )
+    lines = []
+    try:
+        for line in proc.stdout:
+            lines.append(line)
+            if line.startswith("kubetpu up:"):
+                break
+        out = "".join(lines)
+        assert "4 process(es) ready" in out, out
+        drivers = [ln.split() for ln in lines
+                   if ln.strip().startswith("watch-driver-")]
+        assert [d[0] for d in drivers] == ["watch-driver-0",
+                                           "watch-driver-1"], out
+        pids = [int(d[2]) for d in drivers]
+        proc.send_signal(signal.SIGTERM)
+        assert proc.wait(timeout=60) == 0
+        for pid in pids:
+            with pytest.raises(ProcessLookupError):
+                os.kill(pid, 0)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()

@@ -526,23 +526,3 @@ def test_chained_follower_and_stale_epoch_fence():
         fb_srv.close()
         fa_srv.close()
         leader.close()
-
-
-def test_run_list_scaling_smoke():
-    """run_list_scaling at toy scale: multiple pages per
-    relist, the client relist accounting populated, every walk
-    parity-checked, the unpaged baseline recorded."""
-    from kubetpu.perf.runner import run_list_scaling
-
-    r = run_list_scaling(
-        n_nodes=120, relists=3, page_limit=40, wall_budget_s=60.0,
-    )
-    assert r["nodes"] == 120 and r["relists"] == 3
-    assert r["parity_ok"] is True and r["truncated"] is False
-    assert r["pages_per_relist"] == 3.0          # 120 nodes / 40-per-page
-    assert r["list_p99_ms"] > 0
-    assert r["list_p50_ms"] <= r["list_p99_ms"]
-    assert r["bytes_per_relist"] > 0
-    assert 0 < r["max_page_bytes"] <= r["bytes_per_relist"]
-    assert r["unpaged_ms"] is not None and r["unpaged_ms"] > 0
-    assert r["wire_codec"] == "binary"

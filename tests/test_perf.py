@@ -55,6 +55,27 @@ def test_basic_all_scheduled():
     assert r.to_json()["metric"] == "SchedulingThroughput/Average"
 
 
+def test_bindings_digest_names_the_bound_map():
+    """``bindings_digest`` is a digest of the (pod, node) pairs a run
+    bound: order-free, moved by any one pair, equal across two runs that
+    bind the same map (the serial and the pipelined loop, pod for pod)."""
+    from kubetpu.perf.runner import bindings_digest
+
+    pairs = [("a", "n1"), ("b", "n2"), ("c", "n1")]
+    assert bindings_digest(pairs) == bindings_digest(pairs[::-1])
+    assert bindings_digest(pairs) != bindings_digest(
+        [("a", "n1"), ("b", "n1"), ("c", "n1")])
+    wl = tiny(initNodes=20, initPods=10, measurePods=40)
+    serial = run_workload("SchedulingBasic", wl, timeout_s=120,
+                          warmup=False)
+    piped = run_workload("SchedulingBasic", wl, timeout_s=120,
+                         warmup=False, pipeline=True)
+    assert serial.scheduled == piped.scheduled == 40
+    assert serial.bindings_digest
+    assert piped.bindings_digest == serial.bindings_digest
+    assert serial.to_json()["bindings_digest"] == serial.bindings_digest
+
+
 def test_anti_affinity_respects_hostname_capacity():
     """pod-with-pod-anti-affinity (hostname, color=green): at most ONE green
     pod per node, so with N nodes only N measure pods can land."""

@@ -408,3 +408,31 @@ def test_multichip_smoke(mesh):
     assert "tpu_shard_host_to_device_transfer_bytes_total" in text
     assert "tpu_mesh_collective_wall_seconds" in text
     s.close()
+
+
+def test_run_workload_on_the_mesh_binds_the_single_device_map(mesh):
+    """The four-chip smoke's mesh check, small: the in-process harness on
+    SchedulingBasic with ``mesh="on"`` binds the single-device run's map
+    pod for pod (``bindings_digest``), with the resident block sharded
+    over every device of the mesh and routed delta bytes on more than one
+    shard."""
+    from kubetpu.perf import run_workload
+
+    wl = W.Workload("tiny", {"initNodes": 256, "initPods": 16,
+                             "measurePods": 48})
+    runs = {
+        m: run_workload("SchedulingBasic", wl, max_batch=16, mesh=m,
+                        timeout_s=120, warmup=False)
+        for m in (None, "on")
+    }
+    single, meshed = runs[None], runs["on"]
+    assert single.scheduled == meshed.scheduled == 48
+    assert meshed.bindings_digest == single.bindings_digest
+    assert single.mesh_placement is None
+    n = meshed.n_devices
+    assert n == len(jax.devices()) and n > 1
+    placement = meshed.mesh_placement
+    assert placement["block_sharded"]
+    assert placement["resident_devices"] == n
+    assert placement["shards_with_transfer"] > 1
+    assert isinstance(meshed.collective_wall_s, float)

@@ -297,15 +297,15 @@ def test_wal_checker_covers_the_store_wrapper_not_the_replay_side():
 
 def test_proc_checker_covers_kubetpu_but_not_the_launch_seam():
     """PS001 (process-spawn seam discipline) walks all of kubetpu/ — the
-    modules that historically grew ad-hoc subprocess harnesses (perf,
-    cli) included — and does NOT walk the seam
+    CLI that spawns ``kubetpu up``'s topology and the watch fan-out its
+    ``watch-driver`` children carry included — and does NOT walk the seam
     itself. Pinned against the ACTUAL walk, and against the seam still
     SPAWNING: a supervisor refactored away from Popen would leave PS001
     guarding air while nothing in the repo could start a child."""
     res = _repo_result()
     covered = set(res.coverage.get("PS001", ()))
     for f in (
-        "kubetpu/perf/runner.py",
+        "kubetpu/launch/fanout.py",     # the watch-driver's load
         "kubetpu/cli.py",
         "kubetpu/launch/cluster.py",    # topology builds specs, never spawns
         "kubetpu/native/__init__.py",   # run() probes stay in scope (and ok)

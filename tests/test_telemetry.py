@@ -39,6 +39,8 @@ from kubetpu.telemetry.exporter import (
 )
 from kubetpu.tracing import Tracer
 
+from .test_wal import CORES
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -583,11 +585,36 @@ def test_memory_store_scrape_has_no_wal_series():
         srv.close()
 
 
-def test_wal_overhead_embeds_fsync_p99(tmp_path):
-    from kubetpu.perf.runner import run_wal_overhead
+@pytest.mark.parametrize("native", CORES)
+def test_wal_stats_time_each_group_commit_of_bulk_creates_and_binds(
+        tmp_path, native):
+    """The durability tax's latency shape: a bulk create then a bulk bind
+    per chunk, each one group commit, logs one record per op and times
+    every fsync into the p99 ``wal_stats`` reports (the histogram the
+    apiserver's /metrics exposes)."""
+    from kubetpu.store.memstore import MemStore
 
-    o = run_wal_overhead(n_writes=256, chunk=64)
-    assert o["fsync_p99_ms"] is not None and o["fsync_p99_ms"] > 0
+    st = MemStore(persistence=str(tmp_path / "wal"), native=native)
+    try:
+        for i in range(0, 256, 64):
+            keys = [f"ns/p-{j}" for j in range(i, i + 64)]
+            st.bulk("pods", [
+                {"op": "create", "key": k,
+                 "object": make_pod(k.split("/", 1)[1], namespace="ns")}
+                for k in keys
+            ])
+            st.bulk("pods", [
+                {"op": "bind", "key": k, "node": "node-0"} for k in keys
+            ])
+        stats = st.wal_stats()
+    finally:
+        st.close()
+    assert stats["records_appended"] == 512
+    # the segment header's fsync, then ONE group commit per bulk request
+    assert stats["fsyncs"] == 1 + 8
+    assert stats["bytes_appended"] > 0
+    assert stats["fsync_p99_ms"] is not None and stats["fsync_p99_ms"] > 0
+    assert stats["fsync_p50_ms"] <= stats["fsync_p99_ms"]
 
 
 # ---------------------------------------------------------------------------
